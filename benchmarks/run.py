@@ -77,6 +77,9 @@ def main() -> None:
     ap.add_argument("--only", default=None,
                     help="comma-separated section names")
     args = ap.parse_args()
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     names = list(SECTIONS) + ["roofline"]
     if args.only:
         names = [n.strip() for n in args.only.split(",")]

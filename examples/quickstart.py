@@ -76,9 +76,11 @@ x, y = make_batch(9_999)
 x_int = jnp.clip(jnp.round(x / IN_SCALE), -127, 127).astype(jnp.int8)
 
 h = x_int.astype(jnp.int32)
+on_cpu = jax.default_backend() != "tpu"   # the Pallas interpreter off-chip
 for st in smlp.stages:
     h = ops.threshold_matmul(h.astype(jnp.int8), st.w_int, st.thresholds,
-                             block_m=32, block_n=8, block_k=8)
+                             block_m=32, block_n=8, block_k=8,
+                             interpret=on_cpu)
 logits = (h.astype(jnp.float32) @ smlp.head_w * smlp.stages[-1].out_scale
           + smlp.head_b)
 acc_kernel = float((jnp.argmax(logits, -1) == y).mean())

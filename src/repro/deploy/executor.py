@@ -80,11 +80,10 @@ def stage_work(s) -> int:
     return int(work)
 
 
-def _on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:  # pragma: no cover
-        return False
+def on_tpu() -> bool:
+    """True when JAX's default backend is a TPU: the kernel path is then
+    the default. No fallback: a backend that fails to start raises here."""
+    return jax.devices()[0].platform == "tpu"
 
 
 @dataclasses.dataclass
@@ -111,18 +110,28 @@ class StreamingStats:
 
 
 class CompiledTinyModel:
-    """A compiled spatial-dataflow executor for one lowered QIR graph."""
+    """A compiled spatial-dataflow executor for one lowered QIR graph.
+
+    Bound to the device that is JAX's default while it is built (a
+    ``jax.default_device`` context, as ``ReplicaPool`` gives each replica):
+    its weights live there, and ``submit_wave`` places each wave there, so
+    every program of this executor runs on that one device.
+    """
 
     def __init__(self, schedule: StageSchedule, graph: Optional[Graph] = None,
                  use_pallas: Optional[bool] = None,
-                 interpret: Optional[bool] = None,
+                 interpret: bool = False,
                  megakernel: Optional[bool] = None,
                  megakernel_budget_bytes: Optional[int] = None,
                  tracer=None):
         self.schedule = schedule
         self.graph = graph
-        self.use_pallas = _on_tpu() if use_pallas is None else use_pallas
-        self.interpret = interpret
+        #: the kernel path (Pallas) is the default on a TPU; ``interpret``
+        #: runs its kernels through the Pallas interpreter (CPU tests)
+        self.use_pallas = on_tpu() if use_pallas is None else use_pallas
+        self.interpret = bool(interpret)
+        #: None = JAX's default device at the call
+        self.device = jax.config.jax_default_device
         self.tuned = None          # deploy.autotune.TunedConfig, if applied
         #: megakernel dispatch: None = auto (fused whenever the residency
         #: planner admits the segment), True = same but assert-intent,
@@ -231,9 +240,10 @@ class CompiledTinyModel:
             from repro.kernels import ops
 
             return ops.mlp_megakernel(
-                h.astype(jnp.int32),
-                tuple(s.stage.w_int for s in stages),
+                h, tuple(s.stage.w_int for s in stages),
                 tuple(s.stage.thresholds for s in stages),
+                operands=tuple(s.mxu[0] for s in stages),
+                offsets=tuple(s.mxu[1] for s in stages),
                 block_m=plan.block_m, interpret=self.interpret)
         for s in stages:
             h = s.apply_fast(h)
@@ -484,7 +494,7 @@ class CompiledTinyModel:
         # tail before this was moved host-side)
         buf = np.zeros((mb,) + xb.shape[1:], xb.dtype)
         buf[:n][mask[:n]] = xb[mask[:n]]
-        wave = jnp.asarray(buf[None])
+        wave = jax.device_put(buf[None], self.device)
         try:
             wave = self._run_segments(wave, 1, mode="submit_wave")
         except Exception as e:
@@ -609,7 +619,7 @@ class CompiledTinyModel:
 
 def compile_graph(graph: Graph, in_scale: float = 1.0 / 127.0,
                   use_pallas: Optional[bool] = None,
-                  interpret: Optional[bool] = None,
+                  interpret: bool = False,
                   conv_lowering: Optional[str] = None,
                   megakernel: Optional[bool] = None,
                   autotune: bool = False,
@@ -619,6 +629,9 @@ def compile_graph(graph: Graph, in_scale: float = 1.0 / 127.0,
     ``conv_lowering`` picks the conv stage algorithm ("direct" fused kernel
     by default, "im2col" fallback) for both offline and streaming modes —
     the stage methods the executor dispatches through carry the choice.
+    ``use_pallas`` defaults to the kernel path on a TPU and to the XLA
+    path elsewhere; ``interpret=True`` runs the kernel path's Pallas
+    kernels through the interpreter (CPU tests).
     ``megakernel`` forces the whole-network-resident fused dispatch on
     (True) or off (False); the default None lets the residency planner
     decide per segment (``docs/megakernel.md``), and an applied tuned

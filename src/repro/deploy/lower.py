@@ -50,8 +50,9 @@ stages compose exactly.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import os
-from typing import Dict, List, Optional, Sequence, Union
+from typing import Dict, List, Optional, Sequence, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -115,6 +116,37 @@ def _float_mm_safe(w_int, in_bits: int) -> bool:
     return worst * ((1 << in_bits) - 1) < (1 << 24)
 
 
+def mxu_form(w_int, in_bits: int, in_signed: bool) -> Tuple[str, int]:
+    """The exact operand form of one fused stage's matmul on the Pallas
+    kernels (Mosaic has no int32 matmul; ``kernels.multi_threshold``).
+
+    ``("bf16", 0)`` — bf16 operands, f32 accumulation — wherever that is
+    exact: every code and weight an integer of magnitude <= 256 (exact in
+    bf16) and every partial sum below 2^24 (``_float_mm_safe``). Otherwise
+    ``("int8", x_offset)``: int8 x int8 -> int32, with unsigned codes that
+    overflow int8 (8-bit activations) shifted down by ``x_offset`` and the
+    correction folded into the threshold bank. Raises where neither form
+    is exact (codes or weights wider than 8 bits past the bf16 bound).
+    """
+    w = np.asarray(w_int, np.int64)
+    w_lo, w_hi = (int(w.min()), int(w.max())) if w.size else (0, 0)
+    if (in_bits <= 8 and max(-w_lo, w_hi) <= 256
+            and _float_mm_safe(w, in_bits)):
+        return "bf16", 0
+    if in_signed:
+        x_lo, x_hi = -(1 << (in_bits - 1)), (1 << (in_bits - 1)) - 1
+    else:
+        x_lo, x_hi = 0, (1 << in_bits) - 1
+    offset = 0 if x_hi <= 127 else 1 << (in_bits - 1)
+    if x_lo - offset < -128 or x_hi - offset > 127 or w_lo < -128 \
+            or w_hi > 127:
+        raise ValueError(
+            f"no exact MXU operand form: {in_bits}-bit "
+            f"{'signed' if in_signed else 'unsigned'} codes, weights in "
+            f"[{w_lo}, {w_hi}], past the bf16 bound")
+    return "int8", offset
+
+
 def _apply_act(stage: ThresholdDense, affine, acc):
     """Integer activation on the accumulator, fastest exact form available.
 
@@ -142,6 +174,7 @@ class FusedThresholdStage:
     out_dim: int
     in_scale: float
     in_bits: int = 8
+    in_signed: bool = False  # input codes signed (the graph input) or not
     mm_float: bool = False   # exact float32 GEMM path (see _float_mm_safe)
     affine: Optional[tuple] = None   # exact O(1) activation (see _apply_act)
     block_m: Optional[int] = None    # tuned kernel row block (None = default)
@@ -150,6 +183,11 @@ class FusedThresholdStage:
     @property
     def out_scale(self) -> float:
         return self.stage.out_scale
+
+    @functools.cached_property
+    def mxu(self) -> Tuple[str, int]:
+        """(operand, x_offset) of the kernel path (``mxu_form``)."""
+        return mxu_form(self.stage.w_int, self.in_bits, self.in_signed)
 
     @property
     def macs(self) -> int:
@@ -172,14 +210,13 @@ class FusedThresholdStage:
         allows, O(1) or O(log S) in the step count."""
         return _apply_act(self.stage, self.affine, self._acc(x_int))
 
-    def apply_kernel(self, x_int, *, interpret: Optional[bool] = None):
+    def apply_kernel(self, x_int, *, interpret: bool = False):
         from repro.kernels import ops
 
-        # int32, not int8: inter-stage codes are UNSIGNED in
-        # [0, 2^act_bits - 1], so 8-bit activations (128..255) would wrap
-        # negative under an int8 cast. The kernel takes either width.
+        operand, x_offset = self.mxu
         return ops.threshold_matmul(
-            x_int.astype(jnp.int32), self.stage.w_int, self.stage.thresholds,
+            x_int, self.stage.w_int, self.stage.thresholds,
+            operand=operand, x_offset=x_offset,
             block_m=self.block_m or 128, block_n=self.block_n or 128,
             interpret=interpret)
 
@@ -227,6 +264,7 @@ class FusedConvThresholdStage:
     geom: ConvGeom
     in_scale: float
     in_bits: int = 8
+    in_signed: bool = False  # input codes signed (the graph input) or not
     mm_float: bool = False   # exact float32 GEMM path (see _float_mm_safe)
     affine: Optional[tuple] = None   # exact O(1) activation (see _apply_act)
     lowering: str = "direct"         # "direct" | "im2col"
@@ -235,6 +273,11 @@ class FusedConvThresholdStage:
     @property
     def out_scale(self) -> float:
         return self.stage.out_scale
+
+    @functools.cached_property
+    def mxu(self) -> Tuple[str, int]:
+        """(operand, x_offset) of the kernel path (``mxu_form``)."""
+        return mxu_form(self.stage.w_int, self.in_bits, self.in_signed)
 
     @property
     def in_dim(self) -> int:
@@ -333,20 +376,21 @@ class FusedConvThresholdStage:
         return self._shape_out(
             _apply_act(self.stage, self.affine, acc), x_int.shape[0])
 
-    def apply_kernel(self, x_int, *, interpret: Optional[bool] = None):
+    def apply_kernel(self, x_int, *, interpret: bool = False):
         from repro.kernels import ops
 
         g = self.geom
+        operand, x_offset = self.mxu
         if self.lowering == "direct":
             x = x_int.reshape(-1, g.in_h, g.in_w, g.in_ch)
             return ops.conv_threshold(
-                x.astype(jnp.int32), self.stage.w_int, self.stage.thresholds,
+                x, self.stage.w_int, self.stage.thresholds,
                 kernel=g.kernel, stride=g.stride, padding=g.padding,
                 out_h=g.out_h, out_w=g.out_w, block_h=self.block_h,
-                interpret=interpret)
+                operand=operand, x_offset=x_offset, interpret=interpret)
         y = ops.threshold_matmul(
-            self._cols2d(x_int).astype(jnp.int32), self.stage.w_int,
-            self.stage.thresholds, interpret=interpret)
+            self._cols2d(x_int), self.stage.w_int, self.stage.thresholds,
+            operand=operand, x_offset=x_offset, interpret=interpret)
         return self._shape_out(y, x_int.shape[0])
 
 
@@ -421,9 +465,53 @@ class FlattenStage:
         return x.reshape(x.shape[0], -1)
 
 
+def head_limbs(w, in_dim: int, in_bits: int):
+    """Split a float head matrix into small integer limbs on a per-column
+    fixed-point grid, so that ``codes @ w`` becomes exact integer matmuls.
+
+    Column c gets the power-of-two step ``2^(e - 24)`` that keeps 24
+    significant bits of its largest weight (``2^(e-1) <= max|w| < 2^e``), so
+    ``W = round(w / step)`` has ``|W| < 2^24`` and reproduces every weight
+    within half a unit of the column's last float32 bit. ``W`` is cut into
+    balanced base-``2^L`` digits with ``L`` the widest limb for which every
+    digit is exact in bf16 (``|d| <= 128``) and every partial sum of
+    ``codes @ digits`` stays below 2^24 — so one f32 matmul per limb is
+    exact on any backend, the TPU's one-pass bf16 default included.
+    Returns ``(limbs (n, K, N) float32, L, step (N,) float64)``, low limb
+    first.
+    """
+    w = np.asarray(w, np.float64)
+    x_max = (1 << in_bits) - 1
+    bits = 8
+    while bits > 1 and in_dim * x_max * (1 << (bits - 1)) >= (1 << 24):
+        bits -= 1
+    if in_dim * x_max * (1 << (bits - 1)) >= (1 << 24):
+        raise ValueError(f"head {in_dim}x{w.shape[1]} over {in_bits}-bit "
+                         "codes is past the exact f32 accumulation bound")
+    _, e = np.frexp(np.max(np.abs(w), axis=0))
+    step = np.ldexp(1.0, e - 24)
+    rest = np.round(w / step).astype(np.int64)
+    base, limbs = 1 << bits, []
+    while np.any(rest) or not limbs:
+        d = (rest + base // 2) % base - base // 2
+        limbs.append(d)
+        rest = (rest - d) // base
+    return np.stack(limbs).astype(np.float32), bits, step
+
+
 @dataclasses.dataclass
 class FloatHeadStage:
-    """Final affine head: logits = x_int * in_scale @ w + b (float out)."""
+    """Final affine head: logits = x_int * in_scale @ w + b (float out).
+
+    Computed exactly, so every execution path and backend produces the same
+    float32 logits bit for bit: the codes meet ``w``'s integer limbs
+    (``head_limbs``) in exact f32 matmuls, and one fixed sequence of
+    elementwise float32 ops forms the logits — Horner over the limbs
+    (multiplies by powers of two are exact), then ``(acc + b / scale) *
+    scale``, an order no backend can fuse into a multiply-add. Within
+    float32 rounding of ``x @ w * in_scale + b``; bit-identical to it where
+    the weights are integers, as in CNV's sign head.
+    """
 
     name: str
     w: jnp.ndarray
@@ -433,12 +521,27 @@ class FloatHeadStage:
     in_scale: float
     in_bits: int = 8
 
+    def __post_init__(self):
+        limbs, self.limb_bits, step = head_limbs(self.w, self.in_dim,
+                                                 self.in_bits)
+        self.limbs = tuple(jnp.asarray(d) for d in limbs)
+        scale = step * self.in_scale
+        self.scale = jnp.asarray(scale.astype(np.float32))
+        self.b_scaled = jnp.asarray(
+            (np.asarray(self.b, np.float64) / scale).astype(np.float32))
+
     @property
     def macs(self) -> int:
         return self.in_dim * self.out_dim
 
     def apply_ref(self, x_int):
-        return x_int.astype(jnp.float32) @ self.w * self.in_scale + self.b
+        x = x_int.astype(jnp.float32)
+        base = float(1 << self.limb_bits)
+        acc = None
+        for d in self.limbs[::-1]:                 # top limb first (Horner)
+            part = jnp.matmul(x, d, preferred_element_type=jnp.float32)
+            acc = part if acc is None else acc * base + part
+        return (acc + self.b_scaled) * self.scale
 
 
 @dataclasses.dataclass
@@ -819,7 +922,8 @@ def _exact_affine(m: ChainMatch, td: ThresholdDense, scale: float,
 
 def stage_for(m: ChainMatch, scale: float, in_bits: int = 8,
               bn_eps: float = 1e-3,
-              conv_lowering: Optional[str] = None) -> Stage:
+              conv_lowering: Optional[str] = None,
+              in_signed: bool = False) -> Stage:
     """Build the fused stage for one matched chain — the op dispatch point."""
     td = _threshold_for_chain(m, scale, bn_eps)
     mm_float = _float_mm_safe(td.w_int, in_bits)
@@ -839,6 +943,7 @@ def stage_for(m: ChainMatch, scale: float, in_bits: int = 8,
                              f"expected one of {CONV_LOWERINGS}")
         return FusedConvThresholdStage(name=m.head.name, stage=td, geom=geom,
                                        in_scale=scale, in_bits=in_bits,
+                                       in_signed=in_signed,
                                        mm_float=mm_float, affine=affine,
                                        lowering=kind)
     w = m.params["w"]
@@ -846,6 +951,7 @@ def stage_for(m: ChainMatch, scale: float, in_bits: int = 8,
                                in_dim=int(w.shape[0]),
                                out_dim=int(w.shape[1]),
                                in_scale=scale, in_bits=in_bits,
+                               in_signed=in_signed,
                                mm_float=mm_float, affine=affine)
 
 
@@ -865,15 +971,17 @@ def lower_graph(graph: Graph, in_scale: float = 1.0 / 127.0,
     nodes = graph.nodes
     scale = in_scale
     in_bits = 8   # MLPerf-Tiny 8-bit input layer contract
+    in_signed = True   # signed input codes; every stage emits unsigned ones
     i = 0
     while i < len(nodes):
         m = _match_chain(graph, nodes, i)
         if m is not None:
             st = stage_for(m, scale, in_bits, bn_eps,
-                           conv_lowering=conv_lowering)
+                           conv_lowering=conv_lowering, in_signed=in_signed)
             stages.append(st)
             scale = st.out_scale
             in_bits = st.stage.act_bits
+            in_signed = False
             i += m.n_consumed
             continue
         node = nodes[i]

@@ -35,22 +35,13 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams as _CompilerParams
-
-
-def _count_thresholds(acc, thr_ref, n_steps: int):
-    """Threshold count over one resident (S, N) bank slab: out = #(acc >= T)."""
-    out = jnp.zeros_like(acc)
-
-    def body(s, out):
-        t = jax.lax.dynamic_slice_in_dim(thr_ref[...], s, 1, axis=0)  # (1, N)
-        return out + (acc >= t).astype(jnp.int32)
-
-    return jax.lax.fori_loop(0, n_steps, body, out)
+from repro.kernels.multi_threshold import (count_thresholds, mxu_dot,
+                                          shift_bank, to_operand)
 
 
 def _mega_kernel(x_ref, *refs, n_stages: int, n_steps: Sequence[int],
-                 out_dims: Sequence[int]):
+                 out_dims: Sequence[int], operands: Sequence[str],
+                 offsets: Sequence[int], interpret: bool):
     """One row block of the wave through ALL stages, entirely on-chip.
 
     ``refs`` layout (pallas_call order): the n_stages resident weight refs,
@@ -62,14 +53,13 @@ def _mega_kernel(x_ref, *refs, n_stages: int, n_steps: Sequence[int],
     o_ref = refs[2 * n_stages]
     fifo = refs[2 * n_stages + 1:]
 
-    h = x_ref[...].astype(jnp.int32)                    # (bm, K0)
+    h = x_ref[...]                        # (bm, K0), stage 0's operand type
     for d in range(n_stages):
-        acc = jax.lax.dot_general(                      # int32 accumulator,
-            h, w_refs[d][...].astype(jnp.int32),        # never leaves VMEM
-            (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.int32,
-        )
-        codes = _count_thresholds(acc, t_refs[d], int(n_steps[d]))
+        if d:                             # int32 codes from the FIFO tile
+            h = to_operand(h, operands[d], offsets[d])
+        # int32 accumulator, never leaves VMEM
+        acc = mxu_dot(h, w_refs[d][...], operands[d], interpret)
+        codes = count_thresholds(acc, t_refs[d], int(n_steps[d]))
         if d == n_stages - 1:
             o_ref[...] = codes
         else:
@@ -81,18 +71,22 @@ def _mega_kernel(x_ref, *refs, n_stages: int, n_steps: Sequence[int],
 def mlp_megakernel(x_int: jnp.ndarray,
                    weights: Sequence[jnp.ndarray],
                    banks: Sequence[jnp.ndarray], *,
+                   operands: Sequence[str],
+                   offsets: Sequence[int],
                    block_m: int = 128,
                    interpret: bool = False) -> jnp.ndarray:
     """Run a whole FusedThresholdStage chain as one Pallas program.
 
     ``x_int`` is the flattened wave ``(M, K0)`` of int32 codes; ``weights``
-    the per-stage ``(K_d, N_d)`` int8 matrices (``K_{d+1} == N_d``);
-    ``banks`` the per-stage ``(N_d, S_d)`` int32 sorted threshold banks.
-    Returns the LAST stage's ``(M, N_last)`` int32 codes; intermediate
-    activations exist only in the kernel's VMEM scratch. M must divide
-    ``block_m`` (``ops.mlp_megakernel`` pads).
+    the per-stage ``(K_d, N_d)`` integer matrices (``K_{d+1} == N_d``);
+    ``banks`` the per-stage ``(N_d, S_d)`` int32 sorted threshold banks;
+    ``operands``/``offsets`` each stage's exact MXU form
+    (``kernels.multi_threshold``). Returns the LAST stage's ``(M, N_last)``
+    int32 codes; intermediate activations exist only in the kernel's VMEM
+    scratch. M must divide ``block_m`` (``ops.mlp_megakernel`` pads).
     """
-    assert len(weights) == len(banks) and weights
+    assert len(weights) == len(banks) == len(operands) == len(offsets)
+    assert weights
     M, K0 = x_int.shape
     n_stages = len(weights)
     assert M % block_m == 0, (M, block_m)
@@ -103,13 +97,16 @@ def mlp_megakernel(x_int: jnp.ndarray,
         assert b.shape[0] == w.shape[1], (b.shape, w.shape)
         k_prev = int(w.shape[1])
         dims.append(k_prev)
-    thr_t = [b.T.astype(jnp.int32) for b in banks]      # (S, N): lanes = chans
+    # (S, N) banks, lanes = channels, each stage's code offset folded in
+    thr_t = [shift_bank(b, w, off).T
+             for b, w, off in zip(banks, weights, offsets)]
+    w_ops = [to_operand(w, op) for w, op in zip(weights, operands)]
 
     # constant index maps: weights/banks are fetched once and stay resident
     # across the (sequential) row-block grid — the VMEM residency the
     # planner budgets for
     in_specs = [pl.BlockSpec((block_m, K0), lambda i: (i, 0))]
-    for w in weights:
+    for w in w_ops:
         in_specs.append(pl.BlockSpec(w.shape, lambda i: (0, 0)))
     for t in thr_t:
         in_specs.append(pl.BlockSpec(t.shape, lambda i: (0, 0)))
@@ -123,19 +120,20 @@ def mlp_megakernel(x_int: jnp.ndarray,
     return pl.pallas_call(
         functools.partial(_mega_kernel, n_stages=n_stages,
                           n_steps=tuple(int(t.shape[0]) for t in thr_t),
-                          out_dims=tuple(dims)),
+                          out_dims=tuple(dims), operands=tuple(operands),
+                          offsets=tuple(offsets), interpret=interpret),
         grid=(M // block_m,),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((block_m, dims[-1]), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((M, dims[-1]), jnp.int32),
         scratch_shapes=scratch,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             # sequential grid: consecutive row blocks reuse the resident
             # weight/bank blocks instead of refetching them
             dimension_semantics=("arbitrary",),
         ),
         interpret=interpret,
-    )(x_int.astype(jnp.int32), *weights, *thr_t)
+    )(to_operand(x_int, operands[0], offsets[0]), *w_ops, *thr_t)
 
 
 def mlp_megakernel_ref(x_int, weights, banks) -> jnp.ndarray:

@@ -1,8 +1,9 @@
 """Serving launcher: continuous-batching engine with optional int8 deployment
 quantization — the paper's streamlined-deployment path for the LM archs —
 plus the tiny-model stack behind ``--stack tiny``: a compiled Table-1 model
-served through the ``repro.serve`` router with a replica pool and a
-selectable dispatch engine.
+(KWS, AD, IC or CNV; the Pallas kernel path on a TPU) served through the
+``repro.serve`` router by one replica per device and a selectable dispatch
+engine.
 
     PYTHONPATH=src python -m repro.launch.serve --arch internlm2-1.8b \
         --reduced --requests 16 --quant-bits 8
@@ -28,15 +29,43 @@ logging.basicConfig(level=logging.INFO, format="%(message)s")
 log = logging.getLogger("repro.launch.serve")
 
 
+#: Table-1 families the tiny stack serves: name -> per-sample input shape
+TINY_MODELS = {"kws": (490,), "ad": (128,), "ic": (32, 32, 3),
+               "cnv": (32, 32, 3)}
+
+
+def build_tiny_graph(name: str, seed: int = 0):
+    """One Table-1 model at its published width, weights from ``seed``,
+    exported to QIR. Returns ``(graph, in_scale, input_shape)``; requests
+    are signed 8-bit input codes of ``input_shape``."""
+    from repro.core.qir import export_qcnn, export_qmlp
+    from repro.models.tiny import ADAutoencoder, CNVModel, ICModel, KWSMLP
+
+    key = jax.random.PRNGKey(seed)
+    if name in ("kws", "ad"):
+        model = KWSMLP() if name == "kws" else ADAutoencoder()
+        params = model.init(key)
+        hidden_defs, _ = model.layers()
+        in_scale = 1.0 / 127.0
+        graph = export_qmlp(hidden_defs, params["hidden"], params["head"],
+                            meta={"model": type(model).__name__},
+                            freeze_scales=True, in_scale=in_scale)
+    elif name in ("ic", "cnv"):
+        model = ICModel() if name == "ic" else CNVModel()
+        graph = export_qcnn(model, model.init(key))
+        in_scale = graph.meta["in_scale"]
+    else:
+        raise ValueError(f"unknown tiny model {name!r}; "
+                         f"expected one of {sorted(TINY_MODELS)}")
+    return graph, in_scale, TINY_MODELS[name]
+
+
 def _run_tiny(args):
-    """Compile one tiny model, spread it over ``--replicas`` pool slots
-    (one physical CPU device — the pool is logical, the dispatch overlap
-    real via JAX async dispatch), and drive a Poisson trace through the
-    router under the chosen engine."""
-    from repro.core.qir import export_qmlp
+    """Compile one Table-1 model (the Pallas kernel path on a TPU), give
+    each of ``--replicas`` replicas its own executor on its own device, and
+    drive a Poisson trace through the router under the chosen engine."""
     from repro.deploy import compile_graph
     from repro.deploy.autotune import autotune_model
-    from repro.models.tiny import ADAutoencoder, KWSMLP
     from repro.serve import (
         AsyncEngine,
         ReplicaPool,
@@ -48,17 +77,16 @@ def _run_tiny(args):
         poisson_trace,
     )
 
-    in_scale = 1.0 / 127.0
-    model, dim = ((KWSMLP(), 490) if args.tiny_model == "kws"
-                  else (ADAutoencoder(), 128))
-    params = model.init(jax.random.PRNGKey(0))
-    hidden_defs, _ = model.layers()
-    graph = export_qmlp(hidden_defs, params["hidden"], params["head"],
-                        meta={"model": type(model).__name__},
-                        freeze_scales=True, in_scale=in_scale)
-    cm = compile_graph(graph, in_scale=in_scale, use_pallas=False)
+    devices = jax.devices()
+    if args.replicas > len(devices):
+        raise SystemExit(f"--replicas {args.replicas}: only "
+                         f"{len(devices)} devices")
+    graph, in_scale, shape = build_tiny_graph(args.tiny_model)
+    cm = compile_graph(graph, in_scale=in_scale)
+    tuned = None
     if args.autotune != "off":
-        cm.apply_tuned(autotune_model(cm, batch=32, mode=args.autotune))
+        tuned = autotune_model(cm, batch=32, mode=args.autotune)
+        cm.apply_tuned(tuned)
     mb = cm.default_micro_batch
     if args.autotune == "model":
         # cold-start path: no wall-clock reads before the first request —
@@ -74,9 +102,12 @@ def _run_tiny(args):
                 measure_wave_service_s(cm, mb), mb)
     engine = AsyncEngine() if args.engine == "async" else SyncEngine()
 
-    # every replica slot shares the one compiled executor: submit_wave is
-    # stateless, so N slots = N logical devices on the single CPU
-    pool = ReplicaPool(factory=lambda: cm, devices=[None] * args.replicas)
+    # replica 0 reuses the executor built above on the default device;
+    # every other replica compiles its own on its own device
+    pool = ReplicaPool(
+        cm, factory=lambda: compile_graph(graph, in_scale=in_scale,
+                                          tuned=tuned),
+        devices=devices[:args.replicas])
     router = Router({args.tiny_model: pool},
                     RouterConfig(micro_batch=mb),
                     service_models={args.tiny_model: service},
@@ -87,13 +118,14 @@ def _run_tiny(args):
     t0 = obs_timer.now()
     reqs = router.run_trace(
         args.tiny_model, trace,
-        lambda i: rng.integers(-127, 128, (dim,)).astype(np.int32))
+        lambda i: rng.integers(-127, 128, shape).astype(np.int32))
     dt = obs_timer.now() - t0
     served = [r for r in reqs if not r.shed]
     lats_ms = np.asarray([r.latency_s for r in served]) * 1e3
     snap = router.stats()[args.tiny_model]["metrics"]
-    log.info("tiny stack: %s x%d replicas, %s engine, wave=%d",
-             args.tiny_model, args.replicas, args.engine, mb)
+    log.info("tiny stack: %s x%d replicas on %s, %s engine, wave=%d",
+             args.tiny_model, args.replicas, devices[0].device_kind,
+             args.engine, mb)
     log.info("offered %.0f qps | served %d/%d in %.2fs (%.0f qps)",
              qps, len(served), len(reqs), dt, len(served) / max(dt, 1e-9))
     log.info("p50 %.2f ms | p99 %.2f ms | wave p50 %.2f ms | occupancy %.2f",
@@ -110,7 +142,8 @@ def main(argv=None):
     ap.add_argument("--stack", choices=("lm", "tiny"), default="lm",
                     help="lm: continuous-batching ServeEngine; tiny: "
                          "compiled Table-1 model through the serve router")
-    ap.add_argument("--tiny-model", choices=("kws", "ad"), default="kws")
+    ap.add_argument("--tiny-model", choices=sorted(TINY_MODELS),
+                    default="kws")
     ap.add_argument("--autotune", choices=("off", "probe", "model"),
                     default="probe",
                     help="tiny stack tuning: probe = measured search, "
@@ -132,6 +165,9 @@ def main(argv=None):
     args = ap.parse_args(argv)
 
     if args.stack == "tiny":
+        from repro.compile_cache import enable_compile_cache
+
+        enable_compile_cache()
         return _run_tiny(args)
 
     cfg = get_config(args.arch)

@@ -14,14 +14,17 @@ block) and the returned ``WaveHandle``'s ``wait`` — the seam the dispatch
 engines (``serve.dispatch``) are built on. ``run_wave`` remains as the
 blocking submit-then-wait composition.
 
-On the CPU container there is exactly one device; the pool degenerates to
-a single replica and the placement/overlap logic is exercised by the
-tests through fake executors (a fake exposing ``submit_wave_async`` can
-script completion times against a manual clock).
+Each replica's executor is built under ``jax.default_device`` of its
+device, so a ``compile_graph`` factory places that replica's weights and
+programs there (``deploy.executor.CompiledTinyModel``). With one device
+the pool is a single replica; the placement/overlap logic is also
+exercised by the tests through fake executors (a fake exposing
+``submit_wave_async`` can script completion times against a manual clock).
 """
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 from typing import Callable, List, Optional, Sequence
 
@@ -90,9 +93,10 @@ class ReplicaPool:
     """Replicas of one model across devices, placed by least work.
 
     ``factory`` builds a fresh executor per device (e.g.
-    ``lambda: compile_graph(graph, ...)``); when only ``model`` is given
-    the pool has that single replica (the CPU case). The first replica
-    reuses ``model`` so single-device callers pay zero extra compiles.
+    ``lambda: compile_graph(graph, ...)``), called with that device as
+    JAX's default; when only ``model`` is given the pool has that single
+    replica. The first replica reuses ``model`` so single-device callers
+    pay zero extra compiles.
     """
 
     def __init__(self, model=None, *,
@@ -122,7 +126,12 @@ class ReplicaPool:
                 "per-instance) — pass factory=lambda: compile_graph(...)")
         self.replicas: List[Replica] = []
         for i, dev in enumerate(devices):
-            m = model if (i == 0 and model is not None) else factory()
+            if i == 0 and model is not None:
+                m = model
+            else:
+                with (contextlib.nullcontext() if dev is None
+                      else jax.default_device(dev)):
+                    m = factory()
             self.replicas.append(Replica(index=i, model=m, device=dev))
 
     @property

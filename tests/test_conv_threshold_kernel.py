@@ -99,7 +99,7 @@ def _check_all_paths(rng, h, w, c, f, k, stride, padding, bits, ties,
     np.testing.assert_array_equal(np.asarray(y_k), y_d)
 
 
-@settings(max_examples=12)
+@settings(max_examples=12, deadline=None)
 @given(
     st.sampled_from([1, 3, 5]),          # kernel
     st.sampled_from([1, 2]),             # stride
@@ -133,6 +133,38 @@ def test_direct_conv_every_boundary_is_a_tie():
     rng = np.random.default_rng(7)
     _check_all_paths(rng, 6, 6, 2, 3, 3, 1, "SAME", 2, True)
     _check_all_paths(rng, 8, 6, 4, 3, 5, 1, "VALID", 3, True)
+
+
+@pytest.mark.parametrize("k,stride,padding", [(4, 4, "SAME"),
+                                               (3, 2, "SAME"),
+                                               (2, 3, "VALID"),
+                                               (5, 2, "VALID")])
+@pytest.mark.parametrize("operand,x_offset", [("bf16", 0), ("int8", 128)])
+def test_strided_conv_kernel_is_exact(k, stride, padding, operand, x_offset):
+    """Strided convs run as stride-1 convs over pixel blocks
+    (``space_to_depth``), on unsigned 8-bit codes in either exact MXU
+    form; IC's stride-4 layer is the first case."""
+    from repro.core.streamline import multi_threshold
+    from repro.kernels import ops
+    from repro.kernels.conv_threshold import direct_conv_acc, same_pads
+
+    rng = np.random.default_rng(k * 10 + stride)
+    h, c, f = 13, 3, 5
+    oh = _conv_out_hw(h, h, k, stride, padding)[0]
+    x = jnp.asarray(rng.integers(0, 256, (2, h, h, c)), jnp.int32)
+    w2d = jnp.asarray(rng.integers(-127, 128, (k * k * c, f)), jnp.int8)
+    thr = jnp.asarray(np.sort(rng.integers(-60000, 60000, (f, 7)), axis=1),
+                      jnp.int32)
+    pads = same_pads(h, h, oh, oh, stride, k) if padding == "SAME" \
+        else ((0, 0), (0, 0))
+    acc = direct_conv_acc(jnp.pad(x, ((0, 0), pads[0], pads[1], (0, 0))),
+                          w2d, kernel=k, stride=stride, out_h=oh, out_w=oh)
+    y = ops.conv_threshold(x, w2d, thr, kernel=k, stride=stride,
+                           padding=padding, out_h=oh, out_w=oh,
+                           operand=operand, x_offset=x_offset,
+                           interpret=True)
+    np.testing.assert_array_equal(np.asarray(y),
+                                  np.asarray(multi_threshold(acc, thr)))
 
 
 def test_plan_conv_blocks_shapes():

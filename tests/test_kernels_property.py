@@ -30,7 +30,7 @@ def test_qmatmul_property(m, k, n, block, relu, seed):
     w = jnp.asarray(rng.integers(-127, 128, (k, n)).astype(np.int8))
     s = jnp.asarray(rng.uniform(1e-3, 1e-2, n).astype(np.float32))
     y = ops.qmatmul(x, w, s, None, relu=relu,
-                    block_m=block, block_n=block, block_k=block)
+                    block_m=block, block_n=block, block_k=block, interpret=True)
     yr = qmatmul_ref(x, w, s, None, relu=relu)
     np.testing.assert_allclose(np.asarray(y), np.asarray(yr),
                                rtol=1e-5, atol=1e-5)
@@ -46,7 +46,7 @@ def test_multi_threshold_property(m, c, steps, seed):
     acc = jnp.asarray(rng.integers(-10_000, 10_000, (m, c)).astype(np.int32))
     thr = jnp.asarray(np.sort(rng.integers(-9_000, 9_000, (c, steps)), axis=1)
                       .astype(np.int32))
-    y = ops.multi_threshold(acc, thr, block_m=16)
+    y = ops.multi_threshold(acc, thr, block_m=16, interpret=True)
     np.testing.assert_array_equal(np.asarray(y),
                                   np.asarray(multi_threshold_ref(acc, thr)))
 
@@ -63,7 +63,8 @@ def test_threshold_matmul_property(m, k, n, steps, seed):
     thr = jnp.asarray(
         np.sort(rng.integers(-40_000, 40_000, (n, steps)), axis=1)
         .astype(np.int32))
-    y = ops.threshold_matmul(x, w, thr, block_m=16, block_n=16, block_k=16)
+    y = ops.threshold_matmul(x, w, thr, block_m=16, block_n=16, block_k=16,
+                             interpret=True)
     np.testing.assert_array_equal(
         np.asarray(y), np.asarray(threshold_matmul_ref(x, w, thr)))
 
@@ -83,7 +84,8 @@ def test_flash_attention_property(b, heads, s, d, causal, seed):
     q = jnp.asarray(rng.standard_normal((b, h, s, d)).astype(np.float32))
     k = jnp.asarray(rng.standard_normal((b, hkv, s, d)).astype(np.float32))
     v = jnp.asarray(rng.standard_normal((b, hkv, s, d)).astype(np.float32))
-    o = ops.flash_attention(q, k, v, causal=causal, block_q=16, block_k=16)
+    o = ops.flash_attention(q, k, v, causal=causal, block_q=16, block_k=16,
+                            interpret=True)
     orf = flash_attention_ref(q, k, v, causal=causal)
     np.testing.assert_allclose(np.asarray(o), np.asarray(orf),
                                rtol=3e-5, atol=3e-5)

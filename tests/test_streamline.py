@@ -148,5 +148,5 @@ def test_streamlined_stage_runs_on_pallas_kernel():
     y_graph = apply_threshold_dense(stage, x_int)
     y_kernel = ops.threshold_matmul(
         x_int.astype(jnp.int8), stage.w_int, stage.thresholds,
-        block_m=8, block_n=8, block_k=8)
+        block_m=8, block_n=8, block_k=8, interpret=True)
     np.testing.assert_array_equal(np.asarray(y_kernel), np.asarray(y_graph))
