@@ -46,7 +46,7 @@ import numpy as np
 from repro.core.dataflow import micro_batch_stage, optimize_fifo_depths
 from repro.core.qir import Graph
 from repro.obs import timer as obs_timer
-from repro.obs.tracer import NULL_TRACER
+from repro.obs.tracer import NULL_TRACER, phase
 from repro.deploy.lower import (
     FlattenStage,
     FloatHeadStage,
@@ -139,8 +139,9 @@ class CompiledTinyModel:
         #: measured megakernel-vs-staged choice lands here via apply_tuned.
         self.megakernel = megakernel
         self.megakernel_budget_bytes = megakernel_budget_bytes
-        #: obs.Tracer sink for segment/stage spans and FIFO occupancy
-        #: counters; NULL_TRACER keeps every instrumentation site a no-op
+        #: obs.Tracer sink for the wave phases (``executor.pad``,
+        #: ``executor.launch``), ``stage`` probe spans and FIFO occupancy
+        #: counters; NULL_TRACER keeps every span site a no-op
         self.tracer = tracer if tracer is not None else NULL_TRACER
         self._rebuild()
 
@@ -476,27 +477,30 @@ class CompiledTinyModel:
         padded-wave tests assert.
         """
         mb = int(micro_batch) if micro_batch else self.default_micro_batch
-        xb = np.asarray(x_int)
-        n = xb.shape[0]
-        if n > mb:
-            raise ValueError(f"wave of {n} rows exceeds micro_batch={mb}")
-        mask = np.ones(n, bool) if valid is None \
-            else np.asarray(valid, bool).reshape(-1)
-        if mask.shape[0] != n:
-            raise ValueError(f"valid mask has {mask.shape[0]} entries "
-                             f"for a wave of {n} rows")
-        mask = np.concatenate([mask, np.zeros(mb - n, bool)])
-        # pad + zero invalid rows on the HOST: the device only ever sees
-        # the one constant (1, mb, ...) wave shape, so a lane serving
-        # every fill level reuses a single compiled program — eager
-        # device-side padding would trace a new program per fill level,
-        # which is a mid-serve compile stall (a measured 20x wave-time
-        # tail before this was moved host-side)
-        buf = np.zeros((mb,) + xb.shape[1:], xb.dtype)
-        buf[:n][mask[:n]] = xb[mask[:n]]
-        wave = jax.device_put(buf[None], self.device)
+        with phase(self.tracer, "executor.pad", cat="executor"):
+            xb = np.asarray(x_int)
+            n = xb.shape[0]
+            if n > mb:
+                raise ValueError(
+                    f"wave of {n} rows exceeds micro_batch={mb}")
+            mask = np.ones(n, bool) if valid is None \
+                else np.asarray(valid, bool).reshape(-1)
+            if mask.shape[0] != n:
+                raise ValueError(f"valid mask has {mask.shape[0]} entries "
+                                 f"for a wave of {n} rows")
+            mask = np.concatenate([mask, np.zeros(mb - n, bool)])
+            # pad + zero invalid rows on the HOST: the device only ever
+            # sees the one constant (1, mb, ...) wave shape, so a lane
+            # serving every fill level reuses a single compiled program —
+            # eager device-side padding would trace a new program per fill
+            # level, which is a mid-serve compile stall (a measured 20x
+            # wave-time tail before this was moved host-side)
+            buf = np.zeros((mb,) + xb.shape[1:], xb.dtype)
+            buf[:n][mask[:n]] = xb[mask[:n]]
+            wave = jax.device_put(buf[None], self.device)
         try:
-            wave = self._run_segments(wave, 1, mode="submit_wave")
+            with phase(self.tracer, "executor.launch", cat="executor"):
+                y = self._run_segments(wave, 1)[0]
         except Exception as e:
             # raw backend/runtime exceptions must not escape the serving
             # entry point untyped: wrap them so the router's failure
@@ -510,18 +514,14 @@ class CompiledTinyModel:
             raise WaveError(
                 f"wave of {n}/{mb} rows failed in the compiled segment "
                 f"pipeline: {type(e).__name__}: {e}") from e
-        return wave[0], mask
+        return y, mask
 
-    def _run_segments(self, wave, n_micro: int, mode: str):
-        """Push a stacked wave through every segment program, recording one
-        ``segment`` span per segment when a tracer is installed. Spans
-        measure host-side dispatch (tid = segment index + 1); on CPU, where
-        XLA dispatch is effectively synchronous, that is the execution time
-        — on accelerators the wave-level span (router) is the honest
-        end-to-end number."""
-        tr = self.tracer
+    def _run_segments(self, wave, n_micro: int):
+        """Push a stacked wave through every segment program: one jit
+        program per compiled segment, the fallback interpreter per
+        micro-batch at a host boundary. Under async dispatch this returns
+        once every program is dispatched, not when the device is done."""
         for k, seg in enumerate(self.segments):
-            t0 = obs_timer.now() if tr.enabled else 0.0
             if seg.compiled:
                 wave = self._segment_fn(k)(wave)
             else:
@@ -530,13 +530,6 @@ class CompiledTinyModel:
                 for si in range(seg.start, seg.stop):
                     outs = [self._stage_fns[si](h) for h in outs]
                 wave = jnp.stack(outs)
-            if tr.enabled:
-                tr.add_span("segment", t0, obs_timer.now(), cat="executor",
-                            tid=k + 1,
-                            args={"segment": k, "mode": mode,
-                                  "compiled": bool(seg.compiled),
-                                  "megakernel": k in self._mega_plans,
-                                  "stages": [seg.start, seg.stop]})
         return wave
 
     # -- streaming, compiled (the deployment hot path) ---------------------
@@ -603,8 +596,9 @@ class CompiledTinyModel:
         x_int, n, n_micro = self._pad_micro(x_int, mb)
         depths, sim_cycles = self.plan_streaming(n_micro, micro_batch=mb)
         wave = x_int.reshape((n_micro, mb) + x_int.shape[1:])
-        wave = self._run_segments(wave, n_micro, mode="streaming_compiled")
-        y = wave.reshape((n_micro * mb,) + wave.shape[2:])[:n]
+        with phase(self.tracer, "executor.launch", cat="executor"):
+            wave = self._run_segments(wave, n_micro)
+            y = wave.reshape((n_micro * mb,) + wave.shape[2:])[:n]
         # no host queues to observe: report the FIFO model's occupancy
         # (depth = max occupancy + 1 by construction of the optimizer)
         return y, StreamingStats(micro_batch=mb, n_micro=n_micro,

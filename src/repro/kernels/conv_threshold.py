@@ -221,6 +221,9 @@ def conv_threshold(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
+        # the HLO instruction, and so the device op in a profiler trace,
+        # is named ``conv_threshold.N`` after this, whatever wraps the call
+        name="conv_threshold",
     )(x_band, w_taps, thr_t)
 
 

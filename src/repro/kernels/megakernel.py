@@ -133,6 +133,9 @@ def mlp_megakernel(x_int: jnp.ndarray,
             dimension_semantics=("arbitrary",),
         ),
         interpret=interpret,
+        # the HLO instruction, and so the device op in a profiler trace,
+        # is named ``mlp_megakernel.N`` after this, whatever wraps the call
+        name="mlp_megakernel",
     )(to_operand(x_int, operands[0], offsets[0]), *w_ops, *thr_t)
 
 

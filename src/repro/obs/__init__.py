@@ -42,4 +42,6 @@ from repro.obs.tracer import (  # noqa: F401
     NullTracer,
     TraceEvent,
     Tracer,
+    phase,
+    wave_scope,
 )

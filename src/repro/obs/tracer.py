@@ -10,7 +10,8 @@ runtime. Every layer records into one ``Tracer``:
     ``tracer.span(...)`` is a context manager; ``add_span`` records a
     finished interval from explicit timestamps (how the router records a
     request's arrival→completion after the fact).
-  * **instants** — point events (``enqueue``, ``admit``, ``shed``).
+  * **instants** — point events (``shed``, ``wave_failed``,
+    ``replica_health``).
   * **counters** — time series (queue backlog, FIFO occupancy, replica
     outstanding work) rendered as counter tracks.
 
@@ -25,6 +26,17 @@ across runs.
 ``NULL_TRACER`` is the default everywhere: a ``NullTracer`` whose methods
 are no-ops returning shared singletons, so the disabled path costs one
 attribute lookup and an empty call — nothing allocates, nothing locks.
+
+The phases of one wave's host shell (``replica.put``, ``executor.pad``,
+``executor.launch``, ``wave.wait``, ``router.fetch``) each have one
+instrumentation site, ``phase``: it opens a ``jax.profiler``
+annotation of the phase's name, always, and records a span when the
+tracer is enabled. The router opens ``wave_scope`` while it submits,
+waits on or settles a wave: a ``wave`` annotation carrying the wave's id
+as metadata, and the tracer's parent link, which names the wave (and its
+track) on every phase span recorded inside. Spans then pair by id, never
+by time (waves overlap under an async engine), and the profiler's trace
+holds the same phases on the clock of its device operations.
 """
 
 from __future__ import annotations
@@ -33,6 +45,8 @@ import collections
 import dataclasses
 import threading
 from typing import Dict, List, Optional
+
+from jax.profiler import TraceAnnotation
 
 from repro.obs import timer as _timer
 
@@ -133,6 +147,15 @@ class Tracer:
         self._lock = threading.Lock()
         self._seq = 0
         self.n_dropped = 0
+        #: per thread: (wave id, pid, tid) of the wave ``wave_scope`` has
+        #: open, or None
+        self._link = threading.local()
+
+    @property
+    def link(self) -> Optional[tuple]:
+        """The parent link phase spans record: (wave id, pid, tid) of the
+        wave this thread is working on, None outside ``wave_scope``."""
+        return getattr(self._link, "wave", None)
 
     # -- time --------------------------------------------------------------
     def now(self) -> float:
@@ -224,6 +247,7 @@ class NullTracer:
     enabled = False
     capacity = 0
     n_dropped = 0
+    link = None
 
     def now(self) -> float:
         return 0.0
@@ -262,3 +286,74 @@ class NullTracer:
 
 #: The shared default NullTracer instance.
 NULL_TRACER = NullTracer()
+
+
+class _Phase:
+    """``phase``'s context manager: the profiler annotation always, the
+    tracer span only when the tracer is enabled."""
+
+    __slots__ = ("_tracer", "_ann", "name", "cat", "t0")
+
+    def __init__(self, tracer, name: str, cat: str):
+        self._tracer = tracer
+        self._ann = TraceAnnotation(name)
+        self.name, self.cat = name, cat
+        self.t0 = 0.0
+
+    def __enter__(self) -> "_Phase":
+        self._ann.__enter__()
+        if self._tracer.enabled:
+            self.t0 = self._tracer.now()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        tr = self._tracer
+        if tr.enabled:
+            wave, pid, tid = tr.link or (None, 0, 0)
+            tr.add_span(self.name, self.t0, tr.now(), cat=self.cat,
+                        pid=pid, tid=tid,
+                        args=None if wave is None else {"wave": wave})
+        self._ann.__exit__(*exc)
+        return False
+
+
+def phase(tracer, name: str, cat: str = "") -> _Phase:
+    """Time one phase of a wave: ``with phase(tr, "executor.pad"): ...``.
+
+    Opens a ``jax.profiler.TraceAnnotation`` named ``name`` (a profiler
+    trace then shows the phase beside the device's operations; with no
+    profiler running it costs about a microsecond) and, when ``tracer``
+    is enabled, records a span of the same name. Inside ``wave_scope``
+    the span carries its wave's id (``args["wave"]``) and track."""
+    return _Phase(tracer, name, cat)
+
+
+class _WaveScope:
+    __slots__ = ("_tracer", "_ann", "_link", "_prev")
+
+    def __init__(self, tracer, wave: int, pid: int, tid: int):
+        self._tracer = tracer
+        self._ann = TraceAnnotation("wave", wave_id=wave)
+        self._link = (wave, pid, tid)
+        self._prev = None
+
+    def __enter__(self) -> "_WaveScope":
+        self._ann.__enter__()
+        if self._tracer.enabled:
+            self._prev = self._tracer.link
+            self._tracer._link.wave = self._link
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        if self._tracer.enabled:
+            self._tracer._link.wave = self._prev
+        self._ann.__exit__(*exc)
+        return False
+
+
+def wave_scope(tracer, wave: int, pid: int = 0, tid: int = 0) -> _WaveScope:
+    """Work on wave ``wave``: a ``wave`` profiler annotation with
+    ``wave_id`` as metadata, and the tracer's parent link, so each
+    ``phase`` span inside names the wave and lands on its (pid, tid)
+    track."""
+    return _WaveScope(tracer, wave, pid, tid)

@@ -3,7 +3,7 @@
 Every serve component reads time through one of these objects instead of
 the ``time`` module, so the whole server — batching deadlines, SLO
 estimates, sliding-window metrics, trace replay — runs identically under
-the real monotonic clock and under a test-controlled manual clock (the
+the real clock and under a test-controlled manual clock (the
 same trick ``tests/test_scenarios.py`` plays on the scenario runtime, made
 first-class here because the router's correctness *is* its timing).
 """
@@ -14,10 +14,11 @@ import time
 
 
 class SystemClock:
-    """The real monotonic clock."""
+    """The real clock: ``perf_counter``, the source ``obs.timer`` reads, so
+    the router's stamps and the tracer's spans share one clock."""
 
     def now(self) -> float:
-        return time.monotonic()
+        return time.perf_counter()
 
     def sleep(self, seconds: float) -> None:
         if seconds > 0:

@@ -36,6 +36,7 @@ from __future__ import annotations
 
 from typing import Optional, Tuple
 
+from repro.obs.tracer import NULL_TRACER, phase
 from repro.serve.faults import WaveTimeout
 
 
@@ -108,17 +109,19 @@ class WaveHandle:
             raise WaveTimeout(
                 f"wave on replica {getattr(self.replica, 'index', '?')} "
                 "was cancelled past its deadline")
-        if self._inner is not None:
-            y, mask = self._inner.wait()
-            self.done_t = getattr(self._inner, "done_t", self.ready_t)
-        else:
-            y, mask = self._y, self._mask
-            try:
-                import jax
+        with phase(getattr(self.replica, "tracer", NULL_TRACER),
+                   "wave.wait", cat="replica"):
+            if self._inner is not None:
+                y, mask = self._inner.wait()
+                self.done_t = getattr(self._inner, "done_t", self.ready_t)
+            else:
+                y, mask = self._y, self._mask
+                try:
+                    import jax
 
-                y = jax.block_until_ready(y)
-            except ImportError:  # pragma: no cover - jax is a hard dep
-                pass
+                    y = jax.block_until_ready(y)
+                except ImportError:  # pragma: no cover - jax is a hard dep
+                    pass
         self._result = (y, mask)
         return self._result
 

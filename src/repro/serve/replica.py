@@ -31,7 +31,7 @@ from typing import Callable, List, Optional, Sequence
 import jax
 import numpy as np
 
-from repro.obs.tracer import NULL_TRACER
+from repro.obs.tracer import NULL_TRACER, phase
 from repro.serve.dispatch import WaveHandle
 from repro.serve.faults import NoReplicaAvailable
 
@@ -61,6 +61,8 @@ class Replica:
     n_failures: int = 0           # consecutive failures since last success
     last_failure: str = ""        # reason string of the latest failure
     next_probe_t: float = 0.0     # quarantined: when the probe wave is due
+    #: the serving router's tracer (``ReplicaPool.tracer`` hands it down)
+    tracer: object = dataclasses.field(default=NULL_TRACER, repr=False)
 
     def submit(self, x, valid=None, micro_batch: Optional[int] = None
                ) -> WaveHandle:
@@ -74,7 +76,8 @@ class Replica:
         the wave is in flight, not done, until the handle's ``wait``.
         """
         if self.device is not None:
-            x = jax.device_put(np.asarray(x), self.device)
+            with phase(self.tracer, "replica.put", cat="replica"):
+                x = jax.device_put(np.asarray(x), self.device)
         submit_async = getattr(self.model, "submit_wave_async", None)
         if submit_async is not None:
             inner = submit_async(x, valid=valid, micro_batch=micro_batch)
@@ -115,10 +118,9 @@ class ReplicaPool:
             devices = jax.devices() if factory is not None else [None]
         if not devices:
             raise ValueError("no devices to place replicas on")
-        #: per-replica outstanding-work counter sink; the router installs
-        #: its tracer here so placement decisions show up as counter
-        #: tracks (pid 1+i = replica i in the exported timeline)
-        self.tracer = NULL_TRACER
+        #: the tracer of the router serving this pool (``tracer``); the
+        #: models keep their own until a router installs one
+        self._tracer = NULL_TRACER
         if len(devices) > 1 and factory is None:
             raise ValueError(
                 f"{len(devices)} devices but no factory: replicas beyond "
@@ -133,6 +135,26 @@ class ReplicaPool:
                       else jax.default_device(dev)):
                     m = factory()
             self.replicas.append(Replica(index=i, model=m, device=dev))
+
+    @property
+    def tracer(self):
+        """Sink of the per-replica ``outstanding_s`` counters (pid 1+i =
+        replica i in the exported timeline) and the health events."""
+        return self._tracer
+
+    @tracer.setter
+    def tracer(self, tracer) -> None:
+        """Install ``tracer`` (None = ``NULL_TRACER``) on the pool, every
+        replica and every replica's model that takes one (``set_tracer``),
+        so whatever an earlier router left installed records nothing
+        more."""
+        tracer = tracer if tracer is not None else NULL_TRACER
+        self._tracer = tracer
+        for r in self.replicas:
+            r.tracer = tracer
+            set_tracer = getattr(r.model, "set_tracer", None)
+            if set_tracer is not None:
+                set_tracer(tracer)
 
     @property
     def default_micro_batch(self) -> int:

@@ -61,7 +61,7 @@ from typing import Callable, Deque, Dict, FrozenSet, List, Optional, Union
 
 import numpy as np
 
-from repro.obs.tracer import NULL_TRACER
+from repro.obs.tracer import NULL_TRACER, phase, wave_scope
 from repro.serve.clock import SystemClock
 from repro.serve.dispatch import DispatchEngine, SyncEngine, WaveHandle
 from repro.serve.faults import (
@@ -322,8 +322,9 @@ class Router:
                 else ReplicaPool(model)
             if cfg.probe_interval_ms is not None:
                 pool.probe_interval_s = cfg.probe_interval_ms / 1e3
-            if self.tracer.enabled:
-                pool.tracer = self.tracer
+            # always, NULL_TRACER included: a pool shared with an earlier
+            # router must stop recording into that router's tracer
+            pool.tracer = self.tracer
             service = (service_models or {}).get(name)
             slo = None
             if cfg.p99_budget_ms is not None:
@@ -358,9 +359,6 @@ class Router:
                            arrival_t=now)
         self._uid += 1
         tr = self.tracer
-        if tr.enabled:
-            tr.instant("enqueue", t=now, cat="router", tid=lane.tid,
-                       uid=req.uid, model=model)
         if lane.slo is not None:
             lane.slo.observe_arrival(now)
             # waves this request must wait out: the ceiling form prices
@@ -398,10 +396,6 @@ class Router:
                 return req
         lane.metrics.record_admit(now)
         lane.pending.append(req)
-        if tr.enabled:
-            tr.instant("admit", t=now, cat="router", tid=lane.tid,
-                       uid=req.uid, model=model)
-            tr.counter("backlog", len(lane.pending), t=now, tid=lane.tid)
         if lane.cfg.auto_dispatch:
             while len(lane.pending) >= lane.micro_batch:
                 self._dispatch(lane, lane.micro_batch)
@@ -439,10 +433,9 @@ class Router:
         mb = lane.micro_batch
         work_s = lane.work_estimate_s()
         tr = self.tracer
-        if tr.enabled:
-            tr.instant("wave_assemble", cat="router", tid=lane.tid,
-                       model=lane.name, n_valid=n)
         now = self.clock.now()
+        if tr.enabled:
+            tr.counter("backlog", len(lane.pending), t=now, tid=lane.tid)
         try:
             replica = lane.pool.place(work_s, now=now, exclude=exclude)
         except NoReplicaAvailable as e:
@@ -458,9 +451,14 @@ class Router:
                     and self._inflight:
                 self._reap_one(block=True)
         xb = np.stack([r.x for r in reqs])
+        # the wave's id: its phase spans, its wave span and its requests'
+        # spans carry it, and the profiler's ``wave`` annotation too
+        self._wave_seq += 1
         t0 = self.clock.now()
         try:
-            handle = self.engine.submit(replica, xb, micro_batch=mb)
+            with wave_scope(tr, self._wave_seq, pid=1 + replica.index,
+                            tid=lane.tid):
+                handle = self.engine.submit(replica, xb, micro_batch=mb)
         except FaultError as e:
             # the submission itself was refused (crashed replica,
             # transient submit error): credit the placement charge back,
@@ -477,7 +475,6 @@ class Router:
             return 0
         replica.n_inflight += 1
         lane.n_inflight += 1
-        self._wave_seq += 1
         deadline_t = None
         timeout_s = lane.wave_deadline_s(work_s)
         if timeout_s is not None:
@@ -527,6 +524,12 @@ class Router:
         guard — goes to the retry path instead; returns the number of
         requests actually served (0 on failure)."""
         lane = wave.lane
+        with wave_scope(self.tracer, wave.seq, pid=1 + wave.replica.index,
+                        tid=lane.tid):
+            return self._run_completion(wave)
+
+    def _run_completion(self, wave: _InFlightWave) -> int:
+        lane = wave.lane
         try:
             y, mask = wave.handle.wait()
         except FaultError as e:
@@ -540,10 +543,15 @@ class Router:
         if done is None:
             done = self.clock.now()
         self._release(wave)
-        y = np.asarray(y)
-        mask = np.asarray(mask)
         n, mb = wave.n_valid, lane.micro_batch
-        if not (mask[:n].all() and not mask[n:].any()):
+        with phase(self.tracer, "router.fetch", cat="router"):
+            y = np.asarray(y)
+            mask = np.asarray(mask)
+            mask_ok = bool(mask[:n].all() and not mask[n:].any())
+            intact = mask_ok and (not lane.cfg.integrity_check
+                                  or wave_integrity_ok(y[:n],
+                                                       lane.output_bound))
+        if not mask_ok:
             # a bare assert here would vanish under ``python -O`` and let
             # an executor that mislabels its padding hand garbage rows to
             # clients — this is a result-integrity check, not a debug aid
@@ -552,8 +560,7 @@ class Router:
                 f"mask {mask.tolist()} for {n} valid rows in a wave of "
                 f"{mb} — padded rows must be masked out and valid rows "
                 "masked in (see the submit_wave padding contract)")
-        if lane.cfg.integrity_check \
-                and not wave_integrity_ok(y[:n], lane.output_bound):
+        if not intact:
             # corrupt output is a failure, not a contract bug: the wave is
             # retried on another replica, never served to a client
             self._after_failure(
@@ -579,7 +586,7 @@ class Router:
             # predicted-vs-measured training row per wave (obs.report)
             args = {"model": lane.name, "platform": self.platform,
                     "n_valid": n, "micro_batch": mb,
-                    "replica": wave.replica.index}
+                    "replica": wave.replica.index, "wave": wave.seq}
             if lane.service is not None:
                 args["predicted_ms"] = \
                     lane.service.wave_service_s(mb) * 1e3
@@ -591,12 +598,13 @@ class Router:
             tr.add_span("wave", wave.t0, done, cat="router",
                         pid=1 + wave.replica.index, tid=lane.tid, args=args)
             for r in wave.reqs:
-                # request span: arrival (enqueue) -> completion; duration
+                # request span: arrival -> completion; duration
                 # is exactly the latency ServeMetrics recorded, so
                 # span-derived percentiles match snapshots to the bit
                 tr.add_span("request", r.arrival_t, done, cat="router",
                             tid=lane.tid,
-                            args={"uid": r.uid, "model": lane.name})
+                            args={"uid": r.uid, "model": lane.name,
+                                  "wave": wave.seq})
             tr.counter("backlog", len(lane.pending), t=done, tid=lane.tid)
             tr.counter("wave_occupancy", n / max(mb, 1), t=done,
                        tid=lane.tid)

@@ -131,7 +131,7 @@ def test_span_context_manager_records_clock_interval():
 
 def test_instant_counter_and_filters():
     tr = Tracer(clock=ManualClock())
-    tr.instant("enqueue", t=1.0, cat="router", uid=7)
+    tr.instant("shed", t=1.0, cat="router", uid=7)
     tr.counter("backlog", 3, t=1.5, cat="router")
     tr.add_span("wave", 1.0, 2.0, cat="exec")
     assert len(tr) == 3
@@ -280,7 +280,10 @@ def test_manual_clock_runs_export_byte_identically(tmp_path):
     assert b1 == b2
     doc = json.loads(b1)
     assert doc["otherData"]["n_dropped"] == 0
-    assert {e["ph"] for e in doc["traceEvents"]} >= {"M", "X", "i", "C"}
+    # spans and counters; the router records no per-request instants
+    assert {e["ph"] for e in doc["traceEvents"]} == {"M", "X", "C"}
+    assert {e["name"] for e in doc["traceEvents"] if e["ph"] == "X"} == {
+        "request", "wave", "wave.wait", "router.fetch"}
     assert jsonl_lines(tr1) == jsonl_lines(tr2)
     j1 = export_jsonl(tr1, str(tmp_path / "a" / "t1.jsonl"))
     assert all(json.loads(line) for line in open(j1))

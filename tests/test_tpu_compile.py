@@ -143,3 +143,30 @@ def test_ic_convs_compile(one_chip, kernel, stride, cin, cout, hw):
 def test_cnv_valid_convs_compile(one_chip, hw, cin, cout, operand):
     """CNV's 3x3 VALID convs: the 8-bit input layer and binary layers."""
     _conv(one_chip, 16, hw, cin, cout, 3, 1, "VALID", 1, operand, 0)
+
+
+def test_kernels_keep_the_names_the_trace_reducers_match(one_chip):
+    """Each Pallas kernel's HLO instruction, and so its device op in a
+    profiler trace, is ``<kernel>.N`` (``name=`` on the ``pallas_call``):
+    the benchmark's per-kernel reducers match that stem."""
+    import re
+
+    def names(compiled):
+        return set(re.findall(
+            r"%([\w.-]+) = \S+ custom-call\([^\n]*tpu_custom_call",
+            compiled.as_text()))
+
+    conv = _compile(one_chip, lambda x, w, t: ops.conv_threshold(
+        x, w, t, kernel=3, stride=1, padding="VALID", out_h=30, out_w=30,
+        operand="bf16", x_offset=0),
+        ((16, 32, 32, 3), I32), ((27, 64), I8), ((64, 1), I32))
+    mega = _compile(one_chip, lambda x, w0, w1, t0, t1: ops.mlp_megakernel(
+        x, (w0, w1), (t0, t1), operands=("bf16", "bf16"), offsets=(0, 0),
+        block_m=128),
+        ((128, 490), I32), ((490, 256), I8), ((256, 256), I8),
+        ((256, 7), I32), ((256, 7), I32))
+    for compiled, stem in ((conv, "conv_threshold"),
+                           (mega, "mlp_megakernel")):
+        got = names(compiled)
+        assert got and all(re.fullmatch(rf"{stem}\.\d+", n) for n in got), \
+            got
