@@ -140,8 +140,8 @@ class CompiledTinyModel:
         self.megakernel = megakernel
         self.megakernel_budget_bytes = megakernel_budget_bytes
         #: obs.Tracer sink for the wave phases (``executor.pad``,
-        #: ``executor.launch``), ``stage`` probe spans and FIFO occupancy
-        #: counters; NULL_TRACER keeps every span site a no-op
+        #: ``replica.put``, ``executor.launch``), ``stage`` probe spans and
+        #: FIFO occupancy counters; NULL_TRACER keeps every span site a no-op
         self.tracer = tracer if tracer is not None else NULL_TRACER
         self._rebuild()
 
@@ -475,9 +475,18 @@ class CompiledTinyModel:
         invalid row can perturb a valid one — stages are row-independent
         (matmul/conv/threshold act per sample), which the golden-model
         padded-wave tests assert.
+
+        Placement: the wave reaches ``self.device`` in ONE explicit
+        ``device_put`` (the ``replica.put`` phase) of the padded
+        ``(1, micro_batch, ...)`` host buffer; a full wave of valid rows is
+        put as it is, with no copy. Rows that arrive as a device array are
+        pulled back to the host first (correct, but a round trip): the
+        ``executor.pad`` span says so with ``pulled_back``, beside ``rows``
+        (valid), ``pad_rows`` (appended zero rows) and ``put_bytes``.
         """
         mb = int(micro_batch) if micro_batch else self.default_micro_batch
-        with phase(self.tracer, "executor.pad", cat="executor"):
+        with phase(self.tracer, "executor.pad", cat="executor") as ph:
+            pulled_back = isinstance(x_int, jax.Array)
             xb = np.asarray(x_int)
             n = xb.shape[0]
             if n > mb:
@@ -489,18 +498,29 @@ class CompiledTinyModel:
                 raise ValueError(f"valid mask has {mask.shape[0]} entries "
                                  f"for a wave of {n} rows")
             mask = np.concatenate([mask, np.zeros(mb - n, bool)])
-            # pad + zero invalid rows on the HOST: the device only ever
-            # sees the one constant (1, mb, ...) wave shape, so a lane
-            # serving every fill level reuses a single compiled program —
-            # eager device-side padding would trace a new program per fill
-            # level, which is a mid-serve compile stall (a measured 20x
-            # wave-time tail before this was moved host-side)
-            buf = np.zeros((mb,) + xb.shape[1:], xb.dtype)
-            buf[:n][mask[:n]] = xb[mask[:n]]
+            n_valid = int(mask.sum())
+            if n_valid == mb:
+                buf = xb
+            else:
+                # pad + zero invalid rows on the HOST: the device only
+                # ever sees the one constant (1, mb, ...) wave shape, so a
+                # lane serving every fill level reuses a single compiled
+                # program — eager device-side padding would trace a new
+                # program per fill level, which is a mid-serve compile
+                # stall (a measured 20x wave-time tail before this was
+                # moved host-side)
+                buf = np.zeros((mb,) + xb.shape[1:], xb.dtype)
+                buf[:n] = xb
+                buf[~mask] = 0
+            ph.set(rows=n_valid, pad_rows=mb - n, put_bytes=int(buf.nbytes),
+                   pulled_back=pulled_back)
+        with phase(self.tracer, "replica.put", cat="executor"):
             wave = jax.device_put(buf[None], self.device)
         try:
             with phase(self.tracer, "executor.launch", cat="executor"):
-                y = self._run_segments(wave, 1)[0]
+                # squeeze, not ``[0]``: an eager integer index sends its
+                # start index to the device, a second host transfer
+                y = jnp.squeeze(self._run_segments(wave, 1), 0)
         except Exception as e:
             # raw backend/runtime exceptions must not escape the serving
             # entry point untyped: wrap them so the router's failure

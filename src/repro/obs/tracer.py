@@ -292,13 +292,21 @@ class _Phase:
     """``phase``'s context manager: the profiler annotation always, the
     tracer span only when the tracer is enabled."""
 
-    __slots__ = ("_tracer", "_ann", "name", "cat", "t0")
+    __slots__ = ("_tracer", "_ann", "name", "cat", "t0", "args")
 
     def __init__(self, tracer, name: str, cat: str):
         self._tracer = tracer
         self._ann = TraceAnnotation(name)
         self.name, self.cat = name, cat
         self.t0 = 0.0
+        self.args = None
+
+    def set(self, **kwargs) -> "_Phase":
+        """Attach args to the span (the padding's row counts); nothing is
+        kept when the tracer is off."""
+        if self._tracer.enabled:
+            self.args = {**(self.args or {}), **kwargs}
+        return self
 
     def __enter__(self) -> "_Phase":
         self._ann.__enter__()
@@ -310,9 +318,11 @@ class _Phase:
         tr = self._tracer
         if tr.enabled:
             wave, pid, tid = tr.link or (None, 0, 0)
+            args = self.args
+            if wave is not None:
+                args = {"wave": wave, **(args or {})}
             tr.add_span(self.name, self.t0, tr.now(), cat=self.cat,
-                        pid=pid, tid=tid,
-                        args=None if wave is None else {"wave": wave})
+                        pid=pid, tid=tid, args=args)
         self._ann.__exit__(*exc)
         return False
 
@@ -324,7 +334,8 @@ def phase(tracer, name: str, cat: str = "") -> _Phase:
     trace then shows the phase beside the device's operations; with no
     profiler running it costs about a microsecond) and, when ``tracer``
     is enabled, records a span of the same name. Inside ``wave_scope``
-    the span carries its wave's id (``args["wave"]``) and track."""
+    the span carries its wave's id (``args["wave"]``) and track; the
+    handle's ``set(**args)`` adds args of its own."""
     return _Phase(tracer, name, cat)
 
 

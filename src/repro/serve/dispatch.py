@@ -11,8 +11,8 @@ and the second one is what this module makes injectable:
     stays bit-identical.
 
   * ``AsyncEngine`` — submit and return. ``Replica.submit`` launches the
-    wave (``device_put`` + ``submit_wave``; JAX's async dispatch means the
-    returned arrays are promises, not results) and hands back a
+    wave (``submit_wave`` puts it on the device; JAX's async dispatch
+    means the returned arrays are promises, not results) and hands back a
     ``WaveHandle``; the router parks it in an in-flight table and *reaps*
     completions on its next event-loop pass. Waves on different replicas
     overlap — the pool finally runs as wide as it is — and each replica is

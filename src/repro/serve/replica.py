@@ -8,16 +8,20 @@ device, and the pool places each wave on the replica with the least
 outstanding modeled work — the queueing-theory argument for
 join-shortest-queue over round-robin under heterogeneous wave sizes.
 
-Wave execution is split into ``submit`` (``device_put`` + ``submit_wave``;
-JAX's async dispatch makes the returned arrays promises, so this does not
-block) and the returned ``WaveHandle``'s ``wait`` — the seam the dispatch
-engines (``serve.dispatch``) are built on. ``run_wave`` remains as the
-blocking submit-then-wait composition.
+Wave execution is split into ``submit`` (``submit_wave``; JAX's async
+dispatch makes the returned arrays promises, so this does not block) and
+the returned ``WaveHandle``'s ``wait`` — the seam the dispatch engines
+(``serve.dispatch``) are built on. ``run_wave`` remains as the blocking
+submit-then-wait composition.
 
 Each replica's executor is built under ``jax.default_device`` of its
 device, so a ``compile_graph`` factory places that replica's weights and
-programs there (``deploy.executor.CompiledTinyModel``). With one device
-the pool is a single replica; the placement/overlap logic is also
+programs there (``deploy.executor.CompiledTinyModel``), and the executor
+places each wave there itself: the replica hands it the host rows, which
+cross to the device once, in the executor's ``replica.put`` phase. A
+model without a ``device`` attribute (a fake, an adapter) has its rows
+put on the replica's device by the replica, in the same phase. With one
+device the pool is a single replica; the placement/overlap logic is also
 exercised by the tests through fake executors (a fake exposing
 ``submit_wave_async`` can script completion times against a manual clock).
 """
@@ -74,8 +78,12 @@ class Replica:
         ``wait()``); otherwise calls ``submit_wave`` directly — under JAX
         async dispatch that call returns unmaterialized device arrays, so
         the wave is in flight, not done, until the handle's ``wait``.
+
+        A model with a ``device`` attribute places its own waves and gets
+        the host rows as they are; any other model gets them on this
+        replica's device.
         """
-        if self.device is not None:
+        if self.device is not None and not hasattr(self.model, "device"):
             with phase(self.tracer, "replica.put", cat="replica"):
                 x = jax.device_put(np.asarray(x), self.device)
         submit_async = getattr(self.model, "submit_wave_async", None)
@@ -90,6 +98,19 @@ class Replica:
         """Run one padded wave and block until the result is ready, so the
         caller's clock reading is the completion (the sync-engine path)."""
         return self.submit(x, valid=valid, micro_batch=micro_batch).wait()
+
+
+def _bind_device(model, dev) -> None:
+    """Make a model that places its own waves (``model.device``) place
+    them on its replica's device: an unpinned model (``device is None``)
+    adopts ``dev``; one pinned elsewhere is refused."""
+    if dev is None or not hasattr(model, "device"):
+        return
+    if model.device is None:
+        model.device = dev
+    elif model.device != dev:
+        raise ValueError(f"model is pinned to {model.device}, but its "
+                         f"replica is on {dev}")
 
 
 class ReplicaPool:
@@ -134,6 +155,7 @@ class ReplicaPool:
                 with (contextlib.nullcontext() if dev is None
                       else jax.default_device(dev)):
                     m = factory()
+            _bind_device(m, dev)
             self.replicas.append(Replica(index=i, model=m, device=dev))
 
     @property

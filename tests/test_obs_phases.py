@@ -185,6 +185,22 @@ def test_phase_spans_carry_the_open_wave_link_and_restore_it():
     assert NULL_TRACER.link is None and NULL_TRACER.events() == []
 
 
+def test_phase_args_join_the_wave_link():
+    tr = Tracer(clock=ManualClock())
+    with wave_scope(tr, 3):
+        with phase(tr, "executor.pad") as ph:
+            ph.set(rows=2, pulled_back=False)
+    with phase(tr, "executor.pad") as ph:
+        ph.set(rows=1)
+    linked, alone = tr.spans()
+    assert linked.args == {"wave": 3, "rows": 2, "pulled_back": False}
+    assert alone.args == {"rows": 1}
+    # with the tracer off nothing is kept
+    with phase(NULL_TRACER, "executor.pad") as ph:
+        ph.set(rows=2)
+    assert ph.args is None
+
+
 def test_router_records_no_per_request_events():
     tracer, router = _phased_run(SyncEngine())
     names = {e.name for e in tracer.events()}
@@ -244,7 +260,15 @@ def test_router_hands_its_tracer_down_and_a_later_router_clears_it(
         got = [s for s in spans if s.name == name]
         # the real executor's phases, one per wave, each naming its wave
         assert {s.args["wave"] for s in got} == waves, name
+        assert len(got) == len(waves), name
     assert not [s for s in spans if s.name == "segment"]
+    # the executor makes the wave's one put (``replica.put``), of the host
+    # rows the router stacked: nothing was pulled back to pad them
+    for s in spans:
+        if s.name == "executor.pad":
+            assert s.args == {"wave": s.args["wave"], "rows": 4,
+                              "pad_rows": 0, "put_bytes": 4 * 490 * 4,
+                              "pulled_back": False}
     n_first = len(first)
 
     # a router built without a tracer over the same pool installs
