@@ -5,8 +5,10 @@
 
 With no option it builds KWS (490 -> 256 x 3 -> 12) at its published width
 from seeded weights, compiles it on the Pallas kernel path, serves a few
-hundred requests through ``Router`` + ``ReplicaPool`` on one device, and
-runs one full-width offline wave each of AD, IC and CNV. With
+hundred requests through ``Router`` + ``ReplicaPool`` on one device, runs
+one full-width offline wave each of AD, IC and CNV, and one 256-row wave
+of ResNet-8 through a ``ReplicaPool`` (the benchmark's seeded weights,
+``chipbench/configs/resnet8.py``, whose codes stay spread). With
 ``--chips 4`` it runs only the replica path: KWS served by four one-chip
 replicas behind the router, compared with one replica, and each replica's
 outputs checked to live on its own device.
@@ -138,6 +140,39 @@ def one_chip(seed, n_requests, **kw):
               f"{t1 - t0:.2f} s, second wave {(t2 - t1) * 1e3:.3f} ms")
         _check_equal(f"{name} offline", y, _reference(graph, in_scale, x))
         _check_equal(f"{name} offline again", y2, y)
+    resnet8_wave(seed, **kw)
+
+
+def resnet8_wave(seed, rows=256, **kw):
+    """One full wave of ResNet-8 at its published widths through a
+    one-replica ``ReplicaPool``: three residual joins on the join kernel,
+    their skip values live inside the wave's program."""
+    from functools import partial
+
+    import jax
+
+    from repro.deploy import compile_graph
+    from repro.serve import ReplicaPool
+
+    sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
+    from chipbench import spec
+
+    cfg, mod = spec.config("resnet8"), spec.config_module("resnet8")
+    key = jax.random.wrap_key_data(np.array([0, seed], np.uint32))
+    params = jax.jit(partial(mod.make_params, cfg))(key)
+    graph, in_scale = mod.build_graph(cfg, params)
+    cm, t_build = _compile(graph, in_scale, **kw)
+    if cm.schedule.n_join != 3 or cm.host_segments:
+        raise SystemExit(f"FAIL resnet8: {cm.schedule.describe()}")
+    pool = ReplicaPool(cm, devices=jax.devices()[:1])
+    x = _queries(mod.input_shape(cfg), rows, seed + 2)
+    y, mask = pool.replicas[0].submit(x, micro_batch=rows).wait()
+    y = np.asarray(y)[np.asarray(mask)]
+    print(f"resnet8: lowered in {t_build:.2f} s, "
+          f"{cm.schedule.n_join} joins")
+    _check_equal("resnet8 wave", y, _reference(graph, in_scale, x))
+    if np.all(y == y[:1]):
+        raise SystemExit("FAIL resnet8: every row has the same logits")
 
 
 def four_chips(seed, n_requests, **kw):

@@ -8,9 +8,11 @@ JSON graph so the training flow (core/qlayers) and the deployment flow
 
   train (QAT)  --export-->  QIR json  --import-->  streamline/deploy
 
-Supported ops: Dense, Conv2D, MaxPool, Flatten, BatchNorm, Relu, Quant,
-MultiThreshold, TopK, Mul. Weights live in ``initializers`` (name -> ndarray,
-stored base64 in JSON).
+Supported ops: Dense, Conv2D, MaxPool, AvgPool, Flatten, BatchNorm, Relu,
+Quant, Add, MultiThreshold, TopK, Mul. Weights live in ``initializers``
+(name -> ndarray, stored base64 in JSON). A value may have more than one
+consumer (a residual block's input feeds its first conv and its ``Add``),
+so a graph is a DAG in topological node order, not only a chain.
 
 Quant node semantics (attrs select the flavor):
   * default             — dynamic min-max IntQuantizer (the QAT fake-quant)
@@ -177,6 +179,15 @@ def eval_node(node: Node, x: List):
         y = jax.lax.reduce_window(
             x[0], init, jax.lax.max, (1, win, win, 1), (1, stride, stride, 1),
             node.attrs.get("padding", "VALID"))
+    elif node.op == "AvgPool":
+        win = int(node.attrs.get("window", 2))
+        stride = int(node.attrs.get("stride", win))
+        y = jax.lax.reduce_window(
+            x[0], jnp.zeros((), x[0].dtype), jax.lax.add, (1, win, win, 1),
+            (1, stride, stride, 1), node.attrs.get("padding", "VALID")
+        ) / (win * win)
+    elif node.op == "Add":
+        y = x[0] + x[1]
     elif node.op == "Flatten":
         y = x[0].reshape(x[0].shape[0], -1)
     elif node.op == "Relu":
@@ -302,7 +313,7 @@ def _conv_out_hw(h: int, w: int, k: int, stride: int, padding: str):
 
 def export_qcnn(model, params, in_scale: float = 1.0 / 128.0, meta=None,
                 calibrate=None) -> Graph:
-    """Export a Table-1 conv model (``ICModel`` or ``CNVModel``) to QIR.
+    """Export a conv model (``ICModel``, ``CNVModel`` or ``ResNet8``) to QIR.
 
     Mirrors ``export_qmlp`` for the spatial models: every conv layer becomes a
     ``Conv2D -> [Relu] -> Quant`` chain with per-layer ``QuantSpec``s, plus
@@ -319,6 +330,10 @@ def export_qcnn(model, params, in_scale: float = 1.0 / 128.0, meta=None,
         ``w' = 2w, b' = b - sum(w)`` so arithmetic stays affine in the codes
         (its ``meta["in_scale"]`` is 1.0 — input codes are the values).
 
+    ``ResNet8`` exports its residual joins as ``Conv2D [Conv2D] -> Add ->
+    Relu -> Quant`` (``_export_resnet``): both branches' biases sit on the
+    finer of the two accumulator grids, so the sum stays exact too.
+
     ``in_scale`` is the float value of one step of the 8-bit input image
     (ignored for CNV); keep it a power of two for the exactness guarantee.
     ``calibrate`` (optional, multi-bit models) is a batch of integer input
@@ -327,6 +342,8 @@ def export_qcnn(model, params, in_scale: float = 1.0 / 128.0, meta=None,
     of the code range and costs accuracy (post-training static calibration
     is what the hls4ml flow does with its profiling pass).
     """
+    if hasattr(model, "residual_blocks"):
+        return _export_resnet(model, params, in_scale, meta)
     if getattr(model, "weight_bits", 8) == 1 and hasattr(model, "channels"):
         return _export_cnv(model, params, meta)
     if hasattr(model, "conv_layers"):
@@ -463,4 +480,130 @@ def _export_cnv(model, params, meta) -> Graph:
                                 attrs={"bipolar": True},
                                 quant=QuantSpec(bits=1, signed=False)))
             prev = f"f{j}_q"
+    return g
+
+
+def _export_resnet(model, params, in_scale: float, meta) -> Graph:
+    """Export ``models.tiny.ResNet8`` (the MLPerf Tiny IC reference).
+
+    BN folds into each conv (hls4ml's ``QConv2DBatchnorm``) before the
+    po2 per-channel weight quant; params without BN statistics are taken
+    as already folded. A plain conv is ``Conv2D -> Relu -> Quant``. A
+    block's second conv is a join: ``Conv2D(main) [-> Conv2D(1x1 proj of
+    the block input)] -> Add(main, skip) -> Relu -> Quant``, one activation
+    quantizer after the add and none on either branch. The two branches'
+    accumulator grids (``s_w * s_in`` per channel, ``s_in`` for an identity
+    skip) are powers of two; both biases are snapped to the finer of them,
+    so ``Graph.run``'s float sum is the integer sum on that grid, exactly.
+    Activation scales come from the worst-case reach bound, the join's
+    from both branches' reach. Then a global ``AvgPool``, ``Flatten`` and
+    the float ``Dense`` head.
+    """
+    import jax.numpy as jnp
+
+    from repro.core.quantizers import IntQuantizer, quantize_po2
+    from repro.core.streamline import _fold_affine_conv, choose_act_scale
+
+    g = Graph(inputs=["x"], outputs=["logits"],
+              meta=dict(meta or {}, model=type(model).__name__,
+                        in_scale=in_scale))
+    qa = 2 ** model.act_bits - 1
+    # value name -> (scale, largest code, (h, w, c))
+    vals = {"x": (in_scale, 127, (model.in_hw, model.in_hw, model.in_ch))}
+
+    def fold_quant(layer, p):
+        k, b = _fold_affine_conv({n: jnp.asarray(v) for n, v in p.items()},
+                                 model.bn_eps)
+        k = np.asarray(k, np.float32)
+        wq = IntQuantizer(bits=layer.weight_bits, signed=True, narrow=True,
+                          axis=0, po2=True)
+        w_int, s_w = wq.quantize_int(jnp.asarray(k.reshape(-1, k.shape[-1])))
+        s_w = np.asarray(s_w, np.float32).reshape(-1)
+        w_hat = (np.asarray(w_int, np.float32) * s_w).reshape(k.shape)
+        return w_hat, np.asarray(b, np.float32), s_w
+
+    def conv(name, layer, src, w_hat, b_q, s_w):
+        h, w, cin = vals[src][2]
+        oh, ow = _conv_out_hw(h, w, layer.kernel, layer.stride,
+                              layer.padding)
+        g.initializers[f"{name}_w"] = w_hat
+        g.initializers[f"{name}_b"] = b_q
+        g.initializers[f"{name}_ws"] = s_w
+        g.nodes.append(Node(
+            "Conv2D", name, [src, f"{name}_w", f"{name}_b"], [f"{name}_acc"],
+            attrs={"kernel": layer.kernel, "stride": layer.stride,
+                   "padding": layer.padding,
+                   "weight_bits": layer.weight_bits, "w_scale": f"{name}_ws",
+                   "in_shape": [h, w, cin],
+                   "out_shape": [oh, ow, layer.out_ch]}))
+        return f"{name}_acc", (oh, ow, layer.out_ch)
+
+    def act(name, src, s_out, shape):
+        g.nodes.append(Node("Relu", f"{name}_relu", [src], [f"{name}_r"]))
+        g.nodes.append(Node("Quant", f"{name}_quant", [f"{name}_r"],
+                            [f"{name}_q"], attrs={"scale": s_out},
+                            quant=QuantSpec(bits=model.act_bits,
+                                            signed=False)))
+        vals[f"{name}_q"] = (s_out, qa, shape)
+        return f"{name}_q"
+
+    def plain(name, layer, p, src):
+        s_in, qmax_in, _ = vals[src]
+        w_hat, b, s_w = fold_quant(layer, p)
+        grid = s_w * s_in
+        b_q = (np.round(b / grid) * grid).astype(np.float32)
+        out, shape = conv(name, layer, src, w_hat, b_q, s_w)
+        s_out = choose_act_scale(
+            jnp.asarray(w_hat.reshape(-1, layer.out_ch)), jnp.asarray(b_q),
+            in_scale=s_in, act_bits=model.act_bits, in_qmax=qmax_in)
+        return act(name, out, s_out, shape)
+
+    def join(name, layer, p, src, proj, p_proj, block_in):
+        s_x, qmax_x, _ = vals[src]
+        s_u, qmax_u, _ = vals[block_in]
+        w_hat, b, s_w = fold_quant(layer, p)
+        g_main = s_w.astype(np.float64) * s_x
+        reach = (np.sum(np.abs(w_hat.reshape(-1, layer.out_ch)), axis=0,
+                        dtype=np.float64) * s_x * qmax_x)
+        if proj is None:
+            g_skip = np.full_like(g_main, s_u)
+            reach = reach + s_u * qmax_u
+        else:
+            wp_hat, bp, sp_w = fold_quant(proj, p_proj)
+            g_skip = sp_w.astype(np.float64) * s_u
+            reach = reach + (np.sum(np.abs(wp_hat.reshape(-1, proj.out_ch)),
+                                    axis=0, dtype=np.float64) * s_u * qmax_u)
+        grid = np.minimum(g_main, g_skip)   # the finer po2 grid
+        b_q = (np.round(b / grid) * grid).astype(np.float32)
+        main, shape = conv(name, layer, src, w_hat, b_q, s_w)
+        bias = b_q.astype(np.float64)
+        if proj is None:
+            skip = block_in
+        else:
+            bp_q = (np.round(bp / grid) * grid).astype(np.float32)
+            skip, _ = conv(f"{name}_proj", proj, block_in, wp_hat, bp_q, sp_w)
+            bias = bias + bp_q
+        g.nodes.append(Node("Add", f"{name}_add", [main, skip],
+                            [f"{name}_sum"]))
+        reach = float(np.max(reach + np.abs(bias)))
+        s_out = float(quantize_po2(max(reach, 1e-8) / qa))
+        return act(name, f"{name}_sum", s_out, shape)
+
+    h = plain("stem", model.stem(), params["stem"], "x")
+    for k, (c1, c2, proj) in enumerate(model.residual_blocks()):
+        p = params["blocks"][k]
+        mid = plain(f"b{k + 1}_conv1", c1, p["conv1"], h)
+        h = join(f"b{k + 1}_conv2", c2, p["conv2"], mid, proj,
+                 p.get("proj"), h)
+    hh, ww, c = vals[h][2]
+    g.nodes.append(Node("AvgPool", "pool", [h], ["pooled"],
+                        attrs={"window": hh, "stride": hh, "padding": "VALID",
+                               "in_shape": [hh, ww, c],
+                               "out_shape": [1, 1, c]}))
+    g.nodes.append(Node("Flatten", "flatten", ["pooled"], ["flat"],
+                        attrs={"in_shape": [1, 1, c]}))
+    g.initializers["w_head"] = np.asarray(params["head"]["w"], np.float32)
+    g.initializers["b_head"] = np.asarray(params["head"]["b"], np.float32)
+    g.nodes.append(Node("Dense", "head", ["flat", "w_head", "b_head"],
+                        ["logits"]))
     return g

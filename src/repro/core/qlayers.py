@@ -1,4 +1,5 @@
-"""Quantization-aware layers (QDense, QConv2D, QDenseBatchNorm).
+"""Quantization-aware layers (QDense, QConv2D, QDenseBatchNorm,
+QConv2DBatchNorm).
 
 These are the paper's building blocks, expressed as pure init/apply pairs
 (params are plain pytrees — no flax dependency):
@@ -11,6 +12,8 @@ These are the paper's building blocks, expressed as pure init/apply pairs
                              k_folded = v * k_FC
                              b_folded = v * (b_FC - mu) + beta,
                           v = gamma / sqrt(sigma^2 + eps).
+  * ``QConv2DBatchNorm``- the same fold for a conv (hls4ml's
+                          ``QConv2DBatchnorm``), per output channel.
 
 The deployment ("streamlined") path of each layer produces integer-only
 arithmetic via core/streamline.py and runs on the fused Pallas kernel
@@ -215,3 +218,66 @@ class QConv2D:
         return self.kernel * self.kernel * self.in_ch * self.out_ch + (
             self.out_ch if self.use_bias else 0
         )
+
+
+@dataclasses.dataclass(frozen=True)
+class QConv2DBatchNorm:
+    """NHWC conv + BN folded during the forward pass, per output channel
+    (hls4ml's ``QConv2DBatchnorm``; Eqs. 3-4 of ``QDenseBatchNorm``).
+
+    The folded kernel is fake-quantized per output channel with a po2
+    scale, the grid the QIR export deploys. ``apply`` returns the folded
+    pre-activation: the caller places the ReLU and activation quantizer,
+    which a residual block puts after its add.
+    """
+
+    in_ch: int
+    out_ch: int
+    kernel: int = 3
+    stride: int = 1
+    padding: str = "SAME"
+    weight_bits: int = 8
+    act_bits: int = 8
+    momentum: float = 0.99
+    eps: float = 1e-3
+
+    def init(self, key, dtype=jnp.float32) -> Params:
+        fan_in = self.in_ch * self.kernel * self.kernel
+        w = jax.random.normal(
+            key, (self.kernel, self.kernel, self.in_ch, self.out_ch),
+            dtype) * (2.0 / fan_in) ** 0.5                 # he_normal
+        c = self.out_ch
+        return {"w": w, "b": jnp.zeros((c,), dtype),
+                "gamma": jnp.ones((c,), dtype), "beta": jnp.zeros((c,), dtype),
+                "mu": jnp.zeros((c,), dtype), "sigma2": jnp.ones((c,), dtype)}
+
+    def _conv(self, x, w):
+        return jax.lax.conv_general_dilated(
+            x, w, window_strides=(self.stride, self.stride),
+            padding=self.padding, dimension_numbers=("NHWC", "HWIO", "NHWC"))
+
+    def apply(self, params: Params, x, train: bool = True):
+        """Returns (y, new_params); BN statistics update in train mode."""
+        if train:
+            y0 = self._conv(x, params["w"]) + params["b"]
+            mu_b = jnp.mean(y0, axis=(0, 1, 2))
+            var_b = jnp.var(y0, axis=(0, 1, 2))
+            m = self.momentum
+            params = dict(
+                params,
+                mu=m * params["mu"] + (1 - m) * jax.lax.stop_gradient(mu_b),
+                sigma2=(m * params["sigma2"]
+                        + (1 - m) * jax.lax.stop_gradient(var_b)))
+        else:
+            mu_b, var_b = params["mu"], params["sigma2"]
+        v = params["gamma"] / jnp.sqrt(var_b + self.eps)
+        k = params["w"] * v
+        b = v * (params["b"] - mu_b) + params["beta"]
+        if self.weight_bits < 32:
+            k = IntQuantizer(bits=self.weight_bits, signed=True, narrow=True,
+                             axis=(0, 1, 2), po2=True)(k)
+        return self._conv(x, k) + b, params
+
+    def n_params(self) -> int:
+        return (self.kernel * self.kernel * self.in_ch * self.out_ch
+                + 5 * self.out_ch)

@@ -16,6 +16,9 @@ Three execution modes mirror the paper's deployment measurements:
     through the segment's stages inside ``jax.lax`` control flow, with
     buffers donated between segment programs where the backend supports it.
     Python is crossed once per segment, not once per stage per micro-batch.
+    A residual join's skip value (the output of its ``skip_from`` stage)
+    stays live inside the segment program, per micro-batch, until the join
+    reads it.
   * **streaming_host** — the reference queue-loop pipeline: micro-batches
     flow through per-stage programs connected by bounded queues whose
     capacities are *decided* by ``core.dataflow.optimize_fifo_depths`` — the
@@ -48,11 +51,13 @@ from repro.core.qir import Graph
 from repro.obs import timer as obs_timer
 from repro.obs.tracer import NULL_TRACER, phase
 from repro.deploy.lower import (
+    ConvAddThresholdStage,
     FlattenStage,
     FloatHeadStage,
     FusedConvThresholdStage,
     FusedThresholdStage,
     IntPoolStage,
+    IntSumPoolStage,
     MegakernelSegment,
     RefChainStage,
     Segment,
@@ -171,9 +176,28 @@ class CompiledTinyModel:
         # offline and segment programs below dispatch through the plans
         self._mega_plans: Dict[int, MegakernelSegment] = {}
         self._mega_by_start: Dict[int, MegakernelSegment] = {}
-        self.segments: List[Segment] = group_segments(self.schedule.stages)
+        stages = self.schedule.stages
+        self.segments: List[Segment] = group_segments(stages)
+        #: indices of the stages whose outputs a later join reads: kept live
+        self._keep = {s.skip_from for s in stages
+                      if isinstance(s, ConvAddThresholdStage)}
+        self._skip_row_bytes = 4 * sum(stages[k].out_dim for k in self._keep)
+        self.host_segments = self.schedule.host_segments
+        joined = set()
+        for k, seg in enumerate(self.segments):
+            for i in range(seg.start, seg.stop):
+                s = stages[i]
+                if isinstance(s, ConvAddThresholdStage):
+                    if not seg.start <= s.skip_from < i:
+                        raise NotImplementedError(
+                            f"join stage {s.name!r} reads stage "
+                            f"{s.skip_from}, outside its compiled segment "
+                            f"[{seg.start}, {seg.stop})")
+                    joined.add(k)
         if self.megakernel is not False:
             for k, seg in enumerate(self.segments):
+                if k in joined:
+                    continue   # the megakernel plans chains, not joins
                 plan = plan_megakernel(
                     self.schedule.stages, seg,
                     budget_bytes=self.megakernel_budget_bytes)
@@ -182,7 +206,7 @@ class CompiledTinyModel:
                     self._mega_by_start[plan.start] = plan
         self._offline = jax.jit(self._run_all)
         self._stage_fns = [jax.jit(self._make_stage_fn(s))
-                           for s in self.schedule.stages]
+                           for s in stages]
         self._segment_fns: Dict[int, Callable] = {}
         self._plan_cache: Dict[Tuple[int, int], Tuple[List[int], int]] = {}
 
@@ -214,12 +238,17 @@ class CompiledTinyModel:
         return self
 
     # -- single-program (offline) path -----------------------------------
-    def _apply_stage(self, s, h):
+    def _apply_stage(self, s, h, skip=None):
+        if isinstance(s, ConvAddThresholdStage):
+            if self.use_pallas:
+                return s.apply_kernel(h, skip, interpret=self.interpret)
+            return s.apply_fast(h, skip)
         if isinstance(s, (FusedThresholdStage, FusedConvThresholdStage)):
             if self.use_pallas:
                 return s.apply_kernel(h, interpret=self.interpret)
             return s.apply_fast(h)
-        if isinstance(s, (IntPoolStage, FlattenStage, FloatHeadStage)):
+        if isinstance(s, (IntPoolStage, IntSumPoolStage, FlattenStage,
+                          FloatHeadStage)):
             return s.apply_ref(h)
         if isinstance(s, RefChainStage):
             if jnp.issubdtype(h.dtype, jnp.integer):
@@ -228,7 +257,36 @@ class CompiledTinyModel:
         raise TypeError(type(s))  # pragma: no cover
 
     def _make_stage_fn(self, s) -> Callable:
+        """One stage as a function of its input; a join's takes its skip
+        value too."""
+        if isinstance(s, ConvAddThresholdStage):
+            return lambda h, skip: self._apply_stage(s, h, skip)
         return lambda h: self._apply_stage(s, h)
+
+    def _call_stage(self, i: int, h, outs: Dict[int, jnp.ndarray]):
+        """Stage i's program on ``h``, a join's with ``outs[skip_from]``;
+        keeps the output in ``outs`` where a later join reads it."""
+        s = self.schedule.stages[i]
+        if isinstance(s, ConvAddThresholdStage):
+            y = self._stage_fns[i](h, outs[s.skip_from])
+        else:
+            y = self._stage_fns[i](h)
+        if i in self._keep:
+            outs[i] = y
+        return y
+
+    def _chain(self, start: int, stop: int, h):
+        """Stages [start, stop) traced inline on ``h`` (one micro-batch):
+        each join reads its skip value from the outputs kept live here."""
+        live: Dict[int, jnp.ndarray] = {}
+        for i in range(start, stop):
+            s = self.schedule.stages[i]
+            skip = (live[s.skip_from] if isinstance(s, ConvAddThresholdStage)
+                    else None)
+            h = self._apply_stage(s, h, skip)
+            if i in self._keep:
+                live[i] = h
+        return h
 
     def _apply_mega(self, plan: MegakernelSegment, h):
         """One planned stage run as a single program: the Pallas megakernel
@@ -260,8 +318,12 @@ class CompiledTinyModel:
                 h = self._apply_mega(plan, h)
                 i = plan.stop
             else:
-                h = self._apply_stage(stages[i], h)
-                i += 1
+                # up to the next planned megakernel; no plan lies inside a
+                # segment with joins, so a join's source stays in range
+                stop = min((p for p in self._mega_by_start if p > i),
+                           default=len(stages))
+                h = self._chain(i, stop, h)
+                i = stop
         return h
 
     def offline(self, x_int) -> jnp.ndarray:
@@ -271,9 +333,9 @@ class CompiledTinyModel:
     def stage_outputs(self, x_int) -> List[jnp.ndarray]:
         """Per-stage outputs (integer codes for fused stages) — the parity
         surface the exactness tests check against the float reference."""
-        outs, h = [], jnp.asarray(x_int)
-        for fn in self._stage_fns:
-            h = fn(h)
+        outs, h, kept = [], jnp.asarray(x_int), {}
+        for i in range(len(self._stage_fns)):
+            h = self._call_stage(i, h, kept)
             outs.append(h)
         return outs
 
@@ -308,9 +370,14 @@ class CompiledTinyModel:
         tr = self.tracer
         out = []
         h = jnp.asarray(x)
-        for s, fn in zip(self.schedule.stages, self._stage_fns):
-            y = fn(h)
+        kept: Dict[int, jnp.ndarray] = {}
+        for i, s in enumerate(self.schedule.stages):
+            y = self._call_stage(i, h, kept)
             jax.block_until_ready(y)      # compile
+
+            def fn(h, i=i):
+                return self._call_stage(i, h, dict(kept))
+
             jax.block_until_ready(fn(h))  # discarded warm iteration
             times = []
             for it in range(max(iters, 1)):
@@ -415,6 +482,9 @@ class CompiledTinyModel:
         done: List[Optional[jnp.ndarray]] = [None] * n_micro
 
         tr = self.tracer
+        #: micro-batch -> the outputs its later joins read
+        kept: Dict[int, Dict[int, jnp.ndarray]] = collections.defaultdict(
+            dict)
         while feed_i < n_micro or any(len(q) > 0 for q in queues[:-1]):
             # admit into the input queue while its FIFO has room
             while feed_i < n_micro and len(queues[0]) < depths[0]:
@@ -429,7 +499,7 @@ class CompiledTinyModel:
                 if queues[si] and len(queues[si + 1]) < out_cap:
                     idx, h = queues[si].popleft()
                     t0 = obs_timer.now() if tr.enabled else 0.0
-                    h = self._stage_fns[si](h)
+                    h = self._call_stage(si, h, kept[idx])
                     queues[si + 1].append((idx, h))
                     max_occ[si + 1] = max(max_occ[si + 1], len(queues[si + 1]))
                     if tr.enabled:
@@ -517,7 +587,10 @@ class CompiledTinyModel:
         with phase(self.tracer, "replica.put", cat="executor"):
             wave = jax.device_put(buf[None], self.device)
         try:
-            with phase(self.tracer, "executor.launch", cat="executor"):
+            with phase(self.tracer, "executor.launch",
+                       cat="executor") as ph:
+                ph.set(host_segments=self.host_segments,
+                       skip_bytes=self._skip_row_bytes * mb)
                 # squeeze, not ``[0]``: an eager integer index sends its
                 # start index to the device, a second host transfer
                 y = jnp.squeeze(self._run_segments(wave, 1), 0)
@@ -570,29 +643,25 @@ class CompiledTinyModel:
         if fn is None:
             seg = self.segments[k]
             plan = self._mega_plans.get(k)
-            stages = self.schedule.stages[seg.start:seg.stop]
-
-            def chain(run, h):
-                for s in run:
-                    h = self._apply_stage(s, h)
-                return h
 
             if plan is None:
                 def run_wave(wave):
-                    return jax.lax.map(lambda h: chain(stages, h), wave)
+                    return jax.lax.map(
+                        lambda h: self._chain(seg.start, seg.stop, h), wave)
             else:
-                pre = self.schedule.stages[seg.start:plan.start]
-                post = self.schedule.stages[plan.stop:seg.stop]
-
                 def run_wave(wave):
-                    if pre:
-                        wave = jax.lax.map(lambda h: chain(pre, h), wave)
+                    if plan.start > seg.start:
+                        wave = jax.lax.map(
+                            lambda h: self._chain(seg.start, plan.start, h),
+                            wave)
                     n_micro, mb = wave.shape[0], wave.shape[1]
                     flat = wave.reshape((n_micro * mb,) + wave.shape[2:])
                     flat = self._apply_mega(plan, flat)
                     wave = flat.reshape((n_micro, mb) + flat.shape[1:])
-                    if post:
-                        wave = jax.lax.map(lambda h: chain(post, h), wave)
+                    if seg.stop > plan.stop:
+                        wave = jax.lax.map(
+                            lambda h: self._chain(plan.stop, seg.stop, h),
+                            wave)
                     return wave
 
             donate = (0,) if jax.default_backend() in ("tpu", "gpu") else ()
@@ -616,7 +685,9 @@ class CompiledTinyModel:
         x_int, n, n_micro = self._pad_micro(x_int, mb)
         depths, sim_cycles = self.plan_streaming(n_micro, micro_batch=mb)
         wave = x_int.reshape((n_micro, mb) + x_int.shape[1:])
-        with phase(self.tracer, "executor.launch", cat="executor"):
+        with phase(self.tracer, "executor.launch", cat="executor") as ph:
+            ph.set(host_segments=self.host_segments,
+                   skip_bytes=self._skip_row_bytes * n_micro * mb)
             wave = self._run_segments(wave, n_micro)
             y = wave.reshape((n_micro * mb,) + wave.shape[2:])[:n]
         # no host queues to observe: report the FIFO model's occupancy

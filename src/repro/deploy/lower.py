@@ -34,17 +34,30 @@ Stage kinds covering every exported graph:
 
     Both produce identical integers (integer accumulation is order-free),
     so the bit-exactness contract is lowering-independent.
+  * ``ConvAddThresholdStage``   — a residual join: ``Conv2D [-> 1x1
+    projection Conv2D] -> Add -> Relu -> Quant``. A ``FusedConvThreshold
+    Stage`` whose main conv's accumulator meets the skip branch's (identity
+    codes or a fused 1x1 projection of an earlier stage's output, named by
+    ``skip_from``) on one po2 grid before the one threshold bank; the join
+    kernel is ``kernels.ops.conv_threshold_add``.
   * ``IntPoolStage``            — MaxPool on integer codes (max commutes
     with the monotone code -> value map, so pooling codes is exact).
+  * ``IntSumPoolStage``         — AvgPool as an integer sum of codes; the
+    1/(window^2) rides in the output scale, so the next stage (the float
+    head) applies it exactly.
   * ``FlattenStage``            — NHWC -> flat reshape between conv and FC.
   * ``FloatHeadStage``          — the final Dense head: int codes -> float
     logits in one affine (the paper drops softmax; argmax suffices).
-  * ``RefChainStage``           — fallback: any suffix of nodes the matcher
-    does not recognize runs through a float JAX interpreter, so *any*
-    exported graph is executable (just not fused).
+  * ``RefChainStage``           — fallback: a suffix of nodes from the
+    first op with no lowering runs through a float JAX interpreter, so a
+    chain graph is executable even where it is not fused. The schedule
+    counts it (``StageSchedule.host_segments``, ``meta["host_segments"]``):
+    every such stage is a host boundary in every wave.
 
-The schedule records value scales at every boundary so integer and float
-stages compose exactly.
+The schedule is an ordered list: each stage reads its predecessor's output,
+and a join stage besides reads the output of stage ``skip_from``, which
+the executor keeps live until then. The schedule records value scales at
+every boundary so integer and float stages compose exactly.
 """
 
 from __future__ import annotations
@@ -114,6 +127,21 @@ def _float_mm_safe(w_int, in_bits: int) -> bool:
     colsum = np.sum(np.abs(np.asarray(w_int, np.int64)), axis=0)
     worst = int(colsum.max()) if colsum.size else 0
     return worst * ((1 << in_bits) - 1) < (1 << 24)
+
+
+def _join_int32_safe(acc_reach, thresholds) -> bool:
+    """True when a residual join is exact in int32.
+
+    ``acc_reach`` is, per output channel, the largest magnitude of the
+    summed accumulator in units of the join's grid: ``colsum|w_main| *
+    x_max * mul_main + colsum|w_skip| * u_max * mul_skip`` (an identity
+    skip's ``w_skip`` is the identity). The kernel compares the sum, with
+    the int8 offsets' corrections (each at most its branch's reach) moved
+    into the bank, against the thresholds; every such number stays below
+    2^31 when the reach plus the largest threshold does."""
+    t = np.abs(np.asarray(thresholds, np.float64))
+    worst = float(np.max(acc_reach)) + (float(t.max()) if t.size else 0.0)
+    return worst < 2.0 ** 31
 
 
 def mxu_form(w_int, in_bits: int, in_signed: bool) -> Tuple[str, int]:
@@ -347,24 +375,26 @@ class FusedConvThresholdStage:
           SGEMM when the bound allows, int32 otherwise) — the baseline the
           fused kernel is benchmarked against.
         """
+        return _apply_act(self.stage, self.affine, self._acc_fast(x_int))
+
+    def _acc_fast(self, x_int):
+        """The (N, OH, OW, F) int32 accumulator of the CPU/XLA path."""
         g = self.geom
         if self.lowering == "direct":
             x = x_int.reshape(-1, g.in_h, g.in_w, g.in_ch)
             if self.mm_float:
                 w4 = self.stage.w_int.astype(jnp.float32).reshape(
                     g.kernel, g.kernel, g.in_ch, g.out_ch)
-                acc = jax.lax.conv_general_dilated(
+                return jax.lax.conv_general_dilated(
                     x.astype(jnp.float32), w4, (g.stride, g.stride),
                     g.padding,
                     dimension_numbers=("NHWC", "HWIO", "NHWC")
                 ).astype(jnp.int32)
-            else:
-                from repro.kernels.conv_threshold import direct_conv_acc
+            from repro.kernels.conv_threshold import direct_conv_acc
 
-                acc = direct_conv_acc(
-                    self._pad_same(x), self.stage.w_int, kernel=g.kernel,
-                    stride=g.stride, out_h=g.out_h, out_w=g.out_w)
-            return _apply_act(self.stage, self.affine, acc)
+            return direct_conv_acc(
+                self._pad_same(x), self.stage.w_int, kernel=g.kernel,
+                stride=g.stride, out_h=g.out_h, out_w=g.out_w)
         cols = self._cols2d(x_int)
         if self.mm_float:
             acc = jnp.matmul(cols.astype(jnp.float32),
@@ -373,8 +403,7 @@ class FusedConvThresholdStage:
         else:
             acc = jnp.matmul(cols.astype(jnp.int32),
                              self.stage.w_int.astype(jnp.int32))
-        return self._shape_out(
-            _apply_act(self.stage, self.affine, acc), x_int.shape[0])
+        return self._shape_out(acc, x_int.shape[0])
 
     def apply_kernel(self, x_int, *, interpret: bool = False):
         from repro.kernels import ops
@@ -392,6 +421,86 @@ class FusedConvThresholdStage:
             self._cols2d(x_int), self.stage.w_int, self.stage.thresholds,
             operand=operand, x_offset=x_offset, interpret=interpret)
         return self._shape_out(y, x_int.shape[0])
+
+
+@dataclasses.dataclass
+class ConvAddThresholdStage(FusedConvThresholdStage):
+    """One residual join: the main conv (``stage.w_int``, ``geom``) of the
+    predecessor's output plus the skip branch on the output of stage
+    ``skip_from``, thresholded once.
+
+    Each branch's accumulator is on a per-channel po2 grid (``s_w * s_in``;
+    ``s_in`` for identity codes); ``mul`` (2, F) holds each grid's integer
+    ratio to the finer one, on which ``stage.thresholds`` is built, so the
+    block output is ``#(acc_main * mul[0] + acc_skip * mul[1] >= T)``.
+    ``w_skip`` (Cs, F) is a 1x1 projection's integer weights, applied at
+    ``skip_stride``; None adds the skip codes as they are (identity).
+    """
+
+    skip_from: int = -1
+    w_skip: Optional[jnp.ndarray] = None
+    skip_stride: int = 1
+    skip_bits: int = 8
+    mul: Optional[np.ndarray] = None      # (2, F) int32 grid ratios
+    skip_float: bool = False  # exact float32 projection (_float_mm_safe)
+
+    @functools.cached_property
+    def skip_mxu(self) -> Tuple[str, int]:
+        """(operand, offset) of the projection on the kernel path."""
+        if self.w_skip is None:
+            return "int8", 0
+        return mxu_form(self.w_skip, self.skip_bits, False)
+
+    @property
+    def macs(self) -> int:
+        g = self.geom
+        proj = 0 if self.w_skip is None else int(np.size(self.w_skip))
+        return super().macs + g.out_h * g.out_w * proj
+
+    def _skip_acc(self, skip):
+        """The skip branch's (N, OH, OW, F) int32 accumulator."""
+        if self.w_skip is None:
+            return skip.astype(jnp.int32)
+        g, st = self.geom, self.skip_stride
+        u = skip[:, ::st, ::st][:, :g.out_h, :g.out_w]
+        dt = jnp.float32 if self.skip_float else jnp.int32
+        return jnp.matmul(u.astype(dt), self.w_skip.astype(dt)
+                          ).astype(jnp.int32)
+
+    def _total(self, acc, skip):
+        mul = jnp.asarray(self.mul)
+        return acc * mul[0] + self._skip_acc(skip) * mul[1]
+
+    def apply_ref(self, x_int, skip):
+        acc = jnp.matmul(self._cols2d(x_int).astype(jnp.int32),
+                         self.stage.w_int.astype(jnp.int32))
+        acc = self._shape_out(acc, x_int.shape[0])
+        return multi_threshold(self._total(acc, skip), self.stage.thresholds)
+
+    def apply_fast(self, x_int, skip):
+        return _apply_act(self.stage, self.affine,
+                          self._total(self._acc_fast(x_int), skip))
+
+    def apply_kernel(self, x_int, skip, *, interpret: bool = False):
+        """The join kernel. The ``im2col`` lowering materializes the patch
+        matrix and runs it through the same kernel as a 1x1 conv."""
+        from repro.kernels import ops
+
+        g = self.geom
+        operand, x_offset = self.mxu
+        skip_operand, skip_offset = self.skip_mxu
+        x = x_int.reshape(-1, g.in_h, g.in_w, g.in_ch)
+        kernel, stride, padding = g.kernel, g.stride, g.padding
+        if self.lowering != "direct":
+            x = im2col(x, kernel, stride, padding)
+            kernel, stride, padding = 1, 1, "VALID"
+        return ops.conv_threshold_add(
+            x, self.stage.w_int, skip, self.w_skip, jnp.asarray(self.mul),
+            self.stage.thresholds, kernel=kernel, stride=stride,
+            padding=padding, out_h=g.out_h, out_w=g.out_w,
+            skip_stride=self.skip_stride, block_h=self.block_h,
+            operand=operand, x_offset=x_offset, skip_operand=skip_operand,
+            skip_offset=skip_offset, interpret=interpret)
 
 
 @dataclasses.dataclass
@@ -437,6 +546,54 @@ class IntPoolStage:
                 if jnp.issubdtype(x.dtype, jnp.integer) else -jnp.inf)
         return jax.lax.reduce_window(
             x, init, jax.lax.max, (1, self.window, self.window, 1),
+            (1, self.stride, self.stride, 1), self.padding)
+
+
+@dataclasses.dataclass
+class IntSumPoolStage:
+    """AvgPool executed as an integer sum of codes over each window.
+
+    The mean's 1/(window^2) moves into the output scale (a power of two
+    for 8x8), so the codes stay integers and the next stage (the float
+    head) applies the division exactly; the codes widen by
+    log2(window^2) bits."""
+
+    name: str
+    window: int
+    stride: int
+    padding: str
+    in_h: int
+    in_w: int
+    ch: int
+    out_h: int
+    out_w: int
+    in_scale: float
+    in_bits: int = 8
+
+    @property
+    def out_scale(self) -> float:
+        return self.in_scale / (self.window * self.window)
+
+    @property
+    def out_bits(self) -> int:
+        return self.in_bits + int(np.ceil(np.log2(self.window * self.window)))
+
+    @property
+    def in_dim(self) -> int:
+        return self.in_h * self.in_w * self.ch
+
+    @property
+    def out_dim(self) -> int:
+        return self.out_h * self.out_w * self.ch
+
+    @property
+    def macs(self) -> int:
+        return self.out_h * self.out_w * self.ch * self.window * self.window
+
+    def apply_ref(self, x):
+        x = x.reshape(-1, self.in_h, self.in_w, self.ch).astype(jnp.int32)
+        return jax.lax.reduce_window(
+            x, jnp.int32(0), jax.lax.add, (1, self.window, self.window, 1),
             (1, self.stride, self.stride, 1), self.padding)
 
 
@@ -511,6 +668,10 @@ class FloatHeadStage:
     scale``, an order no backend can fuse into a multiply-add. Within
     float32 rounding of ``x @ w * in_scale + b``; bit-identical to it where
     the weights are integers, as in CNV's sign head.
+
+    Codes wider than 8 bits (a sum pool's) are not exact as the TPU's
+    one-pass bf16 matmul operand: they meet the limbs as base-256 digits,
+    each in [0, 255], combined by Horner too.
     """
 
     name: str
@@ -522,8 +683,10 @@ class FloatHeadStage:
     in_bits: int = 8
 
     def __post_init__(self):
+        #: base-256 digits of the codes (unsigned where more than one)
+        self.code_digits = -(-self.in_bits // 8)
         limbs, self.limb_bits, step = head_limbs(self.w, self.in_dim,
-                                                 self.in_bits)
+                                                 min(self.in_bits, 8))
         self.limbs = tuple(jnp.asarray(d) for d in limbs)
         scale = step * self.in_scale
         self.scale = jnp.asarray(scale.astype(np.float32))
@@ -535,12 +698,16 @@ class FloatHeadStage:
         return self.in_dim * self.out_dim
 
     def apply_ref(self, x_int):
-        x = x_int.astype(jnp.float32)
         base = float(1 << self.limb_bits)
         acc = None
-        for d in self.limbs[::-1]:                 # top limb first (Horner)
-            part = jnp.matmul(x, d, preferred_element_type=jnp.float32)
-            acc = part if acc is None else acc * base + part
+        for j in reversed(range(self.code_digits)):  # top digit first
+            x = x_int if self.code_digits == 1 else (x_int >> (8 * j)) & 255
+            x = x.astype(jnp.float32)
+            h = None
+            for d in self.limbs[::-1]:             # top limb first (Horner)
+                part = jnp.matmul(x, d, preferred_element_type=jnp.float32)
+                h = part if h is None else h * base + part
+            acc = h if acc is None else acc * 256.0 + h
         return (acc + self.b_scaled) * self.scale
 
 
@@ -574,7 +741,8 @@ class RefChainStage:
         return env[self.out_name]
 
 
-Stage = Union[FusedThresholdStage, FusedConvThresholdStage, IntPoolStage,
+Stage = Union[FusedThresholdStage, FusedConvThresholdStage,
+              ConvAddThresholdStage, IntPoolStage, IntSumPoolStage,
               FlattenStage, FloatHeadStage, RefChainStage]
 
 
@@ -598,6 +766,16 @@ class StageSchedule:
         return sum(isinstance(s, FusedConvThresholdStage)
                    for s in self.stages)
 
+    @property
+    def n_join(self) -> int:
+        return sum(isinstance(s, ConvAddThresholdStage) for s in self.stages)
+
+    @property
+    def host_segments(self) -> int:
+        """Non-compiled segments (``group_segments``): host boundaries
+        every wave crosses; 0 when the whole schedule compiles."""
+        return sum(not seg.compiled for seg in group_segments(self.stages))
+
     def layer_dims(self) -> List[int]:
         dims = [self.stages[0].in_dim]
         for s in self.stages:
@@ -607,12 +785,16 @@ class StageSchedule:
     def describe(self) -> str:
         rows = [f"schedule: {len(self.stages)} stages "
                 f"({self.n_fused} fused int, {self.n_fused_conv} conv, "
+                f"{self.n_join} join, {self.host_segments} host segments, "
                 f"in_scale={self.in_scale:g})"]
         for s in self.stages:
             kind = type(s).__name__
             if isinstance(s, FusedConvThresholdStage):
                 kind += f"[{s.lowering}]"
-            rows.append(f"  {s.name:16s} {kind:24s} {s.in_dim:>6d} -> {s.out_dim}")
+            skip = (f"  + skip of stage {s.skip_from}"
+                    if isinstance(s, ConvAddThresholdStage) else "")
+            rows.append(f"  {s.name:16s} {kind:24s} {s.in_dim:>6d} -> "
+                        f"{s.out_dim}{skip}")
         return "\n".join(rows)
 
 
@@ -884,6 +1066,31 @@ def _threshold_for_chain(m: ChainMatch, scale: float,
         in_scale=scale, bn_eps=bn_eps, s_out=m.s_out)
 
 
+def _conv_geom(node: Node, w) -> ConvGeom:
+    """A Conv2D node's static geometry (``w``: its HWIO weights)."""
+    a = node.attrs
+    ih, iw, ic = a["in_shape"]
+    oh, ow, oc = a["out_shape"]
+    return ConvGeom(kernel=int(a.get("kernel", np.shape(w)[0])),
+                    stride=int(a.get("stride", 1)),
+                    padding=a.get("padding", "SAME"), in_h=int(ih),
+                    in_w=int(iw), in_ch=int(ic), out_h=int(oh), out_w=int(ow),
+                    out_ch=int(oc))
+
+
+def _lowering(conv_lowering: Optional[str]) -> str:
+    kind = conv_lowering or default_conv_lowering()
+    if kind not in CONV_LOWERINGS:
+        raise ValueError(f"conv_lowering={kind!r}; "
+                         f"expected one of {CONV_LOWERINGS}")
+    return kind
+
+
+def _is_po2(x) -> bool:
+    x = np.asarray(x, np.float64)
+    return bool(np.all(x > 0) and np.all(np.log2(x) == np.round(np.log2(x))))
+
+
 def _exact_affine(m: ChainMatch, td: ThresholdDense, scale: float,
                   mm_safe: bool, in_bits: int) -> Optional[tuple]:
     """(mul, add) for the O(1) activation, or None when not provably exact.
@@ -899,24 +1106,27 @@ def _exact_affine(m: ChainMatch, td: ThresholdDense, scale: float,
             or not mm_safe):
         return None
     s_w = np.asarray(m.w_scale, np.float64).reshape(-1)
-    grids = np.concatenate([s_w, [scale, td.out_scale]])
-    if not np.all(grids > 0):
+    if not _is_po2(np.concatenate([s_w, [scale, td.out_scale]])):
         return None
-    logs = np.log2(grids)
-    if not np.all(logs == np.round(logs)):
-        return None
-    g = s_w * scale                        # accumulator grid per channel
-    r1 = g / td.out_scale                  # activation grid in code units
-    b = np.asarray(m.params["b"], np.float64).reshape(-1)
+    colsum = np.sum(np.abs(np.asarray(td.w_int, np.int64)), axis=0)
+    return _affine_on_grid(s_w * scale,
+                           np.asarray(m.params["b"], np.float64).reshape(-1),
+                           td.out_scale, colsum * ((1 << in_bits) - 1))
+
+
+def _affine_on_grid(g, b, s_out: float, acc_max) -> Optional[tuple]:
+    """(mul, add) of the O(1) activation for an accumulator on the po2 grid
+    ``g`` (per channel) whose magnitude is at most ``acc_max`` grid steps,
+    or None where float32 cannot evaluate it exactly."""
+    r1 = g / s_out                         # activation grid in code units
     if not (np.all(b / g == np.round(b / g)) and np.all(r1 <= 0.5)):
         return None                        # bias off-grid / 0.5 off-grid
     # every term of acc*mul + add is k*r1; exactness needs max|k| < 2^24
-    colsum = np.sum(np.abs(np.asarray(td.w_int, np.int64)), axis=0)
-    k_max = (colsum * ((1 << in_bits) - 1) + np.abs(b / g) + 0.5 / r1)
+    k_max = acc_max + np.abs(b / g) + 0.5 / r1
     if not np.all(k_max < (1 << 24)):
         return None
-    mul = jnp.asarray((g / td.out_scale).astype(np.float32))
-    add = jnp.asarray((b / td.out_scale + 0.5).astype(np.float32))
+    mul = jnp.asarray((g / s_out).astype(np.float32))
+    add = jnp.asarray((b / s_out + 0.5).astype(np.float32))
     return (mul, add)
 
 
@@ -929,23 +1139,12 @@ def stage_for(m: ChainMatch, scale: float, in_bits: int = 8,
     mm_float = _float_mm_safe(td.w_int, in_bits)
     affine = _exact_affine(m, td, scale, mm_float, in_bits)
     if m.kind == "conv":
-        a = m.head.attrs
-        ih, iw, ic = a["in_shape"]
-        oh, ow, oc = a["out_shape"]
-        geom = ConvGeom(kernel=int(a.get("kernel", m.params["w"].shape[0])),
-                        stride=int(a.get("stride", 1)),
-                        padding=a.get("padding", "SAME"),
-                        in_h=int(ih), in_w=int(iw), in_ch=int(ic),
-                        out_h=int(oh), out_w=int(ow), out_ch=int(oc))
-        kind = conv_lowering or default_conv_lowering()
-        if kind not in CONV_LOWERINGS:
-            raise ValueError(f"conv_lowering={kind!r}; "
-                             f"expected one of {CONV_LOWERINGS}")
-        return FusedConvThresholdStage(name=m.head.name, stage=td, geom=geom,
+        return FusedConvThresholdStage(name=m.head.name, stage=td,
+                                       geom=_conv_geom(m.head, m.params["w"]),
                                        in_scale=scale, in_bits=in_bits,
                                        in_signed=in_signed,
                                        mm_float=mm_float, affine=affine,
-                                       lowering=kind)
+                                       lowering=_lowering(conv_lowering))
     w = m.params["w"]
     return FusedThresholdStage(name=m.head.name, stage=td,
                                in_dim=int(w.shape[0]),
@@ -953,6 +1152,129 @@ def stage_for(m: ChainMatch, scale: float, in_bits: int = 8,
                                in_scale=scale, in_bits=in_bits,
                                in_signed=in_signed,
                                mm_float=mm_float, affine=affine)
+
+
+@dataclasses.dataclass
+class JoinMatch:
+    """One residual join ``Conv2D [-> Conv2D(1x1 proj)] -> Add -> Relu ->
+    Quant``: ``head`` reads the predecessor's output, the skip branch reads
+    ``skip_value`` (through ``proj`` when there is one)."""
+
+    head: Node
+    proj: Optional[Node]
+    skip_value: str
+    quant: Node
+    n_consumed: int
+
+
+def _conv_with_scale(graph: Graph, node: Node) -> bool:
+    """A Conv2D with static geometry, initializer weights and the export's
+    per-channel weight scale: what a join branch needs to be exact."""
+    return (node.op == "Conv2D" and "in_shape" in node.attrs
+            and node.attrs.get("w_scale") in graph.initializers
+            and _head_params(graph, node) is not None)
+
+
+def _match_join(graph: Graph, nodes: List[Node], i: int
+                ) -> Optional[JoinMatch]:
+    """Try to match a residual join at i; every intermediate value must be
+    linear, as in ``_match_chain``."""
+    head = nodes[i]
+    if not _conv_with_scale(graph, head):
+        return None
+    j, proj = i + 1, None
+    if (j < len(nodes) and nodes[j].op == "Conv2D"
+            and _conv_with_scale(graph, nodes[j])
+            and int(nodes[j].attrs.get("kernel", 0)) == 1):
+        proj, j = nodes[j], j + 1
+    if j + 2 >= len(nodes):
+        return None
+    add, relu, quant = nodes[j:j + 3]
+    main = head.outputs[0]
+    if add.op != "Add" or len(add.inputs) != 2 or main not in add.inputs:
+        return None
+    other = add.inputs[1] if add.inputs[0] == main else add.inputs[0]
+    if proj is not None and other != proj.outputs[0]:
+        return None
+    if not (relu.op == "Relu" and relu.inputs[0] == add.outputs[0]
+            and quant.op == "Quant" and quant.inputs[0] == relu.outputs[0]
+            and quant.quant is not None and not quant.attrs.get("bipolar")
+            and quant.attrs.get("scale") is not None):
+        return None
+    inner = [main, add.outputs[0], relu.outputs[0]]
+    if proj is not None:
+        inner.append(proj.outputs[0])
+    if not all(_is_linear_value(graph, v) for v in inner):
+        return None
+    return JoinMatch(head=head, proj=proj,
+                     skip_value=other if proj is None else proj.inputs[0],
+                     quant=quant, n_consumed=j + 3 - i)
+
+
+def _int_weights(graph: Graph, node: Node):
+    """(integer weight codes (K, F), po2 scale (F,), bias (F,)) of an
+    exported conv: the weights divided back by their per-channel scale."""
+    p = _head_params(graph, node)
+    s_w = np.asarray(graph.initializers[node.attrs["w_scale"]],
+                     np.float64).reshape(-1)
+    w = np.asarray(p["w"], np.float64).reshape(-1, s_w.size)
+    return (np.round(w / s_w).astype(np.int64), s_w,
+            np.asarray(p["b"], np.float64).reshape(-1))
+
+
+def join_stage_for(jm: JoinMatch, graph: Graph, scale: float, in_bits: int,
+                   in_signed: bool, skip_from: int, skip_scale: float,
+                   skip_bits: int, conv_lowering: Optional[str] = None
+                   ) -> Optional[ConvAddThresholdStage]:
+    """Build the join stage for one matched residual join, or None where
+    the export's exactness contract does not hold: po2 weight and
+    activation scales, biases on the finer branch grid, the sum inside
+    int32 (``_join_int32_safe``)."""
+    w_main, s_main, b = _int_weights(graph, jm.head)
+    g_main = s_main * scale
+    w_skip, stride = None, 1
+    if jm.proj is None:
+        g_skip = np.full_like(g_main, skip_scale)
+        skip_reach = np.full(g_main.shape, float((1 << skip_bits) - 1))
+    else:
+        w_skip, s_skip, b_skip = _int_weights(graph, jm.proj)
+        g_skip = s_skip * skip_scale
+        b = b + b_skip
+        skip_reach = (np.sum(np.abs(w_skip), axis=0)
+                      * ((1 << skip_bits) - 1))
+        stride = int(jm.proj.attrs.get("stride", 1))
+    s_out = float(jm.quant.attrs["scale"])
+    if not (_is_po2(g_main) and _is_po2(g_skip) and _is_po2(s_out)):
+        return None
+    grid = np.minimum(g_main, g_skip)
+    if not np.all(b / grid == np.round(b / grid)):
+        return None
+    mul = np.stack([g_main / grid, g_skip / grid]).astype(np.int64)
+    act_bits = jm.quant.quant.bits
+    steps = np.arange(1, 2 ** act_bits, dtype=np.float64)
+    thr = np.ceil(((steps[None, :] - 0.5) * s_out - b[:, None])
+                  / grid[:, None])
+    reach = (np.sum(np.abs(w_main), axis=0) * ((1 << in_bits) - 1) * mul[0]
+             + skip_reach * mul[1])
+    if not _join_int32_safe(reach, thr):
+        return None
+    td = ThresholdDense(
+        w_int=jnp.asarray(w_main, jnp.int8),
+        thresholds=jnp.asarray(thr.astype(np.int32)), out_scale=s_out,
+        act_bits=act_bits,
+        weight_bits=int(jm.head.attrs.get("weight_bits", 8)))
+    return ConvAddThresholdStage(
+        name=jm.head.name, stage=td,
+        geom=_conv_geom(jm.head, graph.initializers[jm.head.inputs[1]]),
+        in_scale=scale, in_bits=in_bits, in_signed=in_signed,
+        mm_float=_float_mm_safe(w_main, in_bits),
+        affine=_affine_on_grid(grid, b, s_out, reach),
+        lowering=_lowering(conv_lowering),
+        skip_from=skip_from,
+        w_skip=None if w_skip is None else jnp.asarray(w_skip, jnp.int8),
+        skip_stride=stride, skip_bits=skip_bits,
+        mul=mul.astype(np.int32),
+        skip_float=w_skip is not None and _float_mm_safe(w_skip, skip_bits))
 
 
 def lower_graph(graph: Graph, in_scale: float = 1.0 / 127.0,
@@ -966,26 +1288,60 @@ def lower_graph(graph: Graph, in_scale: float = 1.0 / 127.0,
     ``conv_lowering`` selects the conv stage algorithm ("direct" fused
     kernel by default, "im2col" fallback); None defers to the
     REPRO_CONV_LOWERING environment override.
+
+    Every stage reads its predecessor's output; a residual join besides
+    reads an earlier stage's output (``skip_from``), so a value with two
+    consumers does not end the match. From the first op with no lowering,
+    the rest of the graph becomes one ``RefChainStage``, a host boundary
+    that ``meta["host_segments"]`` counts; where that rest reads a value of
+    an earlier stage other than its input, the interpreter could not see
+    it, and lowering raises ``NotImplementedError``.
     """
     stages: List[Stage] = []
     nodes = graph.nodes
     scale = in_scale
     in_bits = 8   # MLPerf-Tiny 8-bit input layer contract
     in_signed = True   # signed input codes; every stage emits unsigned ones
+    running = graph.inputs[0] if graph.inputs else None
+    # value -> (index of the stage that produced it, -1 for the graph
+    # input; its scale; its code bits)
+    vals: Dict[str, Tuple[int, float, int]] = {}
+
+    def emit(st, out_name: str, bits: int, out_scale: float):
+        nonlocal running, scale, in_bits, in_signed
+        stages.append(st)
+        running, scale, in_bits, in_signed = out_name, out_scale, bits, False
+        vals[out_name] = (len(stages) - 1, scale, in_bits)
+
+    if running is not None:
+        vals[running] = (-1, scale, in_bits)
     i = 0
     while i < len(nodes):
-        m = _match_chain(graph, nodes, i)
+        node = nodes[i]
+        # an op reading an earlier stage's value, not the predecessor's,
+        # does not continue the schedule
+        follows = not (node.inputs and node.inputs[0] in vals
+                       and node.inputs[0] != running)
+        m = _match_chain(graph, nodes, i) if follows else None
         if m is not None:
             st = stage_for(m, scale, in_bits, bn_eps,
                            conv_lowering=conv_lowering, in_signed=in_signed)
-            stages.append(st)
-            scale = st.out_scale
-            in_bits = st.stage.act_bits
-            in_signed = False
             i += m.n_consumed
+            emit(st, nodes[i - 1].outputs[0], st.stage.act_bits,
+                 st.out_scale)
             continue
-        node = nodes[i]
-        if (node.op == "MaxPool" and "in_shape" in node.attrs
+        jm = _match_join(graph, nodes, i) if follows else None
+        if jm is not None and vals.get(jm.skip_value, (-1,))[0] >= 0:
+            skip_from, skip_scale, skip_bits = vals[jm.skip_value]
+            st = join_stage_for(jm, graph, scale, in_bits, in_signed,
+                                skip_from, skip_scale, skip_bits,
+                                conv_lowering=conv_lowering)
+            if st is not None:
+                i += jm.n_consumed
+                emit(st, jm.quant.outputs[0], st.stage.act_bits,
+                     st.out_scale)
+                continue
+        if (follows and node.op == "MaxPool" and "in_shape" in node.attrs
                 and _is_passthrough_value(graph, node.outputs[0])):
             ih, iw, ch = (int(v) for v in node.attrs["in_shape"])
             win = int(node.attrs.get("window", 2))
@@ -996,24 +1352,41 @@ def lower_graph(graph: Graph, in_scale: float = 1.0 / 127.0,
                 oh, ow = -(-ih // stride), -(-iw // stride)
             else:
                 oh, ow = (ih - win) // stride + 1, (iw - win) // stride + 1
-            stages.append(IntPoolStage(
+            emit(IntPoolStage(
                 name=node.name, window=win, stride=stride,
                 padding=node.attrs.get("padding", "VALID"),
                 in_h=ih, in_w=iw, ch=ch, out_h=oh, out_w=ow,
-                in_scale=scale, in_bits=in_bits))
+                in_scale=scale, in_bits=in_bits),
+                node.outputs[0], in_bits, scale)
             i += 1
             continue
-        if (node.op == "Flatten"
+        if (follows and node.op == "AvgPool"
+                and "out_shape" in node.attrs
+                and node.attrs.get("padding", "VALID") == "VALID"
+                and _is_passthrough_value(graph, node.outputs[0])):
+            ih, iw, ch = (int(v) for v in node.attrs["in_shape"])
+            oh, ow = (int(v) for v in node.attrs["out_shape"][:2])
+            win = int(node.attrs.get("window", 2))
+            st = IntSumPoolStage(
+                name=node.name, window=win,
+                stride=int(node.attrs.get("stride", win)), padding="VALID",
+                in_h=ih, in_w=iw, ch=ch, out_h=oh, out_w=ow,
+                in_scale=scale, in_bits=in_bits)
+            emit(st, node.outputs[0], st.out_bits, st.out_scale)
+            i += 1
+            continue
+        if (follows and node.op == "Flatten"
                 and _is_passthrough_value(graph, node.outputs[0])):
             if "in_shape" in node.attrs:
                 in_dim = int(np.prod(node.attrs["in_shape"]))
             else:
                 in_dim = stages[-1].out_dim if stages else 1
-            stages.append(FlattenStage(name=node.name, in_dim=in_dim,
-                                       in_scale=scale, in_bits=in_bits))
+            emit(FlattenStage(name=node.name, in_dim=in_dim,
+                              in_scale=scale, in_bits=in_bits),
+                 node.outputs[0], in_bits, scale)
             i += 1
             continue
-        if node.op == "Dense" and i == len(nodes) - 1:
+        if follows and node.op == "Dense" and i == len(nodes) - 1:
             params = _head_params(graph, node)
             if params is not None:
                 stages.append(FloatHeadStage(
@@ -1028,6 +1401,13 @@ def lower_graph(graph: Graph, in_scale: float = 1.0 / 127.0,
         # fallback: sweep the rest of the graph into one reference chain
         rest = nodes[i:]
         in_name = rest[0].inputs[0]
+        unseen = sorted({v for n in rest for v in n.inputs
+                         if v in vals and v != in_name})
+        if unseen:
+            raise NotImplementedError(
+                f"no lowering for {node.op} node {node.name!r}, and the "
+                f"fallback interpreter from there on would need {unseen}, "
+                "values of earlier stages besides its input")
         out_name = graph.outputs[0] if graph.outputs else rest[-1].outputs[0]
         in_dim = stages[-1].out_dim if stages else _guess_dim(graph, in_name)
         out_dim = _guess_dim(graph, out_name, default=in_dim)
@@ -1040,10 +1420,11 @@ def lower_graph(graph: Graph, in_scale: float = 1.0 / 127.0,
             in_dim=in_dim,
             out_dim=out_dim,
             in_scale=scale, in_bits=in_bits))
-        scale = 1.0  # float domain from here on
         i = len(nodes)
-    return StageSchedule(stages=stages, in_scale=in_scale,
-                        meta=dict(graph.meta))
+    schedule = StageSchedule(stages=stages, in_scale=in_scale,
+                             meta=dict(graph.meta))
+    schedule.meta["host_segments"] = schedule.host_segments
+    return schedule
 
 
 def _guess_dim(graph: Graph, name: str, default: int = 1) -> int:

@@ -46,6 +46,17 @@ next row block's band DMA overlaps the current block's tap matmuls, the TPU
 analogue of the paper's line-buffer streaming. Before this the block spec
 pinned the whole padded sample per program (index map ignored the row-block
 index), so every row block refetched the full input.
+
+**Residual join** (``conv_threshold_add``): a residual block's second conv
+meets its skip branch before the activation. The sibling kernel reads,
+besides the main input band, the skip operand of the same output rows:
+the identity codes, or the (strided) block input of a fused 1x1
+projection, which it multiplies in-kernel. The two int32 accumulators
+sit on per-channel power-of-two grids; each is scaled by its integer
+ratio to the finer grid (``mul``), they are added in int32, and the sum
+is counted against the one threshold bank. Exact while the summed
+accumulator and the bank stay inside int32 (``deploy.lower``'s
+``_join_int32_safe``).
 """
 
 from __future__ import annotations
@@ -127,6 +138,23 @@ def space_to_depth(x_pad: jnp.ndarray, w2d: jnp.ndarray, *, kernel: int,
     return x, w.reshape(ka * ka * stride * stride * c, f), ka
 
 
+def _main_operands(x_pad, w2d, *, kernel: int, stride: int, out_h: int,
+                   out_w: int, block_h: int, operand: str):
+    """The main input as per-row-block bands of a stride-1 conv (a strided
+    conv rewritten by ``space_to_depth``) and its tap-major weights in the
+    MXU operand type. Returns ``(x_band (N, nb, br, WP, C), w_taps (T, C,
+    F), kernel of the stride-1 conv)``."""
+    f = w2d.shape[1]
+    x = x_pad.astype(jnp.int32)
+    if stride > 1:
+        x, w2d, kernel = space_to_depth(x, w2d, kernel=kernel, stride=stride,
+                                        out_h=out_h, out_w=out_w)
+    c = x.shape[-1]
+    x_band = _row_bands(x, block_h, kernel, out_h // block_h)
+    w_taps = to_operand(w2d, operand).reshape(kernel * kernel, c, f)
+    return x_band, w_taps, kernel
+
+
 def _conv_thr_kernel(x_ref, w_ref, thr_ref, o_ref, *, kernel: int,
                      block_h: int, out_w: int, n_steps: int, operand: str,
                      x_offset: int, interpret: bool):
@@ -192,16 +220,11 @@ def conv_threshold(
     assert hp >= (out_h - 1) * stride + kernel, (hp, out_h, stride, kernel)
     assert wp >= (out_w - 1) * stride + kernel, (wp, out_w, stride, kernel)
     thr_t = shift_bank(thresholds, w2d, x_offset).T   # (S, F): lanes = chans
-    x = x_pad.astype(jnp.int32)
-    if stride > 1:
-        x, w2d, kernel = space_to_depth(x, w2d, kernel=kernel, stride=stride,
-                                        out_h=out_h, out_w=out_w)
-        c = x.shape[-1]
-    wp = x.shape[2]
+    x_band, w_taps, kernel = _main_operands(
+        x_pad, w2d, kernel=kernel, stride=stride, out_h=out_h, out_w=out_w,
+        block_h=block_h, operand=operand)
+    br, wp, c = x_band.shape[2:]
     n_blocks = out_h // block_h
-    br = band_rows(block_h, 1, kernel)
-    x_band = _row_bands(x, block_h, kernel, n_blocks)  # (N, nb, br, WP, C)
-    w_taps = to_operand(w2d, operand).reshape(kernel * kernel, c, f)
 
     return pl.pallas_call(
         functools.partial(
@@ -225,6 +248,130 @@ def conv_threshold(
         # is named ``conv_threshold.N`` after this, whatever wraps the call
         name="conv_threshold",
     )(x_band, w_taps, thr_t)
+
+
+def _conv_thr_add_kernel(x_ref, w_ref, s_ref, *refs, kernel: int,
+                         block_h: int, out_w: int, n_steps: int,
+                         operand: str, x_offset: int, proj: bool,
+                         skip_operand: str, skip_offset: int,
+                         interpret: bool):
+    """One (sample, output-row-block) program of a residual join.
+
+    x_ref, w_ref: as ``_conv_thr_kernel`` (the main 3x3 conv)
+    s_ref:   (1, block_h, OW, Cs) int32 — the skip operand of this block's
+             output rows: identity codes (Cs = F), or the block input of a
+             1x1 projection, already taken at the projection's stride
+    refs:    [wp_ref (Cs, F) projection weights,] mul_ref (2, F) int32 —
+             each branch's grid ratio to the finer grid —, thr_ref (S, F),
+             o_ref (1, block_h, OW, F)
+    """
+    if proj:
+        wp_ref, mul_ref, thr_ref, o_ref = refs
+    else:
+        mul_ref, thr_ref, o_ref = refs
+    m_main = mul_ref[pl.ds(0, 1), :]                            # (1, F)
+    m_skip = mul_ref[pl.ds(1, 1), :]
+
+    def row(r, carry):
+        acc = None
+        for kh in range(kernel):                   # static K x K tap loop
+            for kw in range(kernel):
+                xs = x_ref[0, 0, r + kh, pl.ds(kw, out_w), :]   # (OW, C)
+                part = mxu_dot(to_operand(xs, operand, x_offset),
+                               w_ref[kh * kernel + kw], operand, interpret)
+                acc = part if acc is None else acc + part
+        skip = s_ref[0, r]                                      # (OW, Cs)
+        if proj:
+            skip = mxu_dot(to_operand(skip, skip_operand, skip_offset),
+                           wp_ref[...], skip_operand, interpret)
+        o_ref[0, r] = count_thresholds(acc * m_main + skip * m_skip,
+                                       thr_ref, n_steps)
+        return carry
+
+    jax.lax.fori_loop(0, block_h, row, 0)
+
+
+def conv_threshold_add(
+    x_pad: jnp.ndarray,            # (N, HP, WP, C) int32, already zero-padded
+    w2d: jnp.ndarray,              # (K*K*C, F) integer, (kh, kw, c)-major
+    skip: jnp.ndarray,             # (N, out_h, out_w, Cs) int32
+    w_skip,                        # (Cs, F) integer, or None (identity)
+    mul: jnp.ndarray,              # (2, F) int32 grid ratios: main, skip
+    thresholds: jnp.ndarray,       # (F, S) int32, sorted along S
+    *,
+    kernel: int,
+    stride: int,
+    out_h: int,
+    out_w: int,
+    block_h: int,
+    operand: str = "int8",
+    x_offset: int = 0,
+    skip_operand: str = "int8",
+    skip_offset: int = 0,
+    interpret: bool = False,
+) -> jnp.ndarray:
+    """One residual join in a single kernel: the main conv as
+    ``conv_threshold`` computes it, plus the skip operand of the same output
+    rows (identity codes, or a 1x1 projection of ``skip`` by ``w_skip``),
+    each accumulator times its grid ratio ``mul``, summed in int32 and
+    counted against ``thresholds``. ``skip`` is padded to ``out_h`` rows
+    like the output. The int8 offsets of both branches fold into the bank:
+    ``mul[0] * x_offset * colsum(w2d) + mul[1] * skip_offset *
+    colsum(w_skip)``. Returns (N, out_h, out_w, F) int32 codes."""
+    n, hp, wp, c = x_pad.shape
+    f = w2d.shape[1]
+    s = thresholds.shape[1]
+    proj = w_skip is not None
+    cs = skip.shape[-1]
+    assert w2d.shape[0] == kernel * kernel * c, (w2d.shape, kernel, c)
+    assert thresholds.shape[0] == f and mul.shape == (2, f), mul.shape
+    assert out_h % block_h == 0, (out_h, block_h)
+    assert skip.shape == (n, out_h, out_w, cs), (skip.shape, out_h, out_w)
+    assert cs == (w_skip.shape[0] if proj else f), (cs, f)
+    assert hp >= (out_h - 1) * stride + kernel, (hp, out_h, stride, kernel)
+    assert wp >= (out_w - 1) * stride + kernel, (wp, out_w, stride, kernel)
+    mul = mul.astype(jnp.int32)
+    corr = mul[0] * x_offset * jnp.sum(w2d.astype(jnp.int32), axis=0)
+    if proj:
+        corr = corr + mul[1] * skip_offset * jnp.sum(
+            w_skip.astype(jnp.int32), axis=0)
+    thr_t = (thresholds.astype(jnp.int32) - corr[:, None]).T     # (S, F)
+    x_band, w_taps, kernel = _main_operands(
+        x_pad, w2d, kernel=kernel, stride=stride, out_h=out_h, out_w=out_w,
+        block_h=block_h, operand=operand)
+    br, wp, c = x_band.shape[2:]
+    n_blocks = out_h // block_h
+    operands = [x_band, w_taps, skip.astype(jnp.int32)]
+    in_specs = [
+        pl.BlockSpec((1, 1, br, wp, c), lambda i, j: (i, j, 0, 0, 0)),
+        pl.BlockSpec((kernel * kernel, c, f), lambda i, j: (0, 0, 0)),
+        pl.BlockSpec((1, block_h, out_w, cs), lambda i, j: (i, j, 0, 0)),
+    ]
+    if proj:
+        operands.append(to_operand(w_skip, skip_operand))
+        in_specs.append(pl.BlockSpec((cs, f), lambda i, j: (0, 0)))
+    operands += [mul, thr_t]
+    in_specs += [pl.BlockSpec((2, f), lambda i, j: (0, 0)),
+                 pl.BlockSpec((s, f), lambda i, j: (0, 0))]
+
+    return pl.pallas_call(
+        functools.partial(
+            _conv_thr_add_kernel, kernel=kernel, block_h=block_h,
+            out_w=out_w, n_steps=s, operand=operand, x_offset=x_offset,
+            proj=proj, skip_operand=skip_operand, skip_offset=skip_offset,
+            interpret=interpret),
+        grid=(n, n_blocks),
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec((1, block_h, out_w, f),
+                               lambda i, j: (i, j, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((n, out_h, out_w, f), jnp.int32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+        ),
+        interpret=interpret,
+        # the device op in a profiler trace is ``conv_threshold_add.N``
+        name="conv_threshold_add",
+    )(*operands)
 
 
 def direct_conv_acc(x_pad: jnp.ndarray, w2d: jnp.ndarray, *, kernel: int,

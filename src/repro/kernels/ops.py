@@ -150,6 +150,25 @@ def plan_conv_blocks(out_h: int, out_w: int, out_ch: int,
     return block_h
 
 
+def _conv_pads(h, w, out_h, out_w, stride, kernel, padding, block_h,
+               out_ch):
+    """The host wrapper's zero padding of a direct conv's input: SAME pads,
+    then extra bottom rows so the row-block grid's last block stays in
+    bounds. Returns ``(pads, block_h, out_h padded to the block)``."""
+    if padding == "SAME":
+        pad_h, pad_w = _ct.same_pads(h, w, out_h, out_w, stride, kernel)
+        pads = ((0, 0), pad_h, pad_w, (0, 0))
+    else:
+        pads = ((0, 0), (0, 0), (0, 0), (0, 0))
+    bh = plan_conv_blocks(out_h, out_w, out_ch) \
+        if block_h is None else min(block_h, out_h)
+    oh_pad = -(-out_h // bh) * bh
+    extra = ((oh_pad - 1) * stride + kernel) - (h + pads[1][0] + pads[1][1])
+    if extra > 0:
+        pads = (pads[0], (pads[1][0], pads[1][1] + extra), pads[2], pads[3])
+    return pads, bh, oh_pad
+
+
 @functools.partial(jax.jit, static_argnames=("kernel", "stride", "padding",
                                              "out_h", "out_w", "block_h",
                                              "operand", "x_offset",
@@ -171,23 +190,53 @@ def conv_threshold(x_int, w2d, thresholds, *, kernel: int, stride: int,
     its exact MXU form, as there.
     """
     n, h, w, c = x_int.shape
-    if padding == "SAME":
-        pad_h, pad_w = _ct.same_pads(h, w, out_h, out_w, stride, kernel)
-        pads = ((0, 0), pad_h, pad_w, (0, 0))
-    else:
-        pads = ((0, 0), (0, 0), (0, 0), (0, 0))
-    bh = plan_conv_blocks(out_h, out_w, w2d.shape[1]) \
-        if block_h is None else min(block_h, out_h)
-    oh_pad = -(-out_h // bh) * bh
-    # extra zero rows so the padded grid's last block stays in bounds
-    extra = ((oh_pad - 1) * stride + kernel) - (h + pads[1][0] + pads[1][1])
-    if extra > 0:
-        pads = (pads[0], (pads[1][0], pads[1][1] + extra), pads[2], pads[3])
+    pads, bh, oh_pad = _conv_pads(h, w, out_h, out_w, stride, kernel,
+                                  padding, block_h, w2d.shape[1])
     x_p = jnp.pad(x_int.astype(jnp.int32), pads)
     y = _ct.conv_threshold(x_p, w2d, thresholds, kernel=kernel,
                            stride=stride, out_h=oh_pad, out_w=out_w,
                            block_h=bh, operand=operand, x_offset=x_offset,
                            interpret=interpret)
+    return y[:, :out_h]
+
+
+@functools.partial(jax.jit, static_argnames=("kernel", "stride", "padding",
+                                             "out_h", "out_w", "skip_stride",
+                                             "block_h", "operand", "x_offset",
+                                             "skip_operand", "skip_offset",
+                                             "interpret"))
+def conv_threshold_add(x_int, w2d, skip, w_skip, mul, thresholds, *,
+                       kernel: int, stride: int, padding: str, out_h: int,
+                       out_w: int, skip_stride: int = 1,
+                       block_h: Optional[int] = None, operand: str = "int8",
+                       x_offset: int = 0, skip_operand: str = "int8",
+                       skip_offset: int = 0, interpret: bool = False):
+    """Fused residual join: the main direct conv of ``x_int`` plus the skip
+    branch, thresholded once (``kernels.conv_threshold.conv_threshold_add``).
+
+    ``skip`` is the identity codes (N, out_h, out_w, F) with ``w_skip``
+    None, or the block input of a 1x1 projection ``w_skip`` (Cs, F) at
+    ``skip_stride`` (a 1x1 SAME conv reads pixels ``(s*i, s*j)`` and pads
+    nothing, so the wrapper takes them with one strided slice). ``mul`` is
+    the (2, F) grid ratios of the two accumulators; ``thresholds`` the
+    (F, S) bank on the finer grid. Padding, the row-block plan and the
+    operand forms are as ``conv_threshold``'s; the skip operand is
+    zero-padded to the padded output rows, whose codes are discarded.
+    """
+    n, h, w, c = x_int.shape
+    pads, bh, oh_pad = _conv_pads(h, w, out_h, out_w, stride, kernel,
+                                  padding, block_h, w2d.shape[1])
+    x_p = jnp.pad(x_int.astype(jnp.int32), pads)
+    skip = skip.astype(jnp.int32)
+    if w_skip is not None and skip_stride > 1:
+        skip = skip[:, ::skip_stride, ::skip_stride]
+    skip = jnp.pad(skip[:, :out_h, :out_w],
+                   ((0, 0), (0, oh_pad - out_h), (0, 0), (0, 0)))
+    y = _ct.conv_threshold_add(
+        x_p, w2d, skip, w_skip, mul, thresholds, kernel=kernel,
+        stride=stride, out_h=oh_pad, out_w=out_w, block_h=bh,
+        operand=operand, x_offset=x_offset, skip_operand=skip_operand,
+        skip_offset=skip_offset, interpret=interpret)
     return y[:, :out_h]
 
 

@@ -6,6 +6,10 @@ Table 1 of the paper:
   AD  (hls4ml) : 6-12 bit autoencoder, 22 285 params      -> ``ADAutoencoder``
   KWS (FINN)   : 3-bit MLP, 259 584 params, 82.5%         -> ``KWSMLP``
 
+and the MLPerf Tiny image-classification reference model every IC
+submission is measured against (Banbury et al., arXiv:2106.07597):
+  IC  (ResNet-8): 8-bit QAT, 78 666 params, 12 501 632 MACs -> ``ResNet8``
+
 Parameter-count notes: CNV reproduces the paper count exactly (1 542 848).
 The KWS MLP (490-256-256-256-12, no biases in the paper's count) matches
 259 584 weights exactly. The IC and AD architectures follow the paper's
@@ -22,8 +26,10 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.bops import ModelCost, conv_cost, dense_cost
-from repro.core.qlayers import QConv2D, QDense, QDenseBatchNorm
-from repro.core.quantizers import BinaryQuantizer, FixedPointQuantizer
+from repro.core.qlayers import (QConv2D, QConv2DBatchNorm, QDense,
+                                QDenseBatchNorm)
+from repro.core.quantizers import (BinaryQuantizer, FixedPointQuantizer,
+                                   IntQuantizer)
 
 
 # ---------------------------------------------------------------------------
@@ -305,3 +311,126 @@ class CNVModel:
         for i in range(len(dims) - 1):
             ls.append(dense_cost(f"fc{i}", dims[i], dims[i + 1], 1, 1, bias=False))
         return ModelCost(ls)
+
+
+# ---------------------------------------------------------------------------
+# IC: ResNet-8 (the MLPerf Tiny reference, mlcommons/tiny resnet_v1_eembc)
+# ---------------------------------------------------------------------------
+
+@dataclasses.dataclass(frozen=True)
+class ResNet8:
+    """mlcommons/tiny ``resnet_v1_eembc``: a 3x3 stem conv (16), then three
+    residual stacks of two 3x3 convs each (16, 32, 64 filters; the last two
+    stacks start with stride 2 and add a 1x1 stride-2 projection of the
+    block input, with no BN on that branch; the first adds the identity),
+    a ReLU after each add, 8x8 average pooling and a Dense(10) head.
+    QAT with 8-bit weights and activations as the hls4ml IC model; every
+    BN folds into its conv (``QConv2DBatchNorm``). 78 666 parameters
+    (77 360 weights), 12 501 632 MACs per 32x32x3 sample."""
+
+    filters: Tuple[int, ...] = (16, 32, 64)
+    n_classes: int = 10
+    weight_bits: int = 8
+    act_bits: int = 8
+    in_hw: int = 32
+    in_ch: int = 3
+    bn_eps: float = 1e-3
+
+    def _bn_conv(self, cin, cout, stride=1):
+        return QConv2DBatchNorm(cin, cout, kernel=3, stride=stride,
+                                padding="SAME", weight_bits=self.weight_bits,
+                                act_bits=self.act_bits, eps=self.bn_eps)
+
+    def stem(self) -> QConv2DBatchNorm:
+        return self._bn_conv(self.in_ch, self.filters[0])
+
+    def residual_blocks(self):
+        """Per stack: (conv1, conv2, projection or None for identity)."""
+        out, cin = [], self.filters[0]
+        for k, f in enumerate(self.filters):
+            stride = 1 if k == 0 else 2
+            proj = None
+            if stride != 1 or cin != f:
+                proj = QConv2D(cin, f, kernel=1, stride=stride,
+                               padding="SAME", weight_bits=self.weight_bits,
+                               act_bits=32)
+            out.append((self._bn_conv(cin, f, stride), self._bn_conv(f, f),
+                        proj))
+            cin = f
+        return out
+
+    def head(self) -> QDense:
+        return QDense(self.filters[-1], self.n_classes, weight_bits=32,
+                      act_bits=32)
+
+    def init(self, key):
+        blocks = self.residual_blocks()
+        keys = jax.random.split(key, 3 * len(blocks) + 2)
+        params = {"stem": self.stem().init(keys[0]), "blocks": [],
+                  "head": self.head().init(keys[-1])}
+        for k, (c1, c2, proj) in enumerate(blocks):
+            p = {"conv1": c1.init(keys[3 * k + 1]),
+                 "conv2": c2.init(keys[3 * k + 2])}
+            if proj is not None:
+                p["proj"] = proj.init(keys[3 * k + 3])
+            params["blocks"].append(p)
+        return params
+
+    def apply(self, params, x, train: bool = True):
+        """Returns (logits, new_params); BN statistics update in train
+        mode."""
+        aq = IntQuantizer(bits=self.act_bits, signed=False)
+
+        def act(y):
+            return aq(jax.nn.relu(y))
+
+        h, stem = self.stem().apply(params["stem"], x, train=train)
+        h = act(h)
+        new_blocks = []
+        for (c1, c2, proj), p in zip(self.residual_blocks(),
+                                     params["blocks"]):
+            y, p1 = c1.apply(p["conv1"], h, train=train)
+            y, p2 = c2.apply(p["conv2"], act(y), train=train)
+            skip = h if proj is None else proj.apply(p["proj"], h,
+                                                     train=train)
+            h = act(y + skip)
+            new_blocks.append(dict(p, conv1=p1, conv2=p2))
+        logits = self.head().apply(params["head"], jnp.mean(h, axis=(1, 2)),
+                                   train=train)
+        return logits, dict(params, stem=stem, blocks=new_blocks)
+
+    def _layers(self):
+        """(name, k, cin, cout, out_hw, in_bits) of every conv."""
+        out, hw = [], self.in_hw
+        out.append(("stem", 3, self.in_ch, self.filters[0], hw, 8))
+        cin = self.filters[0]
+        for k, (c1, c2, proj) in enumerate(self.residual_blocks()):
+            hw = -(-hw // c1.stride)
+            out.append((f"b{k + 1}_conv1", 3, cin, c1.out_ch, hw,
+                        self.act_bits))
+            out.append((f"b{k + 1}_conv2", 3, c1.out_ch, c2.out_ch, hw,
+                        self.act_bits))
+            if proj is not None:
+                out.append((f"b{k + 1}_proj", 1, cin, proj.out_ch, hw,
+                            self.act_bits))
+            cin = c2.out_ch
+        return out
+
+    def cost(self) -> ModelCost:
+        ls = [conv_cost(name, cin, cout, k, hw, hw, b_a, self.weight_bits)
+              for name, k, cin, cout, hw, b_a in self._layers()]
+        ls.append(dense_cost("head", self.filters[-1], self.n_classes,
+                             self.act_bits, self.weight_bits))
+        return ModelCost(ls)
+
+    def n_weights(self) -> int:
+        return (sum(k * k * cin * cout
+                    for _, k, cin, cout, _, _ in self._layers())
+                + self.filters[-1] * self.n_classes)
+
+    def n_params(self) -> int:
+        blocks = self.residual_blocks()
+        return (self.stem().n_params() + self.head().n_params()
+                + sum(c1.n_params() + c2.n_params()
+                      + (proj.n_params() if proj is not None else 0)
+                      for c1, c2, proj in blocks))

@@ -100,6 +100,30 @@ def test_float_head_is_the_same_in_every_program():
     np.testing.assert_allclose(eager, ref, rtol=1e-6, atol=1e-6)
 
 
+def test_float_head_is_exact_under_bf16_operands_for_wide_codes(
+        monkeypatch):
+    """A sum pool's 14-bit codes: every matmul operand of the head is exact
+    in bf16, so rounding them as the TPU's one-pass default does changes
+    no logit (codes above 256 would round as one operand)."""
+    rng = np.random.default_rng(3)
+    w = (rng.standard_normal((64, 10)) * 0.1).astype(np.float32)
+    b = (rng.standard_normal(10) * 0.1).astype(np.float32)
+    x = jnp.asarray(rng.integers(0, 64 * 255 + 1, (8, 64)), jnp.int32)
+    head = _head(w, b, in_scale=2.0 ** -12, in_bits=14)
+    exact = np.asarray(head.apply_ref(x))
+    matmul = jnp.matmul
+
+    def one_pass(a, c, **kw):
+        bf = jnp.bfloat16
+        return matmul(a.astype(bf).astype(a.dtype), c.astype(bf).astype(
+            c.dtype), **kw)
+
+    monkeypatch.setattr(jnp, "matmul", one_pass)
+    np.testing.assert_array_equal(np.asarray(head.apply_ref(x)), exact)
+    ref = np.asarray(x, np.float64) @ w * 2.0 ** -12 + b
+    np.testing.assert_allclose(exact, ref, rtol=1e-6, atol=1e-6)
+
+
 # ---------------------------------------------------------------------------
 # device binding
 # ---------------------------------------------------------------------------

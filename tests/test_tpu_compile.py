@@ -170,3 +170,39 @@ def test_kernels_keep_the_names_the_trace_reducers_match(one_chip):
         got = names(compiled)
         assert got and all(re.fullmatch(rf"{stem}\.\d+", n) for n in got), \
             got
+
+
+#: ResNet-8's three joins at full width: (input width, channels, the skip
+#: branch's input channels and stride; 0 channels for the identity)
+RESNET8_JOINS = [(32, 16, 0, 1), (16, 32, 16, 2), (8, 64, 32, 2)]
+
+
+@pytest.mark.parametrize("hw,ch,skip_ch,skip_stride", RESNET8_JOINS)
+@pytest.mark.parametrize("operand,x_offset", FORMS)
+def test_resnet8_joins_compile(one_chip, hw, ch, skip_ch, skip_stride,
+                               operand, x_offset):
+    """Each residual join: the 3x3 main conv over 8-bit codes plus the
+    identity codes or a 1x1 stride-2 projection of the block input, one
+    wave of 256 rows; the kernel keeps the name ``conv_threshold_add``."""
+    import re
+
+    proj = skip_ch > 0
+
+    def fn(x, w, skip, *rest):
+        w_skip, mul, t = rest if proj else (None, *rest)
+        return ops.conv_threshold_add(
+            x, w, skip, w_skip, mul, t, kernel=3, stride=1, padding="SAME",
+            out_h=hw, out_w=hw, skip_stride=skip_stride, operand=operand,
+            x_offset=x_offset, skip_operand=operand, skip_offset=x_offset)
+
+    skip_hw = hw * skip_stride
+    shapes = [((256, hw, hw, ch), I32), ((9 * ch, ch), I8),
+              ((256, skip_hw, skip_hw, skip_ch if proj else ch), I32)]
+    if proj:
+        shapes.append(((skip_ch, ch), I8))
+    shapes += [((2, ch), I32), ((ch, 255), I32)]
+    compiled = _compile(one_chip, fn, *shapes)
+    got = set(re.findall(r"%([\w.-]+) = \S+ custom-call\([^\n]*"
+                         r"tpu_custom_call", compiled.as_text()))
+    assert got and all(re.fullmatch(r"conv_threshold_add\.\d+", n)
+                       for n in got), got
