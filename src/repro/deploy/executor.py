@@ -183,6 +183,10 @@ class CompiledTinyModel:
                       if isinstance(s, ConvAddThresholdStage)}
         self._skip_row_bytes = 4 * sum(stages[k].out_dim for k in self._keep)
         self.host_segments = self.schedule.host_segments
+        #: stages whose kernel runs the O(1) activation (none off the
+        #: kernel path)
+        self.affine_stages = (self.schedule.affine_stages
+                              if self.use_pallas else 0)
         joined = set()
         for k, seg in enumerate(self.segments):
             for i in range(seg.start, seg.stop):
@@ -590,7 +594,8 @@ class CompiledTinyModel:
             with phase(self.tracer, "executor.launch",
                        cat="executor") as ph:
                 ph.set(host_segments=self.host_segments,
-                       skip_bytes=self._skip_row_bytes * mb)
+                       skip_bytes=self._skip_row_bytes * mb,
+                       affine_stages=self.affine_stages)
                 # squeeze, not ``[0]``: an eager integer index sends its
                 # start index to the device, a second host transfer
                 y = jnp.squeeze(self._run_segments(wave, 1), 0)
@@ -687,7 +692,8 @@ class CompiledTinyModel:
         wave = x_int.reshape((n_micro, mb) + x_int.shape[1:])
         with phase(self.tracer, "executor.launch", cat="executor") as ph:
             ph.set(host_segments=self.host_segments,
-                   skip_bytes=self._skip_row_bytes * n_micro * mb)
+                   skip_bytes=self._skip_row_bytes * n_micro * mb,
+                   affine_stages=self.affine_stages)
             wave = self._run_segments(wave, n_micro)
             y = wave.reshape((n_micro * mb,) + wave.shape[2:])[:n]
         # no host queues to observe: report the FIFO model's occupancy

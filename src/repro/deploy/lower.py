@@ -137,8 +137,9 @@ def _join_int32_safe(acc_reach, thresholds) -> bool:
     x_max * mul_main + colsum|w_skip| * u_max * mul_skip`` (an identity
     skip's ``w_skip`` is the identity). The kernel compares the sum, with
     the int8 offsets' corrections (each at most its branch's reach) moved
-    into the bank, against the thresholds; every such number stays below
-    2^31 when the reach plus the largest threshold does."""
+    into the bank, against the thresholds, or adds them back to the sum
+    before the affine activation; every such number stays below 2^31 when
+    the reach plus the largest threshold does."""
     t = np.abs(np.asarray(thresholds, np.float64))
     worst = float(np.max(acc_reach)) + (float(t.max()) if t.size else 0.0)
     return worst < 2.0 ** 31
@@ -178,17 +179,19 @@ def mxu_form(w_int, in_bits: int, in_signed: bool) -> Tuple[str, int]:
 def _apply_act(stage: ThresholdDense, affine, acc):
     """Integer activation on the accumulator, fastest exact form available.
 
-    ``affine`` is the O(1) arithmetic short-cut (mul, add) per channel:
+    ``affine`` is the O(1) arithmetic short-cut, a (2, F) ``mul; add``:
     when every scale in the stage is a power of two and the bias sits on the
     accumulator grid (the conv exporter's contract), the half-up quant
     q = clip(floor(acc*mul + add), 0, S) is exact in float32 and therefore
     bit-identical to counting thresholds — without the O(log S) gather loop.
     Otherwise fall back to the sorted-bank searchsorted (or, for single-step
-    sign banks, one broadcast compare)."""
+    sign banks, one broadcast compare). The conv kernels end their rows
+    with the same ``affine_codes`` (``affine_on_kernel``)."""
     if affine is not None:
+        from repro.kernels.multi_threshold import affine_codes
+
         mul, add = affine
-        q = jnp.floor(acc.astype(jnp.float32) * mul + add)
-        return jnp.clip(q, 0, stage.n_steps).astype(jnp.int32)
+        return affine_codes(acc, mul, add, stage.n_steps)
     return multi_threshold_sorted(acc, stage.thresholds)
 
 
@@ -204,7 +207,7 @@ class FusedThresholdStage:
     in_bits: int = 8
     in_signed: bool = False  # input codes signed (the graph input) or not
     mm_float: bool = False   # exact float32 GEMM path (see _float_mm_safe)
-    affine: Optional[tuple] = None   # exact O(1) activation (see _apply_act)
+    affine: Optional[jnp.ndarray] = None   # exact O(1) act. (_apply_act)
     block_m: Optional[int] = None    # tuned kernel row block (None = default)
     block_n: Optional[int] = None    # tuned kernel col block (None = default)
 
@@ -294,7 +297,7 @@ class FusedConvThresholdStage:
     in_bits: int = 8
     in_signed: bool = False  # input codes signed (the graph input) or not
     mm_float: bool = False   # exact float32 GEMM path (see _float_mm_safe)
-    affine: Optional[tuple] = None   # exact O(1) activation (see _apply_act)
+    affine: Optional[jnp.ndarray] = None   # exact O(1) act. (_apply_act)
     lowering: str = "direct"         # "direct" | "im2col"
     block_h: Optional[int] = None    # tuned output-row block (None = planner)
 
@@ -306,6 +309,13 @@ class FusedConvThresholdStage:
     def mxu(self) -> Tuple[str, int]:
         """(operand, x_offset) of the kernel path (``mxu_form``)."""
         return mxu_form(self.stage.w_int, self.in_bits, self.in_signed)
+
+    @property
+    def affine_on_kernel(self) -> bool:
+        """Whether the kernel path ends each row with the proven O(1)
+        ``affine`` (the direct-conv kernel does; the im2col lowering's
+        ``threshold_matmul`` counts the bank)."""
+        return self.affine is not None and self.lowering == "direct"
 
     @property
     def in_dim(self) -> int:
@@ -416,7 +426,9 @@ class FusedConvThresholdStage:
                 x, self.stage.w_int, self.stage.thresholds,
                 kernel=g.kernel, stride=g.stride, padding=g.padding,
                 out_h=g.out_h, out_w=g.out_w, block_h=self.block_h,
-                operand=operand, x_offset=x_offset, interpret=interpret)
+                operand=operand, x_offset=x_offset,
+                affine=self.affine if self.affine_on_kernel else None,
+                interpret=interpret)
         y = ops.threshold_matmul(
             self._cols2d(x_int), self.stage.w_int, self.stage.thresholds,
             operand=operand, x_offset=x_offset, interpret=interpret)
@@ -450,6 +462,11 @@ class ConvAddThresholdStage(FusedConvThresholdStage):
         if self.w_skip is None:
             return "int8", 0
         return mxu_form(self.w_skip, self.skip_bits, False)
+
+    @property
+    def affine_on_kernel(self) -> bool:
+        """Both lowerings run the join kernel, which takes the affine."""
+        return self.affine is not None
 
     @property
     def macs(self) -> int:
@@ -500,7 +517,7 @@ class ConvAddThresholdStage(FusedConvThresholdStage):
             padding=padding, out_h=g.out_h, out_w=g.out_w,
             skip_stride=self.skip_stride, block_h=self.block_h,
             operand=operand, x_offset=x_offset, skip_operand=skip_operand,
-            skip_offset=skip_offset, interpret=interpret)
+            skip_offset=skip_offset, affine=self.affine, interpret=interpret)
 
 
 @dataclasses.dataclass
@@ -769,6 +786,14 @@ class StageSchedule:
     @property
     def n_join(self) -> int:
         return sum(isinstance(s, ConvAddThresholdStage) for s in self.stages)
+
+    @property
+    def affine_stages(self) -> int:
+        """Threshold stages whose kernel ends each row with the O(1)
+        affine activation instead of counting the bank
+        (``affine_on_kernel``)."""
+        return sum(getattr(s, "affine_on_kernel", False)
+                   for s in self.stages)
 
     @property
     def host_segments(self) -> int:
@@ -1092,8 +1117,8 @@ def _is_po2(x) -> bool:
 
 
 def _exact_affine(m: ChainMatch, td: ThresholdDense, scale: float,
-                  mm_safe: bool, in_bits: int) -> Optional[tuple]:
-    """(mul, add) for the O(1) activation, or None when not provably exact.
+                  mm_safe: bool, in_bits: int):
+    """(mul; add) for the O(1) activation, or None when not provably exact.
 
     Requires: half-up flavor with an export-frozen s_out, pre-quantized
     weights whose per-channel scales (and in_scale/s_out) are powers of two,
@@ -1114,10 +1139,11 @@ def _exact_affine(m: ChainMatch, td: ThresholdDense, scale: float,
                            td.out_scale, colsum * ((1 << in_bits) - 1))
 
 
-def _affine_on_grid(g, b, s_out: float, acc_max) -> Optional[tuple]:
-    """(mul, add) of the O(1) activation for an accumulator on the po2 grid
-    ``g`` (per channel) whose magnitude is at most ``acc_max`` grid steps,
-    or None where float32 cannot evaluate it exactly."""
+def _affine_on_grid(g, b, s_out: float, acc_max):
+    """The (2, F) float32 ``mul; add`` of the O(1) activation for an
+    accumulator on the po2 grid ``g`` (per channel) whose magnitude is at
+    most ``acc_max`` grid steps, or None where float32 cannot evaluate it
+    exactly. The XLA path and the conv kernels take it as it is."""
     r1 = g / s_out                         # activation grid in code units
     if not (np.all(b / g == np.round(b / g)) and np.all(r1 <= 0.5)):
         return None                        # bias off-grid / 0.5 off-grid
@@ -1125,9 +1151,8 @@ def _affine_on_grid(g, b, s_out: float, acc_max) -> Optional[tuple]:
     k_max = acc_max + np.abs(b / g) + 0.5 / r1
     if not np.all(k_max < (1 << 24)):
         return None
-    mul = jnp.asarray((g / s_out).astype(np.float32))
-    add = jnp.asarray((b / s_out + 0.5).astype(np.float32))
-    return (mul, add)
+    return jnp.asarray(np.stack([g / s_out, b / s_out + 0.5]
+                                ).astype(np.float32))
 
 
 def stage_for(m: ChainMatch, scale: float, in_bits: int = 8,

@@ -57,6 +57,19 @@ ratio to the finer grid (``mul``), they are added in int32, and the sum
 is counted against the one threshold bank. Exact while the summed
 accumulator and the bank stay inside int32 (``deploy.lower``'s
 ``_join_int32_safe``).
+
+**Activation form.** Each output row ends in one of two exact forms,
+chosen by what the caller passes. Given the stage's proven ``affine``
+(a (2, F) float32 operand: ``mul``; ``add``, from ``deploy.lower``'s
+``_affine_on_grid``), the row's int32 total becomes
+``clip(floor(total * mul + add), 0, S)`` (``affine_codes``): a few vector
+ops whatever S, and the (S, F) bank is not passed at all. Under the int8
+form the kernel's total is shifted by the code offsets; the per-channel
+int32 correction is added back before the affine, so the affine sees the
+true total its proof bounds. Without an ``affine`` the row counts the
+bank (``count_thresholds``): S sequential compare steps, with the offsets
+folded into the bank: cheap for a binary or 3-bit stage's one to seven
+steps, the bulk of an 8-bit stage's time at 255.
 """
 
 from __future__ import annotations
@@ -68,8 +81,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.multi_threshold import (count_thresholds, mxu_dot,
-                                          shift_bank, to_operand)
+from repro.kernels.multi_threshold import (affine_codes, count_thresholds,
+                                          mxu_dot, shift_bank, to_operand)
 
 
 def same_pads(h: int, w: int, out_h: int, out_w: int, stride: int,
@@ -155,21 +168,59 @@ def _main_operands(x_pad, w2d, *, kernel: int, stride: int, out_h: int,
     return x_band, w_taps, kernel
 
 
-def _conv_thr_kernel(x_ref, w_ref, thr_ref, o_ref, *, kernel: int,
-                     block_h: int, out_w: int, n_steps: int, operand: str,
-                     x_offset: int, interpret: bool):
+def _act_operands(bank, affine, corr):
+    """The activation's kernel operands: the (S, F) ``bank`` (offsets
+    already folded in) where ``affine`` is None; else the (2, F) float32
+    ``affine`` and, where the int8 offsets shifted the accumulator, the
+    (1, F) int32 correction ``corr`` to add back. Each rides whole in
+    VMEM."""
+    if affine is None:
+        arrs = [bank.T]                                 # (S, F)
+    else:
+        arrs = [affine.astype(jnp.float32)]
+        if corr is not None:
+            arrs.append(corr.astype(jnp.int32)[None])
+    return arrs, [pl.BlockSpec(a.shape, lambda i, j: (0, 0)) for a in arrs]
+
+
+def _activation(act_refs, n_steps: int, affine: bool):
+    """The row activation as a function of the row's int32 total: the
+    bank count, or the affine form (module docstring). The affine's rows
+    load once per program, outside the row loop."""
+    if not affine:
+        thr_ref, = act_refs
+        return lambda total: count_thresholds(total, thr_ref, n_steps)
+    aff_ref, *corr_ref = act_refs
+    mul, add = aff_ref[pl.ds(0, 1), :], aff_ref[pl.ds(1, 1), :]
+    corr = corr_ref[0][...] if corr_ref else None
+
+    def act(total):
+        if corr is not None:
+            total = total + corr
+        return affine_codes(total, mul, add, n_steps)
+
+    return act
+
+
+def _conv_thr_kernel(x_ref, w_ref, *refs, kernel: int, block_h: int,
+                     out_w: int, n_steps: int, operand: str, x_offset: int,
+                     affine: bool, interpret: bool):
     """One (sample, output-row-block) program of a stride-1 conv.
 
     x_ref:   (1, 1, band_rows, WP, C) int32 — only this block's input rows
              (halo included); the grid pipeline double-buffers the band
              fetch against the previous program's tap matmuls
     w_ref:   (K*K, C, F)    MXU operand type — tap-major weights
-    thr_ref: (S, F)         int32 — threshold bank, steps-major
-    o_ref:   (1, block_h, OW, F)  int32 output codes
+    refs:    the activation's operands (``_act_operands``: the (S, F) int32
+             bank, or the (2, F) affine [and (1, F) correction]), then
+             o_ref (1, block_h, OW, F) int32 output codes
 
     Each output row is K*K tap matmuls of (OW, C) windows loaded straight
     from the ref at static offsets: no strided or reshaped values.
     """
+    *act_refs, o_ref = refs
+    activate = _activation(act_refs, n_steps, affine)
+
     def row(r, carry):
         acc = None
         for kh in range(kernel):                   # static K x K tap loop
@@ -178,7 +229,7 @@ def _conv_thr_kernel(x_ref, w_ref, thr_ref, o_ref, *, kernel: int,
                 part = mxu_dot(to_operand(xs, operand, x_offset),
                                w_ref[kh * kernel + kw], operand, interpret)
                 acc = part if acc is None else acc + part
-        o_ref[0, r] = count_thresholds(acc, thr_ref, n_steps)
+        o_ref[0, r] = activate(acc)
         return carry
 
     jax.lax.fori_loop(0, block_h, row, 0)
@@ -196,6 +247,7 @@ def conv_threshold(
     block_h: int,
     operand: str = "int8",
     x_offset: int = 0,
+    affine=None,                   # (2, F) float32 (mul; add), or None
     interpret: bool = False,
 ) -> jnp.ndarray:
     """One whole streamlined conv stage in a single kernel.
@@ -208,8 +260,9 @@ def conv_threshold(
     reads (halo included) — the Pallas pipeline then double-buffers the next
     band's fetch behind the current block's tap matmuls, instead of pinning
     the whole padded sample per program. ``operand``/``x_offset`` select the
-    exact MXU form (``kernels.multi_threshold``). Returns (N, out_h, out_w,
-    F) int32 codes.
+    exact MXU form (``kernels.multi_threshold``). With ``affine`` the rows
+    end in the O(1) activation and ``thresholds`` gives only S (module
+    docstring). Returns (N, out_h, out_w, F) int32 codes.
     """
     n, hp, wp, c = x_pad.shape
     f = w2d.shape[1]
@@ -219,7 +272,14 @@ def conv_threshold(
     assert out_h % block_h == 0, (out_h, block_h)
     assert hp >= (out_h - 1) * stride + kernel, (hp, out_h, stride, kernel)
     assert wp >= (out_w - 1) * stride + kernel, (wp, out_w, stride, kernel)
-    thr_t = shift_bank(thresholds, w2d, x_offset).T   # (S, F): lanes = chans
+    if affine is None:
+        act, act_specs = _act_operands(
+            shift_bank(thresholds, w2d, x_offset), None, None)
+    else:
+        assert affine.shape == (2, f), affine.shape
+        corr = (x_offset * jnp.sum(w2d.astype(jnp.int32), axis=0)
+                if x_offset else None)
+        act, act_specs = _act_operands(None, affine, corr)
     x_band, w_taps, kernel = _main_operands(
         x_pad, w2d, kernel=kernel, stride=stride, out_h=out_h, out_w=out_w,
         block_h=block_h, operand=operand)
@@ -230,13 +290,12 @@ def conv_threshold(
         functools.partial(
             _conv_thr_kernel, kernel=kernel, block_h=block_h, out_w=out_w,
             n_steps=s, operand=operand, x_offset=x_offset,
-            interpret=interpret),
+            affine=affine is not None, interpret=interpret),
         grid=(n, n_blocks),
         in_specs=[
             pl.BlockSpec((1, 1, br, wp, c), lambda i, j: (i, j, 0, 0, 0)),
             pl.BlockSpec((kernel * kernel, c, f), lambda i, j: (0, 0, 0)),
-            pl.BlockSpec((s, f), lambda i, j: (0, 0)),
-        ],
+        ] + act_specs,
         out_specs=pl.BlockSpec((1, block_h, out_w, f),
                                lambda i, j: (i, j, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((n, out_h, out_w, f), jnp.int32),
@@ -247,13 +306,13 @@ def conv_threshold(
         # the HLO instruction, and so the device op in a profiler trace,
         # is named ``conv_threshold.N`` after this, whatever wraps the call
         name="conv_threshold",
-    )(x_band, w_taps, thr_t)
+    )(x_band, w_taps, *act)
 
 
 def _conv_thr_add_kernel(x_ref, w_ref, s_ref, *refs, kernel: int,
                          block_h: int, out_w: int, n_steps: int,
                          operand: str, x_offset: int, proj: bool,
-                         skip_operand: str, skip_offset: int,
+                         skip_operand: str, skip_offset: int, affine: bool,
                          interpret: bool):
     """One (sample, output-row-block) program of a residual join.
 
@@ -262,15 +321,16 @@ def _conv_thr_add_kernel(x_ref, w_ref, s_ref, *refs, kernel: int,
              output rows: identity codes (Cs = F), or the block input of a
              1x1 projection, already taken at the projection's stride
     refs:    [wp_ref (Cs, F) projection weights,] mul_ref (2, F) int32 —
-             each branch's grid ratio to the finer grid —, thr_ref (S, F),
-             o_ref (1, block_h, OW, F)
+             each branch's grid ratio to the finer grid —, the activation's
+             operands (as ``_conv_thr_kernel``), o_ref (1, block_h, OW, F)
     """
     if proj:
-        wp_ref, mul_ref, thr_ref, o_ref = refs
+        wp_ref, mul_ref, *act_refs, o_ref = refs
     else:
-        mul_ref, thr_ref, o_ref = refs
+        mul_ref, *act_refs, o_ref = refs
     m_main = mul_ref[pl.ds(0, 1), :]                            # (1, F)
     m_skip = mul_ref[pl.ds(1, 1), :]
+    activate = _activation(act_refs, n_steps, affine)
 
     def row(r, carry):
         acc = None
@@ -284,8 +344,7 @@ def _conv_thr_add_kernel(x_ref, w_ref, s_ref, *refs, kernel: int,
         if proj:
             skip = mxu_dot(to_operand(skip, skip_operand, skip_offset),
                            wp_ref[...], skip_operand, interpret)
-        o_ref[0, r] = count_thresholds(acc * m_main + skip * m_skip,
-                                       thr_ref, n_steps)
+        o_ref[0, r] = activate(acc * m_main + skip * m_skip)
         return carry
 
     jax.lax.fori_loop(0, block_h, row, 0)
@@ -308,16 +367,19 @@ def conv_threshold_add(
     x_offset: int = 0,
     skip_operand: str = "int8",
     skip_offset: int = 0,
+    affine=None,                   # (2, F) float32 (mul; add), or None
     interpret: bool = False,
 ) -> jnp.ndarray:
     """One residual join in a single kernel: the main conv as
     ``conv_threshold`` computes it, plus the skip operand of the same output
     rows (identity codes, or a 1x1 projection of ``skip`` by ``w_skip``),
     each accumulator times its grid ratio ``mul``, summed in int32 and
-    counted against ``thresholds``. ``skip`` is padded to ``out_h`` rows
-    like the output. The int8 offsets of both branches fold into the bank:
-    ``mul[0] * x_offset * colsum(w2d) + mul[1] * skip_offset *
-    colsum(w_skip)``. Returns (N, out_h, out_w, F) int32 codes."""
+    counted against ``thresholds``, or with ``affine`` put through the O(1)
+    activation. ``skip`` is padded to ``out_h`` rows like the output. The
+    int8 offsets of both branches shift the sum by ``mul[0] * x_offset *
+    colsum(w2d) + mul[1] * skip_offset * colsum(w_skip)``: folded into the
+    bank, or added back before the affine. Returns (N, out_h, out_w, F)
+    int32 codes."""
     n, hp, wp, c = x_pad.shape
     f = w2d.shape[1]
     s = thresholds.shape[1]
@@ -335,7 +397,14 @@ def conv_threshold_add(
     if proj:
         corr = corr + mul[1] * skip_offset * jnp.sum(
             w_skip.astype(jnp.int32), axis=0)
-    thr_t = (thresholds.astype(jnp.int32) - corr[:, None]).T     # (S, F)
+    if affine is None:
+        act, act_specs = _act_operands(
+            thresholds.astype(jnp.int32) - corr[:, None], None, None)
+    else:
+        assert affine.shape == (2, f), affine.shape
+        act, act_specs = _act_operands(
+            None, affine, corr if x_offset or (proj and skip_offset)
+            else None)
     x_band, w_taps, kernel = _main_operands(
         x_pad, w2d, kernel=kernel, stride=stride, out_h=out_h, out_w=out_w,
         block_h=block_h, operand=operand)
@@ -350,16 +419,15 @@ def conv_threshold_add(
     if proj:
         operands.append(to_operand(w_skip, skip_operand))
         in_specs.append(pl.BlockSpec((cs, f), lambda i, j: (0, 0)))
-    operands += [mul, thr_t]
-    in_specs += [pl.BlockSpec((2, f), lambda i, j: (0, 0)),
-                 pl.BlockSpec((s, f), lambda i, j: (0, 0))]
+    operands += [mul] + act
+    in_specs += [pl.BlockSpec((2, f), lambda i, j: (0, 0))] + act_specs
 
     return pl.pallas_call(
         functools.partial(
             _conv_thr_add_kernel, kernel=kernel, block_h=block_h,
             out_w=out_w, n_steps=s, operand=operand, x_offset=x_offset,
             proj=proj, skip_operand=skip_operand, skip_offset=skip_offset,
-            interpret=interpret),
+            affine=affine is not None, interpret=interpret),
         grid=(n, n_blocks),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, block_h, out_w, f),

@@ -113,6 +113,17 @@ def count_thresholds(acc, thr_ref, n_steps: int, out=None):
     return jax.lax.fori_loop(0, n_steps, body, init)
 
 
+def affine_codes(total, mul, add, n_steps: int):
+    """The O(1) form of the threshold count: ``clip(floor(total * mul +
+    add), 0, S)`` per channel (``mul``/``add`` float32, broadcast over the
+    rows). Equal to counting the bank wherever the lowering proved it
+    (``deploy.lower._affine_on_grid``: every term a multiple of the
+    activation grid below 2^24, so float32 evaluates it exactly). One
+    definition for the XLA path and, in-kernel, the conv kernels."""
+    q = jnp.floor(total.astype(jnp.float32) * mul + add)
+    return jnp.clip(q, 0, n_steps).astype(jnp.int32)
+
+
 def _mt_kernel(acc_ref, thr_ref, o_ref, *, n_steps: int):
     o_ref[...] = count_thresholds(acc_ref[...], thr_ref, n_steps)
 

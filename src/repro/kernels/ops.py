@@ -176,7 +176,7 @@ def _conv_pads(h, w, out_h, out_w, stride, kernel, padding, block_h,
 def conv_threshold(x_int, w2d, thresholds, *, kernel: int, stride: int,
                    padding: str, out_h: int, out_w: int,
                    block_h: Optional[int] = None, operand: str = "int8",
-                   x_offset: int = 0, interpret: bool = False):
+                   x_offset: int = 0, affine=None, interpret: bool = False):
     """Fused direct-conv integer stage: NHWC codes -> threshold codes.
 
     Implicit im2col inside the Pallas kernel (shifted-window tap
@@ -187,7 +187,9 @@ def conv_threshold(x_int, w2d, thresholds, *, kernel: int, stride: int,
     the unpadded shape. ``w2d`` is the (kh*kw*cin, cout) im2col weight
     matrix, ``thresholds`` the (cout, S) bank — the same stage artifact the
     im2col lowering feeds ``threshold_matmul``; ``operand``/``x_offset``
-    its exact MXU form, as there.
+    its exact MXU form, as there. ``affine``, the stage's proven (2, F)
+    float32 (mul; add) or None, picks the row activation: the O(1) affine
+    form, or the count against ``thresholds``.
     """
     n, h, w, c = x_int.shape
     pads, bh, oh_pad = _conv_pads(h, w, out_h, out_w, stride, kernel,
@@ -196,7 +198,7 @@ def conv_threshold(x_int, w2d, thresholds, *, kernel: int, stride: int,
     y = _ct.conv_threshold(x_p, w2d, thresholds, kernel=kernel,
                            stride=stride, out_h=oh_pad, out_w=out_w,
                            block_h=bh, operand=operand, x_offset=x_offset,
-                           interpret=interpret)
+                           affine=affine, interpret=interpret)
     return y[:, :out_h]
 
 
@@ -210,7 +212,8 @@ def conv_threshold_add(x_int, w2d, skip, w_skip, mul, thresholds, *,
                        out_w: int, skip_stride: int = 1,
                        block_h: Optional[int] = None, operand: str = "int8",
                        x_offset: int = 0, skip_operand: str = "int8",
-                       skip_offset: int = 0, interpret: bool = False):
+                       skip_offset: int = 0, affine=None,
+                       interpret: bool = False):
     """Fused residual join: the main direct conv of ``x_int`` plus the skip
     branch, thresholded once (``kernels.conv_threshold.conv_threshold_add``).
 
@@ -220,8 +223,9 @@ def conv_threshold_add(x_int, w2d, skip, w_skip, mul, thresholds, *,
     nothing, so the wrapper takes them with one strided slice). ``mul`` is
     the (2, F) grid ratios of the two accumulators; ``thresholds`` the
     (F, S) bank on the finer grid. Padding, the row-block plan and the
-    operand forms are as ``conv_threshold``'s; the skip operand is
-    zero-padded to the padded output rows, whose codes are discarded.
+    operand forms and ``affine`` are as ``conv_threshold``'s; the skip
+    operand is zero-padded to the padded output rows, whose codes are
+    discarded.
     """
     n, h, w, c = x_int.shape
     pads, bh, oh_pad = _conv_pads(h, w, out_h, out_w, stride, kernel,
@@ -236,7 +240,7 @@ def conv_threshold_add(x_int, w2d, skip, w_skip, mul, thresholds, *,
         x_p, w2d, skip, w_skip, mul, thresholds, kernel=kernel,
         stride=stride, out_h=oh_pad, out_w=out_w, block_h=bh,
         operand=operand, x_offset=x_offset, skip_operand=skip_operand,
-        skip_offset=skip_offset, interpret=interpret)
+        skip_offset=skip_offset, affine=affine, interpret=interpret)
     return y[:, :out_h]
 
 

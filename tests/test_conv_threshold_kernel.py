@@ -18,6 +18,7 @@ block size, forced multi-block row grids, and tie-threshold inputs
 accumulator grid — the half-up tie rule fires on every step).
 """
 
+import jax
 import numpy as np
 import jax.numpy as jnp
 import pytest
@@ -97,6 +98,13 @@ def _check_all_paths(rng, h, w, c, f, k, stride, padding, bits, ties,
         stride=stride, padding=padding, out_h=oh, out_w=ow,
         block_h=block_h, interpret=True)
     np.testing.assert_array_equal(np.asarray(y_k), y_d)
+    # 4) the same kernel ending its rows in the stage's proven O(1) affine
+    if st_d.affine is not None:
+        y_a = ops.conv_threshold(
+            x_int, st_d.stage.w_int, st_d.stage.thresholds, kernel=k,
+            stride=stride, padding=padding, out_h=oh, out_w=ow,
+            block_h=block_h, affine=st_d.affine, interpret=True)
+        np.testing.assert_array_equal(np.asarray(y_a), y_d)
 
 
 @settings(max_examples=12, deadline=None)
@@ -208,3 +216,189 @@ def test_conv_threshold_rejects_bad_geometry():
     with pytest.raises(AssertionError):
         ct.conv_threshold(x, w2d, thr, kernel=3, stride=1, out_h=4,
                           out_w=2, block_h=3, interpret=True)  # 4 % 3 != 0
+
+
+# -- the O(1) affine activation on the kernel path ----------------------------
+
+#: (operand, x_offset) forms ``deploy.lower.mxu_form`` picks between
+FORMS = [("bf16", 0), ("int8", 128)]
+
+#: per output channel: the bank's shift in ramp units (channel 0's
+#: boundary i sits at ramp value i + shift), the centre tap's ramp weight
+#: and its noise weight
+CHANNELS = [(-20, 64, 0), (0, 64, 1), (-128, 32, -1), (-250, 64, 2)]
+
+
+def boundary_case(rng, n, hw, stride):
+    """A 3x3 SAME conv of 8-bit codes, and an 8-bit affine activation,
+    such that channel 0's accumulators meet every threshold boundary
+    exactly and fall below code 0 and past code 255.
+
+    Input channels 0 and 1 carry, at the centre pixel of each output's
+    window, a ramp d = x0 - x1 over [-255, 255]; channel 2 is noise.
+    Output channel f weighs the centre tap (r, -r, e) (``CHANNELS``) and,
+    past channel 0, every other tap with noise in [-2, 2]. On the po2
+    accumulator grid 2^-6 with ``s_out`` 1, boundary i lies at accumulator
+    ``64 * (i + shift)``: for channel 0, at ramp value i + shift. Returns
+    ``(x, w2d, grid, bias, s_out)``."""
+    from repro.kernels.conv_threshold import same_pads
+
+    oh = -(-hw // stride)
+    assert n * oh * oh >= 511, (n, hw, stride)
+    lo = same_pads(hw, hw, oh, oh, stride, 3)[0][0]
+    x = rng.integers(0, 256, (n, hw, hw, 3)).astype(np.int32)
+    d = np.resize(np.arange(-255, 256), n * oh * oh).reshape(n, oh, oh)
+    centre = np.arange(oh) * stride + 1 - lo
+    at = np.ix_(np.arange(n), centre, centre)
+    xs = x[at]
+    xs[..., 0], xs[..., 1] = np.maximum(d, 0), np.maximum(-d, 0)
+    x[at] = xs
+    f = len(CHANNELS)
+    w = rng.integers(-2, 3, (3, 3, 3, f))
+    w[..., 0] = 0
+    for c, (_, r, e) in enumerate(CHANNELS):
+        w[1, 1, :, c] = (r, -r, e)
+    grid = np.full(f, 2.0 ** -6)
+    shift = np.array([c[0] for c in CHANNELS], np.float64)
+    bias = grid * (-32 - 64 * shift)
+    return x, w.reshape(9 * 3, f).astype(np.int8), grid, bias, 1.0
+
+
+def affine_bank(grid, bias, s_out, steps=255):
+    """The (F, S) int32 bank the affine form stands for: boundary i at
+    ``ceil(((i - 0.5) * s_out - b) / g)`` (as ``deploy.lower`` builds a
+    join's)."""
+    i = np.arange(1, steps + 1, dtype=np.float64)
+    return np.ceil(((i[None] - 0.5) * s_out - bias[:, None])
+                   / grid[:, None]).astype(np.int32)
+
+
+def proven_affine(grid, bias, s_out, reach):
+    """The lowering's (mul, add) for this grid, which must exist."""
+    from repro.deploy.lower import _affine_on_grid
+
+    affine = _affine_on_grid(grid, bias, s_out, reach)
+    assert affine is not None
+    return affine
+
+
+def assert_meets_every_boundary(total, grid, bias, s_out, bank, codes):
+    """Channel 0's totals sit on each of its boundaries, and below code 0
+    and past code S before the clip; its codes take every value."""
+    steps = bank.shape[1]
+    pre = np.floor(total * grid / s_out + bias / s_out + 0.5)[..., 0]
+    assert np.isin(bank[0], total[..., 0]).all()
+    assert (pre < 0).any() and (pre > steps).any()
+    np.testing.assert_array_equal(np.unique(codes[..., 0]),
+                                  np.arange(steps + 1))
+
+
+def _stage(w2d, bank, affine, hw, stride):
+    from repro.core.streamline import ThresholdDense
+    from repro.deploy.lower import ConvGeom
+
+    f = w2d.shape[1]
+    oh = -(-hw // stride)
+    td = ThresholdDense(w_int=jnp.asarray(w2d),
+                        thresholds=jnp.asarray(bank), out_scale=1.0,
+                        act_bits=8, weight_bits=8)
+    return FusedConvThresholdStage(
+        name="conv", stage=td,
+        geom=ConvGeom(3, stride, "SAME", hw, hw, 3, oh, oh, f),
+        in_scale=2.0 ** -6, affine=affine)
+
+
+@pytest.mark.parametrize("stride", [1, 2])
+@pytest.mark.parametrize("operand,x_offset", FORMS)
+def test_affine_conv_kernel_is_bit_exact_on_every_boundary(
+        stride, operand, x_offset):
+    """The conv kernel ending its rows in the proven affine gives the
+    codes of the bank count (the same kernel without it), of a NumPy
+    count of the int64 accumulator, and of the stage's ``apply_ref``:
+    every boundary a tie, codes clipped at 0 and at 255, stride 1 and 2,
+    in both MXU forms."""
+    from repro.core.streamline import multi_threshold
+    from repro.kernels import ops
+    from repro.kernels.conv_threshold import direct_conv_acc, same_pads
+
+    rng = np.random.default_rng(16 + stride)
+    hw = 16 * stride
+    oh = hw // stride
+    x, w2d, grid, bias, s_out = boundary_case(rng, 2, hw, stride)
+    reach = np.sum(np.abs(w2d.astype(np.int64)), axis=0) * 255
+    affine = proven_affine(grid, bias, s_out, reach)
+    bank = affine_bank(grid, bias, s_out)
+    pads = same_pads(hw, hw, oh, oh, stride, 3)
+    acc = np.asarray(direct_conv_acc(
+        jnp.pad(jnp.asarray(x), ((0, 0), pads[0], pads[1], (0, 0))),
+        jnp.asarray(w2d), kernel=3, stride=stride, out_h=oh, out_w=oh))
+    want = np.asarray(multi_threshold(jnp.asarray(acc), jnp.asarray(bank)))
+    assert_meets_every_boundary(acc.astype(np.float64), grid, bias, s_out,
+                                bank, want)
+    kw = dict(kernel=3, stride=stride, padding="SAME", out_h=oh, out_w=oh,
+              block_h=4, operand=operand, x_offset=x_offset,
+              interpret=True)
+    x, w2d, bank = jnp.asarray(x), jnp.asarray(w2d), jnp.asarray(bank)
+    counted = ops.conv_threshold(x, w2d, bank, **kw)
+    affined = ops.conv_threshold(x, w2d, bank, affine=affine,
+                                 **kw)
+    np.testing.assert_array_equal(np.asarray(counted), want)
+    np.testing.assert_array_equal(np.asarray(affined), want)
+    stage = _stage(w2d, bank, affine, hw, stride)
+    assert stage.affine_on_kernel
+    np.testing.assert_array_equal(np.asarray(stage.apply_ref(x)), want)
+    np.testing.assert_array_equal(
+        np.asarray(stage.apply_kernel(x, interpret=True)), want)
+
+
+def _pallas_operands(fn, *args):
+    """The operand avals of every ``pallas_call`` in ``fn``'s jaxpr."""
+    found = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                found.append([v.aval for v in eqn.invars])
+                continue
+            for p in eqn.params.values():
+                for sub in (p if isinstance(p, (list, tuple)) else [p]):
+                    inner = getattr(sub, "jaxpr", sub)
+                    if hasattr(inner, "eqns"):
+                        walk(inner)
+
+    walk(jax.make_jaxpr(fn)(*args).jaxpr)
+    return found
+
+
+def test_a_stage_without_affine_passes_the_bank_and_no_affine():
+    """A CNV binary conv (S = 1, no proven affine) reaches the conv kernel
+    with its (S, F) int32 bank and no float operand; an 8-bit stage with
+    an affine reaches it with the (2, F) float32 affine and no bank."""
+    import os
+
+    from repro.core.qir import Graph
+
+    golden = os.path.join(os.path.dirname(__file__), "golden")
+    g = Graph.load(os.path.join(golden, "cnv.qir.json"))
+    binary = next(s for s in lower_graph(g, in_scale=g.meta["in_scale"]
+                                         ).stages
+                  if isinstance(s, FusedConvThresholdStage))
+    assert binary.affine is None and binary.stage.n_steps == 1
+    assert not binary.affine_on_kernel
+    gm = binary.geom
+    x = jnp.zeros((2, gm.in_h, gm.in_w, gm.in_ch), jnp.int32)
+    (avals,) = _pallas_operands(
+        lambda v: binary.apply_kernel(v, interpret=True), x)
+    assert [(a.shape, a.dtype) for a in avals[2:]] == \
+        [((1, gm.out_ch), jnp.int32)]
+
+    rng = np.random.default_rng(0)
+    x8, w2d, grid, bias, s_out = boundary_case(rng, 2, 16, 1)
+    reach = np.sum(np.abs(w2d.astype(np.int64)), axis=0) * 255
+    stage = _stage(w2d, affine_bank(grid, bias, s_out),
+                   proven_affine(grid, bias, s_out, reach), 16, 1)
+    (avals,) = _pallas_operands(
+        lambda v: stage.apply_kernel(v, interpret=True), jnp.asarray(x8))
+    assert [(a.shape, a.dtype) for a in avals[2:]] == \
+        [((2, 4), jnp.float32)]
+

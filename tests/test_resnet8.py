@@ -19,13 +19,17 @@ import pytest
 
 from repro.core.qir import Graph, Node, QuantSpec, eval_node, export_qcnn
 from repro.deploy import compile_graph
-from repro.deploy.lower import (ConvAddThresholdStage, FlattenStage,
+from repro.core.streamline import ThresholdDense
+from repro.deploy.lower import (ConvAddThresholdStage, ConvGeom, FlattenStage,
                                 FloatHeadStage, FusedConvThresholdStage,
                                 IntSumPoolStage, RefChainStage, im2col,
                                 lower_graph)
 from repro.kernels import ops
 from repro.models.tiny import ResNet8
 from repro.obs import Tracer
+from test_conv_threshold_kernel import (FORMS, affine_bank,
+                                        assert_meets_every_boundary,
+                                        boundary_case, proven_affine)
 
 SMALL = ResNet8(filters=(4, 8, 16), in_hw=8)
 
@@ -124,6 +128,71 @@ def test_conv_threshold_add_is_bit_exact(proj, skip_stride, operand,
         block_h=4, operand=operand, x_offset=x_offset, skip_operand=operand,
         skip_offset=x_offset, interpret=True)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+@pytest.mark.parametrize("operand,x_offset", FORMS)
+@pytest.mark.parametrize("proj,skip_stride", [(False, 1), (True, 2)])
+def test_join_affine_path_is_bit_exact_on_every_boundary(
+        proj, skip_stride, operand, x_offset):
+    """The join kernel ending its rows in the proven affine gives the codes
+    of its bank count (the same kernel without it), of a NumPy count of
+    the int64 total and of the join stage's ``apply_ref``: identity codes
+    and a 1x1 stride-2 projection, both MXU forms (the offsets of both
+    branches added back before the affine), every boundary a tie on
+    channel 0, whose skip branch is zero, and codes clipped at 0 and at
+    255."""
+    rng = np.random.default_rng(160 + skip_stride)
+    n, hw = 2, 16
+    x, w2d, grid, bias, s_out = boundary_case(rng, n, hw, 1)
+    f = w2d.shape[1]
+    mul = np.array([[1, 2, 1, 1], [1, 4, 1, 2]], np.int32)
+    if proj:
+        cs = 5
+        skip = rng.integers(0, 256, (n, hw * skip_stride, hw * skip_stride,
+                                     cs)).astype(np.int32)
+        w_skip = rng.integers(-3, 4, (cs, f)).astype(np.int8)
+        w_skip[:, 0] = 0
+        acc_skip = (skip[:, ::skip_stride, ::skip_stride].astype(np.int64)
+                    @ w_skip.astype(np.int64))
+        skip_reach = np.sum(np.abs(w_skip.astype(np.int64)), axis=0) * 255
+    else:
+        skip = rng.integers(0, 256, (n, hw, hw, f)).astype(np.int32)
+        skip[..., 0] = 0
+        w_skip, acc_skip = None, skip.astype(np.int64)
+        skip_reach = np.full(f, 255)
+    cols = np.asarray(im2col(jnp.asarray(x), 3, 1, "SAME"), np.int64)
+    total = (cols @ w2d.astype(np.int64)) * mul[0] + acc_skip * mul[1]
+    reach = (np.sum(np.abs(w2d.astype(np.int64)), axis=0) * 255 * mul[0]
+             + skip_reach * mul[1])
+    affine = proven_affine(grid, bias, s_out, reach)
+    bank = affine_bank(grid, bias, s_out)
+    want = np.asarray(_count(jnp.asarray(total), jnp.asarray(bank)))
+    assert_meets_every_boundary(total.astype(np.float64), grid, bias, s_out,
+                                bank, want)
+    args = (jnp.asarray(x), jnp.asarray(w2d), jnp.asarray(skip),
+            None if w_skip is None else jnp.asarray(w_skip),
+            jnp.asarray(mul), jnp.asarray(bank))
+    kw = dict(kernel=3, stride=1, padding="SAME", out_h=hw, out_w=hw,
+              skip_stride=skip_stride, block_h=4, operand=operand,
+              x_offset=x_offset, skip_operand=operand, skip_offset=x_offset,
+              interpret=True)
+    np.testing.assert_array_equal(
+        np.asarray(ops.conv_threshold_add(*args, **kw)), want)
+    np.testing.assert_array_equal(np.asarray(ops.conv_threshold_add(
+        *args, affine=affine, **kw)), want)
+    td = ThresholdDense(w_int=jnp.asarray(w2d), thresholds=jnp.asarray(bank),
+                        out_scale=s_out, act_bits=8, weight_bits=8)
+    stage = ConvAddThresholdStage(
+        name="join", stage=td,
+        geom=ConvGeom(3, 1, "SAME", hw, hw, 3, hw, hw, f), in_scale=1.0,
+        affine=affine, skip_from=0,
+        w_skip=None if w_skip is None else jnp.asarray(w_skip),
+        skip_stride=skip_stride, mul=mul)
+    assert stage.affine_on_kernel
+    for got in (stage.apply_ref(jnp.asarray(x), jnp.asarray(skip)),
+                stage.apply_kernel(jnp.asarray(x), jnp.asarray(skip),
+                                   interpret=True)):
+        np.testing.assert_array_equal(np.asarray(got), want)
 
 
 # -- QIR ops ------------------------------------------------------------------
@@ -338,6 +407,52 @@ def test_launch_span_reports_skip_bytes():
     per_row = 4 * (8 * 8 * 4 + 8 * 8 * 4 + 4 * 4 * 8)
     assert args["host_segments"] == 0
     assert args["skip_bytes"] == 4 * per_row
+
+
+@pytest.mark.parametrize("use_pallas", [False, True])
+def test_launch_span_reports_affine_stages(use_pallas):
+    """``executor.launch`` carries ``affine_stages``: the 7 threshold
+    stages (4 convs, 3 joins) whose kernel ends its rows in the O(1)
+    affine; 0 where the model runs the XLA path, which runs no kernel."""
+    graph = export_qcnn(SMALL, _folded_params(SMALL, 1))
+    tr = Tracer()
+    cm = compile_graph(graph, in_scale=graph.meta["in_scale"],
+                       use_pallas=use_pallas, interpret=use_pallas,
+                       tracer=tr)
+    assert cm.schedule.affine_stages == 7
+    cm.submit_wave(np.zeros((3, 8, 8, 3), np.int32), micro_batch=4)
+    if not use_pallas:
+        cm.streaming_compiled(np.zeros((3, 8, 8, 3), np.int32),
+                              micro_batch=4)
+    spans = [s for s in tr.spans() if s.name == "executor.launch"]
+    assert spans and all(s.args["affine_stages"] == (7 if use_pallas else 0)
+                         for s in spans)
+
+
+@pytest.mark.parametrize("name,want", [("resnet8", 7), ("cnv", 0),
+                                       ("kws", 0)])
+def test_benchmark_schedules_count_their_affine_stages(name, want):
+    """The benchmark's configurations at their published widths, from
+    seeded weights: every ResNet-8 threshold stage (S = 255) proves the
+    affine; no CNV (S = 1) or KWS (S = 7) stage does."""
+    import importlib
+    import json
+    import os
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    sys.path.insert(0, root)
+    mod = importlib.import_module(f"chipbench.configs.{name}")
+    with open(os.path.join(root, "chipbench", "configs",
+                           f"{name}.json")) as f:
+        cfg = json.load(f)
+    params = jax.jit(lambda k: mod.make_params(cfg, k))(
+        jax.random.PRNGKey(3000016001))
+    graph, in_scale = mod.build_graph(cfg, params)
+    sched = lower_graph(graph, in_scale=in_scale)
+    assert sched.affine_stages == want
+    assert all(s.affine_on_kernel == (want > 0) for s in sched.stages
+               if isinstance(s, FusedConvThresholdStage))
 
 
 # -- the model ----------------------------------------------------------------

@@ -206,3 +206,75 @@ def test_resnet8_joins_compile(one_chip, hw, ch, skip_ch, skip_stride,
                          r"tpu_custom_call", compiled.as_text()))
     assert got and all(re.fullmatch(r"conv_threshold_add\.\d+", n)
                        for n in got), got
+
+
+F32 = jnp.float32
+
+
+def _kernel_names(compiled):
+    import re
+
+    return set(re.findall(r"%([\w.-]+) = \S+ custom-call\([^\n]*"
+                          r"tpu_custom_call", compiled.as_text()))
+
+
+#: ResNet-8's four plain convs at full width: (input width, in -> out
+#: channels, stride): the stem, then each stack's first conv
+RESNET8_CONVS = [(32, 3, 16, 1), (32, 16, 16, 1), (32, 16, 32, 2),
+                 (16, 32, 64, 2)]
+
+
+@pytest.mark.parametrize("hw,cin,cout,stride", RESNET8_CONVS)
+@pytest.mark.parametrize("operand,x_offset", FORMS)
+def test_resnet8_convs_compile_on_the_affine_path(one_chip, hw, cin, cout,
+                                                  stride, operand, x_offset):
+    """Each plain conv ending its rows in the O(1) affine activation (a
+    (2, F) float32 operand in place of the 255-step bank), one wave of 256
+    rows; the stem sees signed input codes. The kernel keeps the name
+    ``conv_threshold`` that the trace reducers and the roofline match."""
+    import re
+
+    offset = 0 if cin == 3 else x_offset
+    out = -(-hw // stride)
+
+    def fn(x, w, t, a):
+        return ops.conv_threshold(
+            x, w, t, kernel=3, stride=stride, padding="SAME", out_h=out,
+            out_w=out, operand=operand, x_offset=offset, affine=a)
+
+    got = _kernel_names(_compile(
+        one_chip, fn, ((256, hw, hw, cin), I32), ((9 * cin, cout), I8),
+        ((cout, 255), I32), ((2, cout), F32)))
+    assert got and all(re.fullmatch(r"conv_threshold\.\d+", n)
+                       for n in got), got
+
+
+@pytest.mark.parametrize("hw,ch,skip_ch,skip_stride", RESNET8_JOINS)
+@pytest.mark.parametrize("operand,x_offset", FORMS)
+def test_resnet8_joins_compile_on_the_affine_path(one_chip, hw, ch, skip_ch,
+                                                  skip_stride, operand,
+                                                  x_offset):
+    """Each residual join ending its rows in the O(1) affine activation,
+    with the offsets of both branches added back first in the int8 form;
+    the kernel keeps the name ``conv_threshold_add``."""
+    import re
+
+    proj = skip_ch > 0
+
+    def fn(x, w, skip, *rest):
+        w_skip, mul, t, a = rest if proj else (None, *rest)
+        return ops.conv_threshold_add(
+            x, w, skip, w_skip, mul, t, kernel=3, stride=1, padding="SAME",
+            out_h=hw, out_w=hw, skip_stride=skip_stride, operand=operand,
+            x_offset=x_offset, skip_operand=operand, skip_offset=x_offset,
+            affine=a)
+
+    skip_hw = hw * skip_stride
+    shapes = [((256, hw, hw, ch), I32), ((9 * ch, ch), I8),
+              ((256, skip_hw, skip_hw, skip_ch if proj else ch), I32)]
+    if proj:
+        shapes.append(((skip_ch, ch), I8))
+    shapes += [((2, ch), I32), ((ch, 255), I32), ((2, ch), F32)]
+    got = _kernel_names(_compile(one_chip, fn, *shapes))
+    assert got and all(re.fullmatch(r"conv_threshold_add\.\d+", n)
+                       for n in got), got
