@@ -1,6 +1,6 @@
 .PHONY: test test-fast bench bench-table6 bench-scenarios bench-serve \
-	bench-scaling bench-obs bench-costmodel trace-demo lint lint-clock \
-	lint-residency lint-assert lint-costmodel chaos example
+	bench-scaling bench-obs trace-demo lint lint-clock lint-residency \
+	lint-assert chaos example
 
 test:            ## full tier-1 suite
 	./scripts/test.sh
@@ -26,13 +26,10 @@ bench-scaling:   ## throughput-at-SLO vs replica count (simulated pool)
 bench-obs:       ## NullTracer overhead assert + FIFO prediction-error table
 	PYTHONPATH=src:. REPRO_FAST=$(REPRO_FAST) python benchmarks/obs_bench.py
 
-bench-costmodel: ## learned-predictor LOMO error + probed-vs-predicted autotune
-	PYTHONPATH=src:. REPRO_FAST=$(REPRO_FAST) python benchmarks/costmodel_bench.py
-
 trace-demo:      ## one traced server run -> Perfetto timeline artifact
 	PYTHONPATH=src:. python benchmarks/obs_bench.py --demo
 
-lint: lint-clock lint-residency lint-assert lint-costmodel  ## every static check CI runs
+lint: lint-clock lint-residency lint-assert  ## every static check CI runs
 
 lint-clock:      ## no raw stdlib clock reads outside repro.obs.timer
 	python scripts/check_no_raw_clock.py
@@ -42,9 +39,6 @@ lint-residency:  ## megakernel plans never exceed the VMEM cap (goldens)
 
 lint-assert:     ## no bare asserts in serve/deploy (python -O safety)
 	python scripts/check_no_bare_assert.py
-
-lint-costmodel:  ## shipped predictor artifact matches the live feature schema
-	python scripts/check_costmodel_schema.py
 
 chaos:           ## deterministic fault-injection suite, plain and under -O
 	PYTHONPATH=src python -m pytest -x -q tests/test_faults.py

@@ -11,12 +11,9 @@ from __future__ import annotations
 
 import math
 
-import numpy as np
-
 from benchmarks.common import banner, print_rows, row
 from repro.core.bops import conv_cost, dense_cost, ModelCost
-from repro.core.search import Choice, bo_search, pareto_front, predictor_sweep
-from repro.costmodel import features_from_model_cost, load_default
+from repro.core.search import Choice, bo_search, pareto_front
 
 
 def ic_cost(n_stacks, filters, ksize, stride) -> ModelCost:
@@ -78,40 +75,6 @@ def run():
                     mflops=12.8, accuracy=0.835,
                     note="BO narrows to few-filter-dominated front, matching"))
 
-    # -- predictor-evaluated codesign sweep: the same architecture space
-    # crossed with the serving micro-batch, scored by the learned wave-cost
-    # predictor instead of wall-clock (ROADMAP direction 5). Accuracy uses
-    # the noise-free surrogate so the Pareto geometry is deterministic.
-    predictor = load_default()
-    space = [
-        Choice("filters", (2, 4, 8, 16, 32)),
-        Choice("kernel", (1, 2, 3)),
-        Choice("stride", (1, 2, 4)),
-        Choice("stacks", (1, 2, 3)),
-        Choice("micro_batch", (1, 4, 16, 64)),
-    ]
-
-    def feature_fn(cfg):
-        mc = ic_cost(cfg["stacks"], cfg["filters"], cfg["kernel"],
-                     cfg["stride"])
-        return features_from_model_cost(
-            mc, cfg["micro_batch"], n_conv_stages=3 * cfg["stacks"])
-
-    sweep = predictor_sweep(
-        predictor.predict_ms, feature_fn, space, method="bo", n_trials=96,
-        seed=0,
-        accuracy_fn=lambda cfg: surrogate_accuracy(
-            cfg, 10**8, np.random.default_rng(0)))
-    best = sweep["best"]
-    rows.append(row(
-        "fig2/predictor_codesign_sweep",
-        n_evaluated=sweep["n_evaluated"],
-        best_cfg=(f"f{best['config']['filters']}k{best['config']['kernel']}"
-                  f"s{best['config']['stride']}x{best['config']['stacks']}"
-                  f"mb{best['config']['micro_batch']}"),
-        best_predicted_ms=f"{best['predicted_ms']:.3f}",
-        pareto_points=len(sweep["pareto"]),
-        note="learned-cost sweep, zero wall-clock evaluations"))
     print_rows(rows)
     return rows
 

@@ -13,9 +13,7 @@ import numpy as np
 
 from benchmarks.common import banner, print_rows, row
 from repro.core.bops import ModelCost, conv_cost, dense_cost
-from repro.core.search import Choice, asha_search, pareto_front, \
-    predictor_sweep
-from repro.costmodel import features_from_model_cost, load_default
+from repro.core.search import Choice, asha_search, pareto_front
 
 
 def cnv_cost(channels_scale, fc_units, w_bits, a_bits) -> ModelCost:
@@ -84,36 +82,6 @@ def run():
         paper_finding="CNV-W1A1 performs near optimally",
     )]
 
-    # -- predictor-evaluated codesign sweep: quantization x architecture x
-    # serving micro-batch, ranked by the learned wave-cost predictor — the
-    # Fig. 3 scan re-run without wall-clock (ROADMAP direction 5). ASHA's
-    # rungs degenerate to one evaluation each (predictions are exact), but
-    # the promotion bookkeeping is exercised on the predictor objective.
-    predictor = load_default()
-    codesign_space = space + [Choice("micro_batch", (1, 4, 16, 64))]
-
-    def feature_fn(cfg):
-        mc = cnv_cost(cfg["scale"], cfg["fc"], cfg["w_bits"], cfg["a_bits"])
-        return features_from_model_cost(mc, cfg["micro_batch"],
-                                        n_conv_stages=6)
-
-    sweep = predictor_sweep(
-        predictor.predict_ms, feature_fn, codesign_space, method="asha",
-        n_trials=64, seed=0,
-        accuracy_fn=lambda cfg: surrogate_accuracy(
-            cfg, 10**8, np.random.default_rng(0)))
-    best_pred = sweep["best"]
-    rows.append(row(
-        "fig3/predictor_codesign_sweep",
-        n_evaluated=sweep["n_evaluated"],
-        best_cfg=(f"x{best_pred['config']['scale']}"
-                  f"fc{best_pred['config']['fc']}"
-                  f"w{best_pred['config']['w_bits']}"
-                  f"a{best_pred['config']['a_bits']}"
-                  f"mb{best_pred['config']['micro_batch']}"),
-        best_predicted_ms=f"{best_pred['predicted_ms']:.3f}",
-        pareto_points=len(sweep["pareto"]),
-        note="learned-cost sweep, zero wall-clock evaluations"))
     print_rows(rows)
     return rows
 

@@ -21,14 +21,14 @@ import jax.numpy as jnp
 import numpy as np
 
 from benchmarks.common import banner, emit_json, print_rows, row, time_call
-from repro.core.bops import stage_cost
+from repro.core.bops import conv_input_band_bytes, stage_cost
 from repro.core.streamline import make_threshold_stage
-from repro.deploy.autotune import plan_block_h
 from repro.deploy.lower import (
     ConvGeom,
     FusedConvThresholdStage,
     _float_mm_safe,
 )
+from repro.kernels.ops import plan_conv_blocks
 from repro.kernels.ref import qmatmul_ref
 
 
@@ -104,9 +104,9 @@ def _conv_lowering_bench(rng):
     t_i2c = time_call(f_i2c, x)
     traffic_d = stage_cost(direct).traffic_bytes
     traffic_i = stage_cost(i2c).traffic_bytes
-    # the block_h model the autotuner runs: banded input bytes (halo rows
-    # re-fetched per block) vs VMEM fit, per candidate row block
-    plan = plan_block_h(geom)
+    # the kernel planner's row block and its banded input bytes (halo rows
+    # re-fetched per block)
+    block_h = plan_conv_blocks(geom.out_h, geom.out_w, geom.out_ch)
     return [
         row("kernel/conv_threshold_direct", t_direct,
             hbm_bytes_model=int(traffic_d)),
@@ -115,12 +115,8 @@ def _conv_lowering_bench(rng):
             im2col_bytes=int(traffic_i - traffic_d),
             direct_speedup=f"{t_i2c / max(t_direct, 1e-9):.2f}x"),
         row("kernel/conv_threshold_block_h", 0.0,
-            tuned_block_h=plan["block_h"],
-            banded_input_bytes=int(plan["input_bytes"]),
-            candidates=";".join(
-                f"{c['block_h']}:{int(c['input_bytes'])}"
-                + ("" if c["fits_vmem"] else "!vmem")
-                for c in plan["candidates"])),
+            block_h=block_h,
+            banded_input_bytes=int(conv_input_band_bytes(geom, block_h))),
     ]
 
 

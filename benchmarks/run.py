@@ -16,7 +16,6 @@ import time
 import traceback
 
 from benchmarks import (
-    costmodel_bench,
     fig2_bo_scan,
     fig3_asha_scan,
     fig4_quant_scan,
@@ -44,7 +43,6 @@ SECTIONS = {
     "kernels": kernel_bench.run,
     "serve": serve_bench.run,
     "obs": obs_bench.run,
-    "costmodel": costmodel_bench.run,
 }
 
 

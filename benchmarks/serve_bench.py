@@ -1,8 +1,8 @@
 """Serving benchmark: throughput-at-SLO curves over the dynamic batcher.
 
 The first benchmark gated on *tail latency under load* rather than
-single-query speed: every Table-1 model is compiled, autotuned, given an
-SLO-constrained operating point (``deploy.autotune.slo_micro_batch`` — the
+single-query speed: every Table-1 model is compiled, given an
+SLO-constrained operating point (``serve.slo.slo_operating_point`` — the
 largest wave whose modeled fill+drain fits the p99 budget), and then
 driven through the ``repro.serve`` router with Poisson arrivals at a sweep
 of load fractions of its modeled saturation throughput. Each point reports
@@ -42,13 +42,11 @@ import numpy as np
 
 from benchmarks.common import banner, emit_json, print_rows, row
 from benchmarks.table6_scenarios import _compile_conv, _compile_mlp
-from repro.deploy.autotune import autotune_model
 from repro.deploy.scenarios import server_streaming
 from repro.models.tiny import ADAutoencoder, CNVModel, ICModel, KWSMLP
 from repro.serve import (
     AsyncEngine,
     ManualClock,
-    PredictedServiceModel,
     Router,
     RouterConfig,
     ServiceModel,
@@ -79,36 +77,34 @@ SCALING_REPLICAS = (1, 2, 4)
 SCALING_FRACTIONS = (0.25, 0.4, 0.6, 0.7, 0.8, 0.95)
 
 #: Queries per scaling simulation point (pure event loop — cheap).
-#: ``bench_scaling`` raises this to 40 waves' worth when the tuned wave
+#: ``bench_scaling`` raises this to 40 waves' worth when the wave
 #: is large, so the p99 of a point never rests on a handful of waves.
 SCALING_QUERIES = 160 if FAST else 400
 
 
 def _budget_ms(service: ServiceModel, micro_batch: int) -> float:
-    """Per-model p99 budget: 6x the modeled tuned-wave service time,
+    """Per-model p99 budget: 6x the modeled default-wave service time,
     floored at 10 ms. Derived (not hard-coded) so the same bench stays
     meaningful across machines an order of magnitude apart."""
     return max(10.0, 6.0 * service.wave_service_s(micro_batch) * 1e3)
 
 
 def bench_model(name: str, cm, mk, n_queries: int):
-    cfg = autotune_model(cm, batch=32 if FAST else 64)
-    cm.apply_tuned(cfg)
     # model-first service estimate, pinned to reality by ONE measured wave
-    # probe at the tuned wave size — stage compute alone misses the
+    # probe at the default wave size — stage compute alone misses the
     # per-wave dispatch overhead that dominates small models on CPU, and a
     # capacity plan from the raw model would sweep pure overload
     service = ServiceModel.from_compiled(cm, probe_batch=8)
-    tuned_mb = cm.default_micro_batch
+    default_mb = cm.default_micro_batch
     service = service.recalibrated(
-        measure_wave_service_s(cm, tuned_mb), tuned_mb)
-    budget = _budget_ms(service, tuned_mb)
+        measure_wave_service_s(cm, default_mb), default_mb)
+    budget = _budget_ms(service, default_mb)
     # the wave's own service may take at most ~25% of the budget: the
     # admission estimate adds the batching wait (1.5x service below) and
     # queued waves on top, and est(empty queue) must clear the budget or
     # the controller sheds everything before the first wave forms.
     # Fixed-point-iterate the choice: dispatch overhead is flat across
-    # wave sizes, so a model calibrated at the tuned wave is optimistic
+    # wave sizes, so a model calibrated at the default wave is optimistic
     # about smaller waves — re-measure at the chosen wave until it
     # settles, and the modeled saturation the sweep scales is honest.
     point = slo_operating_point(service, 0.25 * budget)
@@ -333,93 +329,6 @@ def bench_faults(service_s: float, mb: int, n_queries: int = 200,
     }
 
 
-# ---------------------------------------------------------------------------
-# cold start: predictor-priced admission from wave 0 vs the unpriced path
-# ---------------------------------------------------------------------------
-
-def _fleet_predicted_service_s(entries, measured, cold_name: str) -> float:
-    """Predict the cold family's wave service from the REST of the fleet.
-
-    The fleet story end to end: train a ``repro.costmodel`` predictor on
-    the other families' measured wave anchors (features from their static
-    compiled structure), then price the cold family's wave having never
-    measured it — leave-one-out at the fleet level, exactly what a server
-    must do for a model it has never seen."""
-    from repro.costmodel import WaveCostPredictor, wave_features
-
-    rows = []
-    for name, (cm, mk) in entries.items():
-        if name == cold_name:
-            continue
-        m = measured[name]
-        rows.append({"model": name,
-                     "features": wave_features(cm, m["micro_batch"]),
-                     "measured_ms": m["wave_service_ms"]})
-    pred = WaveCostPredictor.fit_rows(rows, l2=1.0, seed=0, n_members=4)
-    cold_cm = entries[cold_name][0]
-    mb = measured[cold_name]["micro_batch"]
-    return float(pred.predict_ms(wave_features(cold_cm, mb))) / 1e3
-
-
-def bench_cold_start(service_s: float, predicted_s: float, mb: int,
-                     n_queries: int = 480, seed: int = 31):
-    """A cold model at overload, with vs without predictor-priced admission.
-
-    Both runs are exact discrete-event simulations (``ManualClock`` +
-    scripted replica) of the same overloaded Poisson trace (2.5x
-    saturation) against a model the server has NEVER measured. The p99
-    budget is priced off the *prediction* (3x predicted service) — the
-    only service number a cold model has; pricing it off the true
-    service would assume the very measurement cold start lacks, and an
-    overestimating predictor would then shed everything and starve the
-    EWMA of the samples it needs to correct. The "predicted" run prices
-    admission from wave 0 with a ``PredictedServiceModel`` anchored on
-    the fleet predictor's estimate (the SLO controller's EWMA then
-    corrects toward the true service online); the "unpriced" run is the
-    status quo for an unmeasured model — no admission control, so
-    overload queues instead of shedding and the p99 blows through the
-    budget. The headline numbers are the p99 and shed-rate deltas
-    between the two."""
-    budget_ms = max(5.0, 3.0 * predicted_s * 1e3)
-    max_wait_ms = max(2.0, 1.5 * predicted_s * 1e3)
-    offered = 2.5 * (mb / service_s)
-    trace = poisson_trace(qps=offered, n=n_queries, seed=seed)
-    out = {"offered_qps": offered, "load_fraction": 2.5,
-           "micro_batch": mb, "wave_service_ms": service_s * 1e3,
-           "predicted_wave_ms": predicted_s * 1e3,
-           "prediction_rel_err": abs(predicted_s - service_s) / service_s,
-           "p99_budget_ms": budget_ms, "n_queries": n_queries}
-    for label, priced in (("predicted", True), ("unpriced", False)):
-        clock = ManualClock()
-        pool = scripted_pool(clock, [service_s], micro_batch=mb)
-        router = Router(
-            {"m": pool},
-            RouterConfig(max_wait_ms=max_wait_ms, micro_batch=mb,
-                         p99_budget_ms=budget_ms if priced else None),
-            clock=clock,
-            service_models={"m": PredictedServiceModel.from_table(
-                [("s", 0)], {mb: predicted_s})} if priced else None,
-            engine=AsyncEngine())
-        reqs = router.run_trace(
-            "m", trace, lambda i: np.full((2,), i % 128, np.int32))
-        served = [r for r in reqs if not r.shed]
-        lats_ms = np.asarray([r.latency_s for r in served]) * 1e3
-        p99 = float(np.percentile(lats_ms, 99)) if served else None
-        out[label] = {
-            "served": len(served),
-            "shed_rate": 1.0 - len(served) / len(reqs),
-            "p99_ms": p99,
-            "met_slo": p99 is not None and p99 <= budget_ms,
-        }
-    if (out["predicted"]["p99_ms"] is not None
-            and out["unpriced"]["p99_ms"] is not None):
-        out["p99_delta_ms"] = (out["unpriced"]["p99_ms"]
-                               - out["predicted"]["p99_ms"])
-    out["shed_rate_delta"] = (out["predicted"]["shed_rate"]
-                              - out["unpriced"]["shed_rate"])
-    return out
-
-
 def _build_entries(key, rng):
     entries = {}
     kws, ad = KWSMLP(), ADAutoencoder()
@@ -459,16 +368,14 @@ def _scaling_rows(name: str, sc) -> list:
 
 
 def run_scaling_only():
-    """Standalone replica-scaling sweep: autotune each family, measure its
-    real wave service time, and run the discrete-event sweep from that
-    anchor — skipping the full load-curve bench."""
+    """Standalone replica-scaling sweep: measure each family's real wave
+    service time at the default wave size, and run the discrete-event
+    sweep from that anchor — skipping the full load-curve bench."""
     banner("Serving: replica scaling (simulated pool, measured service)")
     entries = _build_entries(jax.random.PRNGKey(0), np.random.default_rng(0))
     rows = []
     doc = {"fast": FAST, "models": {}}
     for name, (cm, mk) in entries.items():
-        cfg = autotune_model(cm, batch=32 if FAST else 64)
-        cm.apply_tuned(cfg)
         mb = cm.default_micro_batch
         sc = bench_scaling(name, measure_wave_service_s(cm, mb), mb)
         doc["models"][name] = sc
@@ -534,24 +441,6 @@ def run():
         post_shed=f"{flt['post_kill']['shed_rate']:.3f}",
         quarantined=flt["killed_replica_quarantined"],
         zero_lost=flt["zero_lost"]))
-    # cold-start row: the anchor family served as if NEVER measured —
-    # admission priced by the fleet predictor (trained on the other
-    # families' anchors) from wave 0, vs today's unpriced cold start
-    pred_s = _fleet_predicted_service_s(entries, doc["models"], anchor)
-    cold = bench_cold_start(doc["models"][anchor]["wave_service_ms"] / 1e3,
-                            pred_s, doc["models"][anchor]["micro_batch"])
-    doc["cold_start"] = {"anchor_model": anchor, **cold}
-    rows.append(row(
-        "serve/cold_start/predicted_vs_unpriced", 0.0,
-        predicted_ms=f"{cold['predicted_wave_ms']:.3f}",
-        true_ms=f"{cold['wave_service_ms']:.3f}",
-        pred_err=f"{cold['prediction_rel_err']:.2f}",
-        priced_p99_ms=(f"{cold['predicted']['p99_ms']:.3f}"
-                       if cold["predicted"]["p99_ms"] is not None else "-"),
-        unpriced_p99_ms=(f"{cold['unpriced']['p99_ms']:.3f}"
-                         if cold["unpriced"]["p99_ms"] is not None else "-"),
-        shed_delta=f"{cold['shed_rate_delta']:+.3f}",
-        priced_met_slo=cold["predicted"]["met_slo"]))
     print_rows(rows)
     emit_json("BENCH_serving.json", doc)
     return rows

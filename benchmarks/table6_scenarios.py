@@ -29,11 +29,10 @@ bit-exactness asserted between the two.
 The streaming section runs every model through BOTH streaming executors —
 the compiled segment-wave path (``streaming_compiled``: one jit program per
 segment wave, no host loop) and the host queue-loop reference
-(``streaming_host``) — at the micro-batch the FIFO-model autotuner
-(``deploy.autotune``) picked, asserts the three-way bit-equality
-(offline == host == compiled), and reports the compiled-vs-host speedup
-next to the tuned micro-batch / conv ``block_h`` and the modeled FIFO
-cycles / traffic bytes that chose them.
+(``streaming_host``) — at the executor's default micro-batch, asserts the
+three-way bit-equality (offline == host == compiled), and reports the
+compiled-vs-host speedup next to the FIFO plan's depths and modeled
+cycles.
 
 Everything is also emitted machine-readable to ``BENCH_scenarios.json``
 (``REPRO_BENCH_DIR``) so the perf trajectory is tracked across PRs.
@@ -50,11 +49,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from benchmarks.common import banner, emit_json, print_rows, row
+from benchmarks.common import banner, emit_json, print_rows, row, time_call
 from repro.core.bops import schedule_cost
 from repro.core.qir import export_qcnn, export_qmlp
 from repro.deploy import compile_graph
-from repro.deploy.autotune import autotune_model, probe_streaming
 from repro.deploy.scenarios import offline, server_streaming, single_stream
 from repro.models.tiny import ADAutoencoder, CNVModel, ICModel, KWSMLP
 from repro.serve import measure_wave_service_s
@@ -119,8 +117,7 @@ def run():
         entries[name] = (cm, mk, bits)
 
     rows = []
-    scenario_json = {"rows": [], "streaming": [], "tuned": {},
-                     "fast": FAST}
+    scenario_json = {"rows": [], "streaming": [], "fast": FAST}
     for name, (cm, mk, bits) in entries.items():
         conv = cm.schedule.n_fused_conv > 0
         cost = schedule_cost(cm.schedule.stages)
@@ -191,50 +188,44 @@ def run():
                 "im2col_traffic_bytes": t_i2c,
                 "beats_im2col": bool(qps_direct > qps_i2c)}
 
-    # -- streaming: tuned micro-batch, compiled segment waves vs the host
+    # -- streaming: default micro-batch, compiled segment waves vs the host
     #    queue loop, three-way bit-equality asserted --------------------------
     stream_rows = []
     for name, (cm, mk, _) in entries.items():
         conv = cm.schedule.n_fused_conv > 0
         n = (8 if conv else 16) if FAST else (16 if conv else 32)
-        cfg = autotune_model(cm, batch=n)
-        cm.apply_tuned(cfg)
-        scenario_json["tuned"][name] = cfg.to_dict()
         xb = jnp.asarray(np.stack([mk(i) for i in range(n)]), jnp.int32)
         y_off = cm.offline(xb)
-        y_cmp, st_c = cm.streaming_compiled(xb)           # tuned micro-batch
-        y_host, st_h = cm.streaming_host(xb, micro_batch=st_c.micro_batch)
+        y_cmp, st_c = cm.streaming_compiled(xb)
+        mb = st_c.micro_batch
+        y_host, st_h = cm.streaming_host(xb, micro_batch=mb)
         assert bool(jnp.all(jnp.asarray(y_cmp) == jnp.asarray(y_off))), name
         assert bool(jnp.all(jnp.asarray(y_host) == jnp.asarray(y_off))), name
-        t_cmp = probe_streaming(cm, xb, st_c.micro_batch, iters=5)
-        t_host = probe_streaming(cm, xb, st_c.micro_batch, iters=5,
-                                 runner=cm.streaming_host)
+        t_cmp = time_call(
+            lambda: cm.streaming_compiled(xb, micro_batch=mb)[0]) / 1e6
+        t_host = time_call(
+            lambda: cm.streaming_host(xb, micro_batch=mb)[0]) / 1e6
         speed = t_host / max(t_cmp, 1e-9)
         stream_rows.append(row(
             f"table6/{name}/Streaming", t_cmp * 1e6 / n,
             compiled_ms=f"{t_cmp * 1e3:.2f}",
             host_ms=f"{t_host * 1e3:.2f}",
             compiled_vs_host=f"{speed:.2f}x",
-            tuned_micro_batch=st_c.micro_batch,
-            tuned_block_h=cfg.block_h or "-",
-            modeled_cycles=cfg.modeled_cycles,
-            modeled_traffic_B=f"{cfg.modeled_traffic_bytes:.0f}",
+            micro_batch=mb,
+            modeled_cycles=st_c.sim_cycles,
             fifo_depths=str(st_h.fifo_depths),
             segments=str(st_c.segments),
             bit_exact=True))
-        print(f"streaming[{name}]: mb={st_c.micro_batch} "
-              f"block_h={cfg.block_h} fifo_depths={st_h.fifo_depths} "
+        print(f"streaming[{name}]: mb={mb} "
+              f"fifo_depths={st_h.fifo_depths} "
               f"max_occupancy={st_h.max_occupancy} "
               f"sim_cycles={st_h.sim_cycles} "
               f"compiled_vs_host={speed:.2f}x matches_offline=True")
         scenario_json["streaming"].append({
-            "model": name, "micro_batch": st_c.micro_batch,
-            "block_h": cfg.block_h,
-            "block_mn": cfg.block_mn,
+            "model": name, "micro_batch": mb,
             "compiled_ms": t_cmp * 1e3, "host_ms": t_host * 1e3,
             "compiled_vs_host_speedup": speed,
-            "modeled_cycles": cfg.modeled_cycles,
-            "modeled_traffic_bytes": cfg.modeled_traffic_bytes,
+            "modeled_cycles": st_c.sim_cycles,
             "fifo_depths": st_h.fifo_depths,
             "max_occupancy": st_h.max_occupancy,
             "segments": st_c.segments,

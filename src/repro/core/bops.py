@@ -125,9 +125,8 @@ def conv_input_band_bytes(geom, block_h: int) -> float:
     ``(block_h - 1) * stride + kernel`` padded input rows (halo rows
     duplicated across adjacent bands — the line-buffer overlap), so smaller
     blocks re-fetch more halo rows while bigger blocks need a bigger VMEM
-    accumulator. This is the byte term the ``block_h`` autotuner minimizes
-    (``deploy.autotune.plan_block_h``) and what ``stage_traffic_bytes``
-    charges a tuned direct stage.
+    accumulator. This is what ``stage_traffic_bytes`` charges a direct
+    stage at the kernel planner's row block.
     """
     from repro.kernels.conv_threshold import band_rows, same_pads
 
@@ -174,15 +173,12 @@ def stage_traffic_bytes(stage) -> float:
                  * geom.kernel * geom.kernel * geom.in_ch)
         io += 2.0 * 4.0 * patch                 # write + read the im2col mat
     elif geom is not None:
-        # direct lowering always streams per-row-block bands (halo rows
-        # duplicated per block) — tuned block_h or the planner's default —
-        # so the banded fetch replaces the flat input term either way and
-        # tuned vs untuned traffic stays comparable
-        bh = getattr(stage, "block_h", None)
-        if not bh:
-            from repro.kernels.ops import plan_conv_blocks
+        # direct lowering streams per-row-block bands (halo rows
+        # duplicated per block) at the kernel planner's row block, so the
+        # banded fetch replaces the flat input term
+        from repro.kernels.ops import plan_conv_blocks
 
-            bh = plan_conv_blocks(geom.out_h, geom.out_w, geom.out_ch)
+        bh = plan_conv_blocks(geom.out_h, geom.out_w, geom.out_ch)
         io += conv_input_band_bytes(geom, bh) \
             - 4.0 * int(getattr(stage, "in_dim", 0))
     return io + params
@@ -232,8 +228,8 @@ def schedule_cost(stages: Iterable) -> ModelCost:
 # ---------------------------------------------------------------------------
 
 #: VMEM cap the megakernel residency planner budgets against — the same
-#: conservative per-program working-set budget the block_h / block_mn
-#: models use (``deploy.autotune.VMEM_BUDGET_BYTES``; real cores have
+#: conservative per-program working-set budget as the conv kernel's
+#: accumulator cap (``kernels.ops.plan_conv_blocks``; real cores have
 #: ~16 MB, the margin covers compiler padding to (8, 128) tiles and the
 #: revolving input/output row blocks).
 MEGAKERNEL_VMEM_BYTES = 1 << 21
@@ -277,7 +273,7 @@ def staged_traffic_bytes(stages, wave_rows: int) -> float:
     program re-reads its parameters and round-trips its input and output
     activations through HBM (the inter-stage write+read the fused kernel
     keeps on-chip). The difference vs ``megakernel_traffic_bytes`` is the
-    modeled saving the autotuner ranks the megakernel/staged choice by."""
+    modeled saving of the megakernel."""
     total = 0.0
     for s in stages:
         total += 4.0 * wave_rows * (int(s.in_dim) + int(s.out_dim))

@@ -140,8 +140,8 @@ STAGE_ELEMS_PER_CYCLE = 8192
 #: Fixed per-initiation cost in simulated cycles: the dispatch/launch/sync
 #: overhead a stage pays every time it starts a micro-batch, independent of
 #: the micro-batch size. This is the term that makes tiny micro-batches
-#: expensive (many hops) and is what the micro-batch autotuner trades against
-#: pipeline fill/drain latency (which grows with the micro-batch).
+#: expensive (many hops), traded against pipeline fill/drain latency (which
+#: grows with the micro-batch).
 HOP_OVERHEAD_CYCLES = 8
 
 
@@ -156,7 +156,7 @@ def micro_batch_stage(name: str, work: int, micro_batch: int = 1,
     stage is busy for the whole service time (ii == latency — the executor
     runs one micro-batch at a time per stage). Total batch cycles therefore
     trade hop overhead (favors big micro-batches) against pipeline fill/drain
-    (favors small ones) — the optimum the FIFO-model autotuner searches for.
+    (favors small ones), an interior optimum.
     """
     mb = max(int(micro_batch), 1)
     lat = int(overhead) + max(1, -(-int(work) * mb // int(elems_per_cycle)))

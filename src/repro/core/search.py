@@ -22,7 +22,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Callable, Dict, List, Optional, Sequence, Tuple
+from typing import Callable, Dict, List, Sequence, Tuple
 
 import numpy as np
 
@@ -163,74 +163,6 @@ def bo_search(
 
     best_cfg = max(history, key=lambda t: t[1])[0]
     return best_cfg, history
-
-
-# ---------------------------------------------------------------------------
-# predictor-evaluated codesign sweeps (the fleet-scale mode)
-# ---------------------------------------------------------------------------
-
-def predictor_objective(predict_ms: Callable[[Dict], float],
-                        feature_fn: Callable[[Dict], Dict]) -> Callable:
-    """Wrap a learned wave-cost predictor as a search objective.
-
-    ``feature_fn(config) -> feature dict`` maps a search-space point to
-    the versioned ``repro.costmodel`` feature schema (typically via
-    ``features_from_model_cost``); the score is the *negative* predicted
-    wave cost, so both drivers' higher-is-better convention minimizes
-    cost. The objective is pure arithmetic — no compile, no execution, no
-    wall clock — which is what lets the quantization x tiling x
-    micro-batch scans run thousands of points.
-    """
-
-    def objective(config: Dict, budget: int, rng) -> float:
-        del budget, rng   # a prediction has no fidelity knob or noise
-        return -float(predict_ms(feature_fn(config)))
-
-    return objective
-
-
-def predictor_sweep(predict_ms: Callable[[Dict], float],
-                    feature_fn: Callable[[Dict], Dict],
-                    space: Sequence[Choice], *,
-                    method: str = "bo", n_trials: int = 64, seed: int = 0,
-                    accuracy_fn: Optional[Callable[[Dict], float]] = None
-                    ) -> Dict[str, object]:
-    """Predictor-evaluated codesign sweep over a discrete space.
-
-    Runs the existing BO/ASHA drivers with ``predictor_objective`` — the
-    Fig. 2/3 scans without wall-clock. Returns the best config, every
-    evaluated row (config + predicted cost, plus ``accuracy`` when an
-    ``accuracy_fn`` surrogate is supplied), and the Pareto-front indices
-    over (predicted cost, accuracy).
-    """
-    obj = predictor_objective(predict_ms, feature_fn)
-    if method == "bo":
-        best_cfg, history = bo_search(obj, space, n_trials=n_trials,
-                                      seed=seed)
-        evaluated = [(cfg, score) for cfg, score in history]
-    elif method == "asha":
-        best, trials = asha_search(obj, space, n_trials=n_trials, seed=seed)
-        best_cfg = best.config
-        evaluated = [(t.config, t.score) for t in trials]
-    else:
-        raise ValueError(f"method {method!r}: expected bo|asha")
-    rows = []
-    for cfg, score in evaluated:
-        row = {"config": dict(cfg), "predicted_ms": -float(score)}
-        if accuracy_fn is not None:
-            row["accuracy"] = float(accuracy_fn(cfg))
-        rows.append(row)
-    out: Dict[str, object] = {
-        "method": method,
-        "n_evaluated": len(rows),
-        "best": {"config": dict(best_cfg),
-                 "predicted_ms": float(predict_ms(feature_fn(best_cfg)))},
-        "rows": rows,
-    }
-    if accuracy_fn is not None:
-        pts = [(r["predicted_ms"], r["accuracy"]) for r in rows]
-        out["pareto"] = pareto_front(pts)
-    return out
 
 
 # ---------------------------------------------------------------------------

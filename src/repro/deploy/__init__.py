@@ -4,12 +4,11 @@ Closes the paper's loop: quantization-aware training exports a QIR graph
 (``core.qir``: ``export_qmlp`` for the MLPs, ``export_qcnn`` for the conv
 nets), this package streamlines and fuses it into integer dataflow stages
 (``lower``), compiles the stage schedule into one jit program plus a
-segment-compiled streaming pipeline whose FIFO depths, micro-batch, and
-conv row blocks come from the FIFO-model autotuner (``executor``,
-``autotune``), and measures it under the MLPerf Tiny load scenarios
-(``scenarios``). See ``docs/pipeline.md`` for the streaming/autotune
-architecture and ``docs/lowering.md`` for the stage/bit-exactness
-contract.
+segment-compiled streaming pipeline whose FIFO depths come from the
+paper's FIFO pass (``executor``), and measures it under the MLPerf Tiny
+load scenarios (``scenarios``). See ``docs/pipeline.md`` for the
+streaming architecture and ``docs/lowering.md`` for the
+stage/bit-exactness contract.
 
 What actually lowers to fused integer stages:
 
@@ -32,13 +31,6 @@ exported graph runs — just not fused.
                                 compiled=model)       # + per-stage latency
 """
 
-from repro.deploy.autotune import (  # noqa: F401
-    TunedConfig,
-    autotune_mode,
-    autotune_model,
-    load_config,
-    save_config,
-)
 from repro.deploy.executor import (  # noqa: F401
     CompiledJaxModel,
     CompiledTinyModel,

@@ -29,11 +29,6 @@ Three execution modes mirror the paper's deployment measurements:
 The unfused per-node interpreter (``reference``) is kept as the baseline the
 benchmarks compare against — it is what running the QIR graph layer by layer
 without the compiler looks like.
-
-The default streaming micro-batch (and the direct-conv kernel's row block)
-can come from the FIFO-model autotuner (``deploy.autotune``) via
-``apply_tuned`` / ``compile_graph(..., autotune=True)`` instead of the
-historical hard-coded 16.
 """
 
 from __future__ import annotations
@@ -67,7 +62,7 @@ from repro.deploy.lower import (
     plan_megakernel,
 )
 
-#: Historical default micro-batch; used only when no tuned config is applied.
+#: Wave size of the streaming entry points when the caller names none.
 DEFAULT_MICRO_BATCH = 16
 
 
@@ -126,7 +121,7 @@ class CompiledTinyModel:
     def __init__(self, schedule: StageSchedule, graph: Optional[Graph] = None,
                  use_pallas: Optional[bool] = None,
                  interpret: bool = False,
-                 megakernel: Optional[bool] = None,
+                 megakernel: bool = True,
                  megakernel_budget_bytes: Optional[int] = None,
                  tracer=None):
         self.schedule = schedule
@@ -137,12 +132,9 @@ class CompiledTinyModel:
         self.interpret = bool(interpret)
         #: None = JAX's default device at the call
         self.device = jax.config.jax_default_device
-        self.tuned = None          # deploy.autotune.TunedConfig, if applied
-        #: megakernel dispatch: None = auto (fused whenever the residency
-        #: planner admits the segment), True = same but assert-intent,
-        #: False = force the per-stage reference path. The autotuner's
-        #: measured megakernel-vs-staged choice lands here via apply_tuned.
-        self.megakernel = megakernel
+        #: megakernel dispatch: True = fused wherever the residency planner
+        #: admits the segment, False = force the per-stage reference path
+        self.megakernel = bool(megakernel)
         self.megakernel_budget_bytes = megakernel_budget_bytes
         #: obs.Tracer sink for the wave phases (``executor.pad``,
         #: ``replica.put``, ``executor.launch``), ``stage`` probe spans and
@@ -155,23 +147,21 @@ class CompiledTinyModel:
         self.tracer = tracer if tracer is not None else NULL_TRACER
         return self
 
-    def set_megakernel(self, mode: Optional[bool],
-                       budget_bytes: Optional[int] = None
+    def set_megakernel(self, on: bool, budget_bytes: Optional[int] = None
                        ) -> "CompiledTinyModel":
-        """Re-plan megakernel dispatch (None = auto / True / False) and drop
-        the stale compiled programs; ``budget_bytes`` overrides the planner's
-        VMEM cap (tests force the fallback with a tiny one) and None restores
-        the default cap. Returns self."""
-        self.megakernel = mode
+        """Re-plan megakernel dispatch (``on=False`` forces the staged
+        chain) and drop the stale compiled programs; ``budget_bytes``
+        overrides the planner's VMEM cap (tests force the fallback with a
+        tiny one) and None restores the default cap. Returns self."""
+        self.megakernel = bool(on)
         self.megakernel_budget_bytes = budget_bytes
         self._rebuild()
         return self
 
     def _rebuild(self):
-        """(Re)create every compiled entry point from the current schedule —
-        called at construction and after ``apply_tuned`` mutates stage
-        parameters (jit closures capture the stage objects at trace time, so
-        stale programs must be dropped)."""
+        """(Re)create every compiled entry point from the schedule and the
+        megakernel dispatch: called at construction and by
+        ``set_megakernel``, whose re-plan leaves the old programs stale."""
         # residency-plan megakernel runs per compiled segment first — the
         # offline and segment programs below dispatch through the plans
         self._mega_plans: Dict[int, MegakernelSegment] = {}
@@ -198,7 +188,7 @@ class CompiledTinyModel:
                             f"{s.skip_from}, outside its compiled segment "
                             f"[{seg.start}, {seg.stop})")
                     joined.add(k)
-        if self.megakernel is not False:
+        if self.megakernel:
             for k, seg in enumerate(self.segments):
                 if k in joined:
                     continue   # the megakernel plans chains, not joins
@@ -214,32 +204,17 @@ class CompiledTinyModel:
         self._segment_fns: Dict[int, Callable] = {}
         self._plan_cache: Dict[Tuple[int, int], Tuple[List[int], int]] = {}
 
-    @property
-    def default_micro_batch(self) -> int:
-        return (self.tuned.micro_batch if self.tuned is not None
-                else DEFAULT_MICRO_BATCH)
+    #: the wave size ``micro_batch=None`` resolves to (router, pool)
+    default_micro_batch = DEFAULT_MICRO_BATCH
 
-    def apply_tuned(self, cfg) -> "CompiledTinyModel":
-        """Adopt an autotuned config (``deploy.autotune.TunedConfig``): the
-        streaming default micro-batch, per-conv-stage ``block_h``,
-        per-dense-stage ``block_m``/``block_n``, and the measured
-        megakernel-vs-staged segment dispatch choice (schema v3) replace
-        the magic constants. Returns self for chaining."""
-        for s in self.schedule.stages:
-            if isinstance(s, FusedConvThresholdStage):
-                bh = cfg.block_h.get(s.name)
-                if bh is not None:
-                    s.block_h = min(int(bh), s.geom.out_h)
-            elif isinstance(s, FusedThresholdStage):
-                mn = getattr(cfg, "block_mn", {}).get(s.name)
-                if mn is not None:
-                    s.block_m, s.block_n = int(mn[0]), int(mn[1])
-        mode = getattr(cfg, "segment_mode", None)
-        if mode in ("megakernel", "staged"):
-            self.megakernel = mode == "megakernel"
-        self.tuned = cfg
-        self._rebuild()
-        return self
+    def zero_batch(self, batch: int) -> jnp.ndarray:
+        """A zero input batch of ``batch`` rows shaped from the first
+        stage: the probe input of the service-time calibration."""
+        s0 = self.schedule.stages[0]
+        if isinstance(s0, FusedConvThresholdStage):
+            g = s0.geom
+            return jnp.zeros((batch, g.in_h, g.in_w, g.in_ch), jnp.int32)
+        return jnp.zeros((batch, s0.in_dim), jnp.int32)
 
     # -- single-program (offline) path -----------------------------------
     def _apply_stage(self, s, h, skip=None):
@@ -361,9 +336,8 @@ class CompiledTinyModel:
 
         Per stage: one compile call, one *discarded* warm iteration, then
         ``iters`` timed samples, median reported — enough samples that the
-        breakdown (and the autotuner's measured refinement it seeds) is
-        stable against scheduler noise. Runs the per-stage programs in
-        schedule order (each stage's input is the previous stage's real
+        breakdown is stable against scheduler noise. Runs the per-stage
+        programs in schedule order (each stage's input is the previous stage's real
         output) so conv-vs-dense costs are visible in scenario reports.
 
         Every timed sample is also recorded as a ``stage`` span on the
@@ -406,8 +380,7 @@ class CompiledTinyModel:
 
         Each stage's simulated service time scales with its per-sample work
         times the micro-batch size, plus a fixed per-hop overhead
-        (``core.dataflow.micro_batch_stage``) — the cost model the
-        micro-batch autotuner searches over. Work is parameterized on the
+        (``core.dataflow.micro_batch_stage``). Work is parameterized on the
         lowering kind: MACs for dense stages, im2col tile counts (output
         tiles x patch size) for ``im2col`` conv stages, but only *output*
         tiles for ``direct`` fused conv stages — the fused kernel never
@@ -452,7 +425,7 @@ class CompiledTinyModel:
         the observable reference: its occupancy stats are what validate the
         FIFO model, and the compiled path is asserted bit-identical to it.
 
-        ``micro_batch=None`` resolves to the same (autotuned) default as
+        ``micro_batch=None`` resolves to the same default as
         ``streaming_compiled``, so the two entry points always compare the
         same schedule. ``fifo_depths`` overrides the optimizer's capacities
         (backpressure testing: depth-1 FIFOs must still make progress);
@@ -683,8 +656,7 @@ class CompiledTinyModel:
         (``_segment_fn``); only host-boundary segments (fallback float
         chains) return to Python, once per micro-batch. Bit-identical to
         ``offline`` and ``streaming_host`` — same stage semantics, different
-        schedule. ``micro_batch=None`` uses the autotuned default
-        (``apply_tuned``), else ``DEFAULT_MICRO_BATCH``.
+        schedule. ``micro_batch=None`` uses ``DEFAULT_MICRO_BATCH``.
         """
         mb = int(micro_batch) if micro_batch else self.default_micro_batch
         x_int, n, n_micro = self._pad_micro(x_int, mb)
@@ -712,9 +684,8 @@ def compile_graph(graph: Graph, in_scale: float = 1.0 / 127.0,
                   use_pallas: Optional[bool] = None,
                   interpret: bool = False,
                   conv_lowering: Optional[str] = None,
-                  megakernel: Optional[bool] = None,
-                  autotune: bool = False,
-                  tuned=None, tracer=None) -> CompiledTinyModel:
+                  megakernel: bool = True,
+                  tracer=None) -> CompiledTinyModel:
     """The one-call deployment entry point: QIR json graph -> executor.
 
     ``conv_lowering`` picks the conv stage algorithm ("direct" fused kernel
@@ -723,30 +694,15 @@ def compile_graph(graph: Graph, in_scale: float = 1.0 / 127.0,
     ``use_pallas`` defaults to the kernel path on a TPU and to the XLA
     path elsewhere; ``interpret=True`` runs the kernel path's Pallas
     kernels through the interpreter (CPU tests).
-    ``megakernel`` forces the whole-network-resident fused dispatch on
-    (True) or off (False); the default None lets the residency planner
-    decide per segment (``docs/megakernel.md``), and an applied tuned
-    config's measured ``segment_mode`` choice overrides it.
-
-    ``tuned`` applies a prebuilt ``deploy.autotune.TunedConfig``;
-    ``autotune=True`` instead loads (or searches and caches) the config for
-    this (model, platform) via ``deploy.autotune.autotune_model`` — honours
-    the ``REPRO_AUTOTUNE*`` knobs, see ``docs/pipeline.md``.
+    ``megakernel=True`` lets the residency planner fuse each segment's
+    dense run into one resident program where it admits one
+    (``docs/megakernel.md``); False forces the staged chain.
     """
     schedule = lower_graph(graph, in_scale=in_scale,
                            conv_lowering=conv_lowering)
-    cm = CompiledTinyModel(schedule, graph=graph, use_pallas=use_pallas,
-                           interpret=interpret, megakernel=megakernel,
-                           tracer=tracer)
-    if tuned is not None:
-        cm.apply_tuned(tuned)
-    elif autotune:
-        from repro.deploy.autotune import autotune_mode, autotune_model
-
-        mode = autotune_mode()
-        if mode != "off":
-            cm.apply_tuned(autotune_model(cm, mode=mode))
-    return cm
+    return CompiledTinyModel(schedule, graph=graph, use_pallas=use_pallas,
+                             interpret=interpret, megakernel=megakernel,
+                             tracer=tracer)
 
 
 class CompiledJaxModel:

@@ -208,8 +208,6 @@ class FusedThresholdStage:
     in_signed: bool = False  # input codes signed (the graph input) or not
     mm_float: bool = False   # exact float32 GEMM path (see _float_mm_safe)
     affine: Optional[jnp.ndarray] = None   # exact O(1) act. (_apply_act)
-    block_m: Optional[int] = None    # tuned kernel row block (None = default)
-    block_n: Optional[int] = None    # tuned kernel col block (None = default)
 
     @property
     def out_scale(self) -> float:
@@ -247,9 +245,7 @@ class FusedThresholdStage:
         operand, x_offset = self.mxu
         return ops.threshold_matmul(
             x_int, self.stage.w_int, self.stage.thresholds,
-            operand=operand, x_offset=x_offset,
-            block_m=self.block_m or 128, block_n=self.block_n or 128,
-            interpret=interpret)
+            operand=operand, x_offset=x_offset, interpret=interpret)
 
 
 @dataclasses.dataclass
@@ -299,7 +295,6 @@ class FusedConvThresholdStage:
     mm_float: bool = False   # exact float32 GEMM path (see _float_mm_safe)
     affine: Optional[jnp.ndarray] = None   # exact O(1) act. (_apply_act)
     lowering: str = "direct"         # "direct" | "im2col"
-    block_h: Optional[int] = None    # tuned output-row block (None = planner)
 
     @property
     def out_scale(self) -> float:
@@ -425,8 +420,8 @@ class FusedConvThresholdStage:
             return ops.conv_threshold(
                 x, self.stage.w_int, self.stage.thresholds,
                 kernel=g.kernel, stride=g.stride, padding=g.padding,
-                out_h=g.out_h, out_w=g.out_w, block_h=self.block_h,
-                operand=operand, x_offset=x_offset,
+                out_h=g.out_h, out_w=g.out_w, operand=operand,
+                x_offset=x_offset,
                 affine=self.affine if self.affine_on_kernel else None,
                 interpret=interpret)
         y = ops.threshold_matmul(
@@ -515,9 +510,9 @@ class ConvAddThresholdStage(FusedConvThresholdStage):
             x, self.stage.w_int, skip, self.w_skip, jnp.asarray(self.mul),
             self.stage.thresholds, kernel=kernel, stride=stride,
             padding=padding, out_h=g.out_h, out_w=g.out_w,
-            skip_stride=self.skip_stride, block_h=self.block_h,
-            operand=operand, x_offset=x_offset, skip_operand=skip_operand,
-            skip_offset=skip_offset, affine=self.affine, interpret=interpret)
+            skip_stride=self.skip_stride, operand=operand, x_offset=x_offset,
+            skip_operand=skip_operand, skip_offset=skip_offset,
+            affine=self.affine, interpret=interpret)
 
 
 @dataclasses.dataclass

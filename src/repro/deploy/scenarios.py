@@ -197,7 +197,7 @@ def streaming_pipeline(compiled, make_query: Callable[[int], np.ndarray],
 
     Runs ``compiled.streaming_compiled`` (one jit program per segment wave)
     over the whole pool; ``micro_batch=None`` consumes the executor's
-    autotuned default (``deploy.autotune``) instead of a magic constant.
+    default wave size.
     Reports the median span of ``iters`` runs like ``offline``, plus the
     FIFO plan that scheduled it.
     """
@@ -284,8 +284,8 @@ def server_streaming(compiled, make_query: Callable[[int], np.ndarray],
 
     Where ``server_poisson`` serves each arrival alone (batch 1, one
     worker), this scenario drives the ``repro.serve`` router: Poisson
-    arrivals are coalesced into padded micro-batch waves (the autotuned
-    wave size by default, ``max_wait_ms`` deadline) and dispatched through
+    arrivals are coalesced into padded micro-batch waves (the executor's
+    default wave size, ``max_wait_ms`` deadline) and dispatched through
     the executor's compiled segment programs — the PR-4 streaming pipeline
     finally fed by request traffic rather than a pre-batched pool. With a
     ``p99_budget_ms`` the SLO controller sheds load it estimates would
@@ -366,8 +366,8 @@ def run_all_scenarios(infer: Callable, make_query: Callable[[int], np.ndarray],
 
     When ``compiled`` exposes a streaming executor
     (``CompiledTinyModel.streaming_compiled``), the sweep also measures the
-    Offline pool through the compiled streaming pipeline at its (autotuned)
-    default micro-batch; when it exposes the wave-submission API
+    Offline pool through the compiled streaming pipeline at its default
+    micro-batch; when it exposes the wave-submission API
     (``submit_wave``), the Server load is additionally replayed through
     the dynamic-batching router (``ServerStreaming``).
     """

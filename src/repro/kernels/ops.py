@@ -137,7 +137,7 @@ def plan_conv_blocks(out_h: int, out_w: int, out_ch: int,
                      acc_budget_bytes: int = 1 << 21) -> int:
     """Pick the output-row block for the fused direct-conv kernel.
 
-    Autotuned from the *output tile* shape: enough rows that each program's
+    Sized from the *output tile* shape: enough rows that each program's
     flattened matmul M dimension (``block_h * out_w``) approaches
     ``target_rows`` (keeps the MXU busy), capped so the int32 accumulator
     block (``block_h * out_w * out_ch * 4`` bytes) stays inside a VMEM

@@ -65,7 +65,6 @@ def _run_tiny(args):
     each of ``--replicas`` replicas its own executor on its own device, and
     drive a Poisson trace through the router under the chosen engine."""
     from repro.deploy import compile_graph
-    from repro.deploy.autotune import autotune_model
     from repro.serve import (
         AsyncEngine,
         ReplicaPool,
@@ -83,30 +82,15 @@ def _run_tiny(args):
                          f"{len(devices)} devices")
     graph, in_scale, shape = build_tiny_graph(args.tiny_model)
     cm = compile_graph(graph, in_scale=in_scale)
-    tuned = None
-    if args.autotune != "off":
-        tuned = autotune_model(cm, batch=32, mode=args.autotune)
-        cm.apply_tuned(tuned)
     mb = cm.default_micro_batch
-    if args.autotune == "model":
-        # cold-start path: no wall-clock reads before the first request —
-        # the learned predictor prices admission from wave 0 and the
-        # router's EWMA corrects it online (docs/costmodel.md)
-        from repro.costmodel import load_default
-        from repro.serve import PredictedServiceModel
-
-        service = PredictedServiceModel.from_predictor(load_default(), cm)
-    else:
-        service = ServiceModel.from_compiled(
-            cm, probe_batch=mb).recalibrated(
-                measure_wave_service_s(cm, mb), mb)
+    service = ServiceModel.from_compiled(cm, probe_batch=mb).recalibrated(
+        measure_wave_service_s(cm, mb), mb)
     engine = AsyncEngine() if args.engine == "async" else SyncEngine()
 
     # replica 0 reuses the executor built above on the default device;
     # every other replica compiles its own on its own device
     pool = ReplicaPool(
-        cm, factory=lambda: compile_graph(graph, in_scale=in_scale,
-                                          tuned=tuned),
+        cm, factory=lambda: compile_graph(graph, in_scale=in_scale),
         devices=devices[:args.replicas])
     router = Router({args.tiny_model: pool},
                     RouterConfig(micro_batch=mb),
@@ -144,12 +128,6 @@ def main(argv=None):
                          "compiled Table-1 model through the serve router")
     ap.add_argument("--tiny-model", choices=sorted(TINY_MODELS),
                     default="kws")
-    ap.add_argument("--autotune", choices=("off", "probe", "model"),
-                    default="probe",
-                    help="tiny stack tuning: probe = measured search, "
-                         "model = probe-free learned cost model (cold-"
-                         "start admission priced by the predictor), "
-                         "off = compiled defaults")
     ap.add_argument("--replicas", type=int, default=1)
     ap.add_argument("--engine", choices=("sync", "async"), default="sync")
     ap.add_argument("--qps", type=float, default=0.0,

@@ -27,7 +27,6 @@ from repro.obs.export import (  # noqa: F401
     jsonl_lines,
 )
 from repro.obs.report import (  # noqa: F401
-    export_prediction_records,
     latency_percentiles,
     prediction_error,
     prediction_records,

@@ -79,25 +79,6 @@ def prediction_records(tracer: Tracer) -> List[Dict]:
     return rows
 
 
-def export_prediction_records(tracer: Tracer, path: str) -> str:
-    """Write ``prediction_records`` as a deterministic JSONL shard.
-
-    One sorted-key JSON object per line, rows in span order — the
-    accumulable on-disk form ``repro.costmodel.dataset`` harvests
-    (``load_trace_records``): archive a shard per traced run and the
-    training table rebuilds byte-identically from the archive alone.
-    """
-    import json
-    import os
-
-    os.makedirs(os.path.dirname(path) or ".", exist_ok=True)
-    with open(path, "w") as f:
-        for r in prediction_records(tracer):
-            f.write(json.dumps(r, sort_keys=True, separators=(",", ":")))
-            f.write("\n")
-    return path
-
-
 def prediction_error(tracer: Tracer) -> Dict[str, Dict]:
     """Prediction-error statistics per ``model@platform``.
 

@@ -1,8 +1,8 @@
 """The one injectable timer every measurement in ``src/repro`` reads.
 
 Raw ``time.time()`` / ``time.perf_counter()`` calls used to be scattered
-through ``deploy/scenarios.py``, ``deploy/executor.py``, ``deploy/autotune.py``
-and the launch/serving shims — each one a place a deterministic test could
+through ``deploy/scenarios.py``, ``deploy/executor.py`` and the
+launch/serving shims — each one a place a deterministic test could
 not reach. This module is now the single point of truth (enforced by
 ``scripts/check_no_raw_clock.py``): everything times itself through
 ``obs.timer.now()``, and a test swaps the process-wide timer for a manual

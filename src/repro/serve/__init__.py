@@ -61,7 +61,6 @@ from repro.serve.router import (  # noqa: F401
     ServeRequest,
 )
 from repro.serve.slo import (  # noqa: F401
-    PredictedServiceModel,
     ServiceModel,
     SLOController,
     measure_wave_service_s,

@@ -4,8 +4,8 @@ This is the runtime that was missing between individual requests and the
 PR-4 compiled streaming pipeline. Per model ("lane") the router keeps a
 pending queue and two dispatch triggers:
 
-  * **full wave** — the moment ``micro_batch`` requests (the autotuned wave
-    size by default) are queued, they leave as one wave;
+  * **full wave** — the moment ``micro_batch`` requests (the executor's
+    default wave size unless configured) are queued, they leave as one wave;
   * **deadline flush** — the oldest pending request never waits longer than
     ``max_wait_ms``: when the deadline passes, the partial wave leaves
     anyway, zero-padded through the executor's ``submit_wave`` padding-mask
@@ -124,8 +124,8 @@ class ServeRequest:
 class RouterConfig:
     """Per-model routing policy.
 
-    ``micro_batch=None`` consumes the executor's (autotuned) default wave
-    size; ``p99_budget_ms=None`` disables shedding (every request is
+    ``micro_batch=None`` consumes the executor's default wave size;
+    ``p99_budget_ms=None`` disables shedding (every request is
     admitted). ``slo_headroom`` scales the budget the admission test uses
     (0.8 = shed at 80% of budget, keeping margin for estimate error).
     """

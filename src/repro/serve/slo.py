@@ -3,8 +3,7 @@
 The question the shedder answers per arriving request: *if we admit this,
 will it (and the requests behind it) still finish inside the p99 budget?*
 Answering needs a service-time estimate, and the serve runtime builds it
-the same way the streaming autotuner does — model first, measurement
-second:
+model first, measurement second:
 
   1. **FIFO cost model** — ``core.dataflow.micro_batch_stage`` prices every
      compiled stage at a wave size (``overhead + ceil(work*mb/elems)``
@@ -92,16 +91,14 @@ class ServiceModel:
         """Build the model for a ``CompiledTinyModel``: FIFO-model stage
         works plus a stage_latencies calibration at ``probe_batch``.
 
-        Pass a precomputed ``stage_ms`` breakdown (e.g. the autotuner's
-        ``seed_stage_ms``) to skip the probe; its batch must then be
-        ``probe_batch``.
+        Pass a precomputed ``stage_ms`` breakdown to skip the probe; its
+        batch must then be ``probe_batch``.
         """
-        from repro.deploy.autotune import default_sample
         from repro.deploy.executor import stage_work
 
         works = [(s.name, stage_work(s)) for s in cm.schedule.stages]
         if stage_ms is None:
-            stage_ms = cm.stage_latencies(default_sample(cm, probe_batch))
+            stage_ms = cm.stage_latencies(cm.zero_batch(probe_batch))
         measured_s = sum(s["ms"] for s in stage_ms) / 1e3
         model = cls(works=works, sec_per_cycle=1.0)
         cycles = model.wave_cycles(probe_batch)
@@ -112,106 +109,15 @@ class ServiceModel:
         return model
 
 
-@dataclasses.dataclass
-class PredictedServiceModel(ServiceModel):
-    """Predictor-priced service model for a COLD model — no probe, no
-    completed wave, no ``stage_latencies`` run.
-
-    ``predicted_s`` tables the learned wave-cost predictor's per-wave
-    service estimate at each candidate micro-batch
-    (``repro.costmodel``); ``scale`` is the online correction factor
-    ``recalibrated`` folds measured waves into. Off-table wave sizes are
-    extrapolated with the FIFO model's *shape* (cycles ratio against the
-    nearest tabled size) — the same stance as the calibrated base class,
-    just anchored on a prediction instead of a probe. The first measured
-    wave starts pulling ``scale`` toward reality (and the
-    ``SLOController`` EWMA corrects on top), so cold-start pricing decays
-    into the measured path with no mode switch.
-    """
-
-    predicted_s: Dict[int, float] = dataclasses.field(default_factory=dict)
-    scale: float = 1.0
-
-    def wave_service_s(self, micro_batch: int) -> float:
-        mb = int(micro_batch)
-        if not self.predicted_s:
-            return super().wave_service_s(mb)
-        s = self.predicted_s.get(mb)
-        if s is None:
-            ref = min(sorted(self.predicted_s),
-                      key=lambda m: (abs(m - mb), m))
-            s = self.predicted_s[ref] * (
-                self.wave_cycles(mb) / max(self.wave_cycles(ref), 1))
-        return s * self.scale
-
-    def recalibrated(self, measured_s: float, micro_batch: int
-                     ) -> "PredictedServiceModel":
-        modeled = self.wave_service_s(micro_batch)
-        if measured_s <= 0 or modeled <= 0:
-            return self
-        ratio = measured_s / modeled
-        return dataclasses.replace(
-            self, scale=self.scale * ratio,
-            calibration={**self.calibration,
-                         "measured_wave_ms": measured_s * 1e3,
-                         "wave_micro_batch": int(micro_batch),
-                         "dispatch_overhead_ratio": ratio})
-
-    @classmethod
-    def from_predictor(cls, predictor, cm,
-                       candidates: Sequence[int] = (1, 2, 4, 8, 16, 32, 64)
-                       ) -> "PredictedServiceModel":
-        """Price a compiled model's waves from static structure alone.
-
-        ``predictor`` is a ``repro.costmodel.WaveCostPredictor`` (or
-        anything with ``predict_ms(features_dict)``); the features come
-        from the versioned extractor, so this runs zero probes and zero
-        model executions — admission control for a model the server has
-        never seen.
-        """
-        from repro.costmodel.features import wave_features
-        from repro.deploy.executor import stage_work
-
-        works = [(s.name, stage_work(s)) for s in cm.schedule.stages]
-        table = {int(mb): float(predictor.predict_ms(wave_features(cm, mb)))
-                 / 1e3
-                 for mb in sorted({int(m) for m in candidates if m >= 1})}
-        model = cls(works=works, sec_per_cycle=1.0, predicted_s=table)
-        ref = min(table)
-        model.sec_per_cycle = table[ref] / max(model.wave_cycles(ref), 1)
-        model.calibration = {
-            "source": "predicted",
-            "feature_schema_version": int(getattr(predictor,
-                                                  "schema_version", 0)),
-            "candidates": sorted(table),
-        }
-        return model
-
-    @classmethod
-    def from_table(cls, works: List[Tuple[str, int]],
-                   predicted_s: Dict[int, float]) -> "PredictedServiceModel":
-        """Build directly from a predicted per-micro-batch table — the
-        scripted-simulation entry point (no compiled model needed)."""
-        table = {int(k): float(v) for k, v in predicted_s.items()}
-        model = cls(works=list(works), sec_per_cycle=1.0,
-                    predicted_s=table)
-        ref = min(table)
-        model.sec_per_cycle = table[ref] / max(model.wave_cycles(ref), 1)
-        model.calibration = {"source": "predicted",
-                             "candidates": sorted(table)}
-        return model
-
-
 def measure_wave_service_s(cm, micro_batch: int, iters: int = 5) -> float:
     """Median wall seconds of one padded wave through ``submit_wave`` —
     the probe ``ServiceModel.recalibrated`` consumes (one compile + one
     discarded warm iteration first, the ``stage_latencies`` convention)."""
     import jax
 
-    from repro.deploy.autotune import default_sample
     from repro.obs import timer as obs_timer
 
-    x = default_sample(cm, micro_batch)
+    x = cm.zero_batch(micro_batch)
     for _ in range(2):                   # compile + discarded warm
         y, _ = cm.submit_wave(x, micro_batch=micro_batch)
         jax.block_until_ready(y)

@@ -200,7 +200,7 @@ def test_launch_serve_tiny_serves_every_family(monkeypatch, name):
 
     monkeypatch.setattr(repro.compile_cache, "enable_compile_cache",
                         lambda: "")
-    res = main(["--stack", "tiny", "--tiny-model", name, "--autotune", "off",
+    res = main(["--stack", "tiny", "--tiny-model", name,
                 "--requests", "24"])
     assert res["served"] == res["n"] == 24
 

@@ -176,7 +176,8 @@ def test_strided_conv_kernel_is_exact(k, stride, padding, operand, x_offset):
 
 
 def test_plan_conv_blocks_shapes():
-    """The autotuner sizes row blocks from the output tile, within bounds."""
+    """The kernel planner sizes row blocks from the output tile, within
+    bounds."""
     from repro.kernels.ops import plan_conv_blocks
 
     assert plan_conv_blocks(32, 32, 16) == 8      # 256-row target

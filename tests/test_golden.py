@@ -201,5 +201,5 @@ def test_golden_mlp_megakernel_fallback_when_budget_rejects(name):
     _assert_stage_match(y_str, want_stages[-1], f"{name}[fallback] streaming")
     assert not stats.megakernel
     # restoring the default budget re-admits the plan
-    cm.set_megakernel(None)
+    cm.set_megakernel(True)
     assert cm._mega_plans

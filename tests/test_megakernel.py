@@ -1,6 +1,6 @@
 """Megakernel unit tests: the whole-segment Pallas kernel against its
 pure-jnp oracle, the residency planner's admit/reject logic, and the byte
-accounting the planner and autotuner share (``docs/megakernel.md``).
+accounting of the residency model (``docs/megakernel.md``).
 
 Golden-fixture bit-exactness across executor entry points lives in
 ``tests/test_golden.py``; this file covers the pieces in isolation.
@@ -142,7 +142,7 @@ def test_residency_components_readd_and_default_budget():
 
 
 def test_traffic_model_megakernel_beats_staged():
-    """The residency traffic model the autotuner ranks by: the fused wave
+    """The residency traffic model: the fused wave
     skips every inter-stage HBM round-trip and re-fetch, so it can only
     save bytes — and the saving grows with chain depth."""
     stages = [_fts("d0", 64, 48), _fts("d1", 48, 48), _fts("d2", 48, 12)]

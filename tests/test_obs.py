@@ -436,8 +436,8 @@ def test_prune_boundary_is_inclusive():
 
 
 def test_wave_occupancy_histogram_with_mixed_micro_batch_sizes():
-    """Waves dispatched under different micro-batch sizes (the autotuner
-    can retune a lane mid-run): the histogram keys on n_valid and the
+    """Waves dispatched under different micro-batch sizes (a lane's wave
+    size can change between runs): the histogram keys on n_valid and the
     mean normalizes each wave by ITS OWN micro_batch."""
     m = ServeMetrics(window_s=30.0)
     m.record_wave(1.0, 4, 4)     # full wave at mb=4

@@ -314,8 +314,9 @@ def test_offline_reports_median_span_over_iters(clock):
 
 
 def test_streaming_pipeline_scenario_uses_tuned_default(clock):
-    """The streaming scenario consumes the executor's (autotuned) default
-    micro-batch and reports the FIFO plan that scheduled the run."""
+    """The streaming scenario uses the executor's default micro-batch
+    (``micro_batch=None``) and reports the FIFO plan that scheduled the
+    run."""
     calls = []
 
     class FakeStats:
@@ -332,7 +333,7 @@ def test_streaming_pipeline_scenario_uses_tuned_default(clock):
     rep = streaming_pipeline(FakeCompiled(), _mk, n_samples=16,
                              warmup=1, iters=3)
     assert rep.scenario == "StreamingOffline"
-    assert calls == [None] * 4            # warmup + 3 timed, tuned default
+    assert calls == [None] * 4            # warmup + 3 timed, the default
     assert rep.extras["micro_batch"] == 8
     assert rep.extras["fifo_depths"] == "[2, 2]"
     assert rep.p50_ms == pytest.approx(0.016 / 16 * 1e3)
